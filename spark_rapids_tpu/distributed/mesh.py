@@ -2,9 +2,8 @@
 
 The ideal scale-out promotes the virtual single-process mesh to a
 genuine multi-process ``jax.distributed`` mesh (SNIPPETS.md [1][2] —
-pjit across TPU-pod processes with a call-site mesh).  On this image's
-CPU backend (jax 0.4.37) cross-process CPU collectives are not
-reliably available, so mesh formation is an ATTEMPT with a bounded
+pjit across TPU-pod processes with a call-site mesh).  On the CPU
+backend cross-process collectives are not reliably available, so mesh formation is an ATTEMPT with a bounded
 timeout, and the distributed runner degrades to the process-per-shard
 harness: every rank computes its shard with plain local jit, and ALL
 cross-rank movement rides the kudo shuffle service — which is the

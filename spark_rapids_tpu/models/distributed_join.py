@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_tpu.ops.device_join import inner_join_device
-from spark_rapids_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from spark_rapids_tpu.parallel.exchange import exchange
 
 

@@ -18,7 +18,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from spark_rapids_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_tpu import observability as _obs

@@ -296,7 +296,7 @@ def make_q9_multichip(mesh: Mesh):
     """q9-shape on the mesh: rows sharded, the five bucket reductions
     psum'd — sums cross ICI, the avg divide happens on the global
     sums (a mean of shard means would be wrong)."""
-    from spark_rapids_tpu.utils.jax_compat import shard_map as smap
+    from jax import shard_map as smap
 
     axis = mesh.axis_names[0]
 
@@ -485,7 +485,7 @@ def make_q5_multichip(mesh: Mesh, stores: int, join_capacity: int):
     picks), per-shard partial group-by via the SHARED _q5_kernel, ONE
     psum over ICI for the global group table, order-by replicated.
     The whole step is a single jitted shard_map program."""
-    from spark_rapids_tpu.utils.jax_compat import shard_map as smap
+    from jax import shard_map as smap
 
     axis = mesh.axis_names[0]
     kernel = _q5_kernel(
@@ -508,7 +508,7 @@ def make_q72_multichip(mesh: Mesh, items: int, max_week: int,
     inventory + item dim replicated (broadcast), per-shard join +
     filters + partial (item, week) counts via the SHARED _q72_kernel,
     psum for the global group table, top-k replicated."""
-    from spark_rapids_tpu.utils.jax_compat import shard_map as smap
+    from jax import shard_map as smap
 
     axis = mesh.axis_names[0]
     kernel = _q72_kernel(
@@ -617,7 +617,7 @@ def make_q3_multichip(mesh: Mesh, base: int, years: int, brands: int,
                       limit: int = 100):
     """q3-shape on the mesh: fact sharded row-parallel, dense date and
     item dims replicated, partial group tables psum'd over ICI."""
-    from spark_rapids_tpu.utils.jax_compat import shard_map as smap
+    from jax import shard_map as smap
 
     axis = mesh.axis_names[0]
     kernel = _q3_kernel(base, years, brands, manufact, month, limit,
@@ -725,7 +725,7 @@ def make_q7_multichip(mesh: Mesh, items: int, limit: int = 100):
     """q7-shape on the mesh: facts row-sharded, filter/dictionary dims
     replicated, partial counts/sums psum'd BEFORE the avg divide (a
     mean of shard means would be wrong)."""
-    from spark_rapids_tpu.utils.jax_compat import shard_map as smap
+    from jax import shard_map as smap
 
     axis = mesh.axis_names[0]
     kernel = _q7_kernel(items, limit, lambda x: lax.psum(x, axis))

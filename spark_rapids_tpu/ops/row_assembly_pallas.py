@@ -2,17 +2,17 @@
 
 The default `_assemble_fixed_words` path (row_conversion.py) composes
 each output u32 word as an OR of shifted column vectors and relies on
-XLA's `jnp.stack(words, axis=1)` to materialize the (rows, W) matrix —
-measured ~59 GB/s of output on one v5e chip, a few x below the HBM
-ceiling because the stack's strided stores pass through HBM.
+XLA's `jnp.stack(words, axis=1)` to materialize the (rows, W) matrix;
+the stack's strided stores pass through HBM.
 
 This kernel instead builds each (BLOCK_ROWS, W) tile in VMEM: column
 blocks stream in once in their NATIVE widths (u8/u16/u32 — the narrow
-converts and shifts happen in-register), the word-stack transpose
-happens in VMEM, and the tile is stored once.  The only pre-pass is
-splitting 8-byte columns into u32 lo/hi halves (TPU vectors are 32-bit;
-see docs/tpu_design.md §2 for why (rows, 2) u32 bitcasts are not safe
-on this backend's tiling).
+converts and shifts happen in-register), the word vectors stack along
+sublanes and ONE aligned transpose in VMEM turns them into the row
+tile, which is stored once.  The only pre-pass is splitting 8-byte
+columns into u32 lo/hi halves (TPU vectors are 32-bit; see
+docs/tpu_design.md §2 for why (rows, 2) u32 bitcasts are not safe on
+the TPU's tiling).
 
 Reference counterpart: row_conversion.cu:591 copy_to_rows (shared-memory
 tiled memcpy); the TPU shape is word-composition, not memcpy.
@@ -24,10 +24,15 @@ matrix through VMEM once and slices every column field out in-register
 into row tiles (the string variants, row_conversion.cu:71-73) instead
 of scattering across the whole HBM matrix.
 
-Opt-in until profiled on real hardware: set
-SPARK_RAPIDS_TPU_PALLAS_ROWCONV=1 (row_conversion routes to-rows,
-from-rows, and the string paste through these kernels), or call
-directly.  `interpret=True` runs anywhere (tests use the CPU backend).
+Opt-in: set SPARK_RAPIDS_TPU_PALLAS_ROWCONV=1 (row_conversion routes
+to-rows, from-rows, and the string paste through these kernels), or
+call directly.  `interpret=True` runs anywhere (tests use the CPU
+backend).  On a chip to-rows and from-rows compile for Mosaic and match
+the stack path byte for byte (chip_smoke.py; device throughput: not
+measured); `paste_strings_pallas` is NOT brought up — its
+take_along_axis does not lower for Mosaic under x64
+(tests/test_tpu_compile.py) — and a kernel selected on a chip raises
+what the compiler raises, it never gives way to the stack path.
 """
 
 from __future__ import annotations
@@ -37,27 +42,38 @@ from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from spark_rapids_tpu.columns.column import Column
 
 _U32 = jnp.uint32
+# block index maps must return int32: under x64 a python 0 traces as
+# an i64 constant, which Mosaic refuses beside the i32 grid index
+_ZERO = np.int32(0)
+
+
+def _lane_pad(n_words: int) -> int:
+    """Row width rounded up to whole 128-lane tiles: a 2-D transpose
+    inside a kernel wants both dimensions aligned."""
+    return -(-n_words // 128) * 128
 
 
 def assemble_rows_pallas(inputs: Sequence[jnp.ndarray],
                          plan: Sequence[Tuple[int, int]],
                          rows: int, n_words: int,
-                         block_rows: int = 512,
+                         block_rows: int = 1024,
                          interpret: bool = False) -> jnp.ndarray:
     """Run the tile kernel; returns flat packed u32 LE words
     (rows * n_words,), same contract as _assemble_fixed_words."""
     import jax.experimental.pallas as pl
 
     br = min(block_rows, max(8, rows))
+    wpad = _lane_pad(n_words)
 
     def kernel(*refs):
         out_ref = refs[-1]
-        words = [None] * n_words
+        words = [None] * wpad
         for r, (w, sh) in zip(refs[:-1], plan):
             v = r[:]
             if v.dtype != _U32:
@@ -66,16 +82,21 @@ def assemble_rows_pallas(inputs: Sequence[jnp.ndarray],
                 v = v << _U32(sh)
             words[w] = v if words[w] is None else (words[w] | v)
         zeros = jnp.zeros((br,), _U32)
-        tile = jnp.stack([w if w is not None else zeros
-                          for w in words], axis=1)
-        out_ref[:, :] = tile
+        # each (br,) word vector is one sublane row of the (wpad, br)
+        # stack; ONE aligned transpose turns it into the row tile
+        # (stacking along axis 1 asks Mosaic for a relayout per word:
+        # VMEM exhausted at 274 words)
+        out_ref[:, :] = jnp.stack([w if w is not None else zeros
+                                   for w in words], axis=0).T
 
     grid = (pl.cdiv(rows, br),)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((br,), lambda i: (i,)) for _ in inputs],
-        out_specs=pl.BlockSpec((br, n_words), lambda i: (i, 0)),
+        # the block is lane-padded past the row width; Pallas masks
+        # the out-of-bounds columns of an edge block
+        out_specs=pl.BlockSpec((br, wpad), lambda i: (i, _ZERO)),
         out_shape=jax.ShapeDtypeStruct((rows, n_words), _U32),
         interpret=interpret,
     )(*inputs)
@@ -83,7 +104,7 @@ def assemble_rows_pallas(inputs: Sequence[jnp.ndarray],
 
 
 def assemble_fixed_words_pallas(cols, starts, validity_offset, row_size,
-                                block_rows: int = 512,
+                                block_rows: int = 1024,
                                 interpret: bool = False) -> jnp.ndarray:
     """Drop-in replacement for row_conversion._assemble_fixed_words.
 
@@ -135,7 +156,7 @@ def assemble_fixed_words_pallas(cols, starts, validity_offset, row_size,
 
 def disassemble_rows_pallas(words: jnp.ndarray,
                             extract_plan: Sequence[Tuple[int, int, int]],
-                            block_rows: int = 512,
+                            block_rows: int = 1024,
                             interpret: bool = False):
     """Inverse tile kernel (row_conversion.cu:591 copy_from_rows
     counterpart): the (rows, W) packed word matrix streams through
@@ -151,9 +172,12 @@ def disassemble_rows_pallas(words: jnp.ndarray,
     br = min(block_rows, max(8, rows))
 
     def kernel(in_ref, *out_refs):
-        tile = in_ref[:, :]
+        # one aligned transpose, then every field is a sublane row:
+        # the lane extract tile[:, w] costs Mosaic a relayout each and
+        # compile time grew quadratically with the field count
+        words = in_ref[:, :].T
         for ref, (w, sh, nbits) in zip(out_refs, extract_plan):
-            v = tile[:, w]
+            v = words[w, :]
             if sh:
                 v = v >> _U32(sh)
             if nbits < 32:
@@ -164,7 +188,8 @@ def disassemble_rows_pallas(words: jnp.ndarray,
     outs = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((br, n_words), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((br, _lane_pad(n_words)),
+                               lambda i: (i, _ZERO))],
         out_specs=[pl.BlockSpec((br,), lambda i: (i,))
                    for _ in extract_plan],
         out_shape=[jax.ShapeDtypeStruct((rows,), _U32)
@@ -199,7 +224,7 @@ def build_extract_plan(schema, starts, validity_offset, n_words):
 
 
 def convert_from_rows_pallas(list_col: Column, schema,
-                             block_rows: int = 512,
+                             block_rows: int = 1024,
                              interpret: bool = False):
     """Fixed-width-schema from-rows over the tile kernel; returns a
     Table matching row_conversion.convert_from_rows bit-for-bit.
@@ -280,7 +305,7 @@ def convert_from_rows_pallas(list_col: Column, schema,
 
 def paste_strings_pallas(mat: jnp.ndarray, chars: jnp.ndarray,
                          vstart: jnp.ndarray, lens: jnp.ndarray,
-                         block_rows: int = 256,
+                         block_rows: int = 1024,
                          interpret: bool = False) -> jnp.ndarray:
     """Tile-resident string-payload paste for the variable-width
     to-rows path (row_conversion.cu:71-73 string copy counterpart):
@@ -304,18 +329,18 @@ def paste_strings_pallas(mat: jnp.ndarray, chars: jnp.ndarray,
         src = p - vs[:, None]
         in_span = (src >= 0) & (src < ln[:, None]) & (src < pad)
         gathered = jnp.take_along_axis(
-            ch, jnp.clip(src, 0, pad - 1), axis=1)
+            ch, jnp.minimum(jnp.maximum(src, 0), pad - 1), axis=1)
         out_ref[:, :] = jnp.where(in_span, gathered, base)
 
     grid = (pl.cdiv(rows, br),)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((br, max_row), lambda i: (i, 0)),
-                  pl.BlockSpec((br, pad), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((br, max_row), lambda i: (i, _ZERO)),
+                  pl.BlockSpec((br, pad), lambda i: (i, _ZERO)),
                   pl.BlockSpec((br,), lambda i: (i,)),
                   pl.BlockSpec((br,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((br, max_row), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((br, max_row), lambda i: (i, _ZERO)),
         out_shape=jax.ShapeDtypeStruct((rows, max_row), mat.dtype),
         interpret=interpret,
     )(mat, chars, vstart.astype(jnp.int32), lens.astype(jnp.int32))

@@ -46,6 +46,23 @@ def cache_enabled() -> bool:
     return os.environ.get("SPARK_RAPIDS_TPU_JIT_CACHE", "1") != "0"
 
 
+def enable_persistent_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+    Entry points that run on the chip (chip_smoke.py, bench.py,
+    bench_all.py) call this before their first compile; importing the
+    package never does.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+    reads it itself and nothing here sets another directory; otherwise
+    the cache lives at ``<checkout>/.jax_cache`` — a fixed path, because
+    the path is part of the cache key."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def bucket_rows(n: int, min_bucket: int = _MIN_BUCKET) -> int:
     """Power-of-two row bucket: smallest 2^k >= n (floor min_bucket)."""
     if n <= min_bucket:

@@ -581,7 +581,7 @@ def make_q5_multichip_fused(mesh, stores: int, join_capacity: int):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from spark_rapids_tpu.utils.jax_compat import shard_map as smap
+    from jax import shard_map as smap
 
     axis = mesh.axis_names[0]
     fn, n_args = fused_pipeline_fn(q5_pipeline(stores, join_capacity),
@@ -600,7 +600,7 @@ def make_q72_multichip_fused(mesh, items: int, max_week: int,
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from spark_rapids_tpu.utils.jax_compat import shard_map as smap
+    from jax import shard_map as smap
 
     axis = mesh.axis_names[0]
     fn, n_args = fused_pipeline_fn(
