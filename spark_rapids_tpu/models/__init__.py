@@ -23,6 +23,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from spark_rapids_tpu import observability as _obs
 from spark_rapids_tpu.robustness import lifeguard as _lifeguard
 
 
@@ -62,6 +63,16 @@ class QueryContext:
         if self.deadline_ns is None:
             return None
         return (self.deadline_ns - time.monotonic_ns()) / 1e9
+
+    def phase(self, name: str, **attrs):
+        """One span of this query's timeline, for
+        ``with ctx.phase("ingest"):`` — kind ``phase``, parented under
+        the span open on this thread (the server's ``server_query``
+        root, whose ``query_id`` it inherits).  Recorded under the
+        metrics or the tracing switch; the shared no-op span with
+        both off."""
+        return _obs.TRACER.start_span(name, kind="phase",
+                                      attrs=attrs or None)
 
     def check_cancel(self) -> None:
         _lifeguard.beat(f"ctx:{self.query_id or 'query'}")
@@ -151,13 +162,49 @@ def _pipeline(key: tuple, build: Callable):
 def _rows(*arrays) -> List[list]:
     """Host-materialize pipeline outputs as plain nested lists (ints
     and floats only) — JSON-able across the socket front door and
-    directly comparable for byte-identity."""
+    directly comparable for byte-identity.  The timeline's ``rows``
+    span: device-to-host of the answer and the list building."""
     import numpy as np
-    cols = [np.asarray(a).reshape(-1) for a in arrays]
-    out = []
-    for row in zip(*cols):
-        out.append([float(v) if isinstance(v, np.floating) else int(v)
-                    for v in row])
+    with _obs.TRACER.start_span("rows", kind="phase") as span:
+        cols = [np.asarray(a).reshape(-1) for a in arrays]
+        out = []
+        for row in zip(*cols):
+            out.append([float(v) if isinstance(v, np.floating)
+                        else int(v) for v in row])
+        span.set_attr("rows_out", len(out))
+    return out
+
+
+def _ingest(ctx: QueryContext, read: Callable, /, **kwargs):
+    """A runner's source call (seeded generator or file read) as the
+    timeline's ``ingest`` span: the numpy draw or page decode and the
+    ``jnp.asarray`` enqueues, with the rows and bytes handed over."""
+    import numpy as np
+    with ctx.phase("ingest") as span:
+        data = read(**kwargs)
+        if span is not _obs.NOOP_SPAN:
+            leaves = data if isinstance(data, tuple) else (data,)
+            shape = np.shape(leaves[0]) if leaves else ()
+            span.set_attr("rows", int(shape[0]) if shape else 0)
+            span.set_attr("bytes", sum(int(getattr(a, "nbytes", 0))
+                                       for a in leaves))
+    return data
+
+
+def _execute(ctx: QueryContext, path: str, run: Callable, /, *args,
+             **kwargs):
+    """The pipeline (``path="handfused"``) or stage (``"stage"``) call
+    as the timeline's ``execute`` span, from the call to its outputs
+    ready.  A stage run waits for its outputs itself (``device_wait``
+    inside ``stage_run``); a hand-written jit returns at the enqueue,
+    so its ``device_wait`` is taken here, where the runner's first
+    host read of an output blocked anyway."""
+    with ctx.phase("execute", path=path):
+        out = run(*args, **kwargs)
+        if path == "handfused":
+            import jax
+            with ctx.phase("device_wait"):
+                jax.block_until_ready(out)
     return out
 
 
@@ -174,10 +221,11 @@ def _run_q5(params: dict, ctx: QueryContext):
     stores = int(params.get("stores", 8))
     seed = int(params.get("seed", 5))
     cap = int(params.get("join_capacity", 1 << 12))
-    d = tpcds.gen_q5(rows=rows, stores=stores, days=60, seed=seed)
+    d = _ingest(ctx, tpcds.gen_q5, rows=rows, stores=stores, days=60,
+                seed=seed)
     q = _pipeline(("q5", stores, cap),
                   lambda: tpcds.make_q5(stores, join_capacity=cap))
-    k, sales, rets, profit, of = q(d)
+    k, sales, rets, profit, of = _execute(ctx, "handfused", q, d)
     if bool(np.asarray(of)):
         raise RuntimeError("q5 join capacity overflow")
     return _rows(k, sales, rets, profit)
@@ -188,8 +236,9 @@ def _run_q9(params: dict, ctx: QueryContext):
     ctx.check_cancel()
     rows = int(params.get("rows", 4096))
     seed = int(params.get("seed", 9))
-    data = tpcds.gen_q9(rows=rows, seed=seed)
-    counts, avg_p, avg_n = tpcds.run_q9(*data)
+    data = _ingest(ctx, tpcds.gen_q9, rows=rows, seed=seed)
+    counts, avg_p, avg_n = _execute(ctx, "handfused", tpcds.run_q9,
+                                    *data)
     return _rows(counts, avg_p, avg_n)
 
 
@@ -204,13 +253,13 @@ def _run_q72(params: dict, ctx: QueryContext):
     seed = int(params.get("seed", 72))
     cap = int(params.get("join_capacity", 1 << 17))
     week0 = 11_000 // 7
-    d = tpcds.gen_q72(cs_rows=rows, inv_rows=rows // 2, items=items,
-                      days=35, seed=seed)
+    d = _ingest(ctx, tpcds.gen_q72, cs_rows=rows, inv_rows=rows // 2,
+                items=items, days=35, seed=seed)
     q = _pipeline(("q72", items, max_week, cap),
                   lambda: tpcds.make_q72(items, max_week,
                                          join_capacity=cap,
                                          week0=week0))
-    i, w, c, of = q(d)
+    i, w, c, of = _execute(ctx, "handfused", q, d)
     if bool(np.asarray(of)):
         raise RuntimeError("q72 join capacity overflow")
     return _rows(i, w, c)
@@ -225,12 +274,12 @@ def _run_q3(params: dict, ctx: QueryContext):
     manufact = int(params.get("manufact", 3))
     seed = int(params.get("seed", 3))
     base = 10_957
-    d = tpcds.gen_q3(rows=rows, items=items, days=730, brands=brands,
-                     seed=seed)
+    d = _ingest(ctx, tpcds.gen_q3, rows=rows, items=items, days=730,
+                brands=brands, seed=seed)
     q = _pipeline(("q3", base, brands, manufact),
                   lambda: tpcds.make_q3(base, years=2, brands=brands,
                                         manufact=manufact))
-    year, brand, sums, total = q(d)
+    year, brand, sums, total = _execute(ctx, "handfused", q, d)
     return _rows(year, brand, sums) + [[int(total)]]
 
 
@@ -240,10 +289,10 @@ def _run_q7(params: dict, ctx: QueryContext):
     rows = int(params.get("rows", 2048))
     items = int(params.get("items", 64))
     seed = int(params.get("seed", 7))
-    d = tpcds.gen_q7(rows=rows, items=items, demos=256, promos=32,
-                     seed=seed)
+    d = _ingest(ctx, tpcds.gen_q7, rows=rows, items=items, demos=256,
+                promos=32, seed=seed)
     q = _pipeline(("q7", items), lambda: tpcds.make_q7(items))
-    return _rows(*q(d))
+    return _rows(*_execute(ctx, "handfused", q, d))
 
 
 # stage-IR variants (plan/catalog.py, ISSUE 13): the SAME queries
@@ -266,8 +315,10 @@ def _run_q5_fused(params: dict, ctx: QueryContext):
     stores = int(params.get("stores", 8))
     seed = int(params.get("seed", 5))
     cap = int(params.get("join_capacity", 1 << 12))
-    d = tpcds.gen_q5(rows=rows, stores=stores, days=60, seed=seed)
-    k, sales, rets, profit, of = plan_catalog.run_q5(d, stores, cap)
+    d = _ingest(ctx, tpcds.gen_q5, rows=rows, stores=stores, days=60,
+                seed=seed)
+    k, sales, rets, profit, of = _execute(
+        ctx, "stage", plan_catalog.run_q5, d, stores, cap)
     if bool(np.asarray(of)):
         raise RuntimeError("q5 join capacity overflow")
     return _rows(k, sales, rets, profit)
@@ -282,10 +333,11 @@ def _run_q3_fused(params: dict, ctx: QueryContext):
     brands = int(params.get("brands", 16))
     manufact = int(params.get("manufact", 3))
     seed = int(params.get("seed", 3))
-    d = tpcds.gen_q3(rows=rows, items=items, days=730, brands=brands,
-                     seed=seed)
-    year, brand, sums, total = plan_catalog.run_q3(
-        d, 10_957, years=2, brands=brands, manufact=manufact)
+    d = _ingest(ctx, tpcds.gen_q3, rows=rows, items=items, days=730,
+                brands=brands, seed=seed)
+    year, brand, sums, total = _execute(
+        ctx, "stage", plan_catalog.run_q3, d, 10_957, years=2,
+        brands=brands, manufact=manufact)
     return _rows(year, brand, sums) + [[int(total)]]
 
 
@@ -300,10 +352,10 @@ def _run_q72_fused(params: dict, ctx: QueryContext):
     max_week = int(params.get("max_week", 16))
     seed = int(params.get("seed", 72))
     cap = int(params.get("join_capacity", 1 << 17))
-    d = tpcds.gen_q72(cs_rows=rows, inv_rows=rows // 2, items=items,
-                      days=35, seed=seed)
-    i, w, c, of = plan_catalog.run_q72(d, items, max_week, cap,
-                                       week0=11_000 // 7)
+    d = _ingest(ctx, tpcds.gen_q72, cs_rows=rows, inv_rows=rows // 2,
+                items=items, days=35, seed=seed)
+    i, w, c, of = _execute(ctx, "stage", plan_catalog.run_q72, d,
+                           items, max_week, cap, week0=11_000 // 7)
     if bool(np.asarray(of)):
         raise RuntimeError("q72 join capacity overflow")
     return _rows(i, w, c)
@@ -355,9 +407,10 @@ def _run_q5_incremental(params: dict, ctx: QueryContext):
                 cap = max(cap, int(meta.get("cap", cap)))
     for b in range(upto, batches):
         ctx.check_cancel()
-        d = tpcds.gen_q5(rows=rows, stores=stores, days=60,
-                         seed=seed + 7919 * b)
-        outs, cap = _cat.run_q5_partials(
+        d = _ingest(ctx, tpcds.gen_q5, rows=rows, stores=stores,
+                    days=60, seed=seed + 7919 * b)
+        outs, cap = _execute(
+            ctx, "stage", _cat.run_q5_partials,
             (d.s_date, d.s_store, d.s_price, d.s_profit,
              d.r_date, d.r_store, d.r_amt, d.r_loss, d.d_date),
             stores, cap, ctx=ctx)
@@ -373,8 +426,10 @@ def _run_q5_incremental(params: dict, ctx: QueryContext):
     # dimension labels come from the BASE batch (st_id is a seeded
     # permutation; partials are keyed by store INDEX, so the labels
     # must not drift with the arriving batches)
-    d0 = tpcds.gen_q5(rows=stores, stores=stores, days=60, seed=seed)
-    k, sales, rets, profit, g_of = _cat.run_q5_finish(
+    d0 = _ingest(ctx, tpcds.gen_q5, rows=stores, stores=stores,
+                 days=60, seed=seed)
+    k, sales, rets, profit, g_of = _execute(
+        ctx, "stage", _cat.run_q5_finish,
         state[0], state[1], state[2], state[3], state[4],
         d0.st_id, stores)
     if bool(np.asarray(g_of)):
@@ -412,10 +467,11 @@ def _run_q72_incremental(params: dict, ctx: QueryContext):
                 cap = max(cap, int(meta.get("cap", cap)))
     for b in range(upto, batches):
         ctx.check_cancel()
-        d = tpcds.gen_q72(cs_rows=rows, inv_rows=rows // 2,
-                          items=items, days=35,
-                          seed=seed + 7919 * b)
-        outs, cap = _cat.run_q72_partials(
+        d = _ingest(ctx, tpcds.gen_q72, cs_rows=rows,
+                    inv_rows=rows // 2, items=items, days=35,
+                    seed=seed + 7919 * b)
+        outs, cap = _execute(
+            ctx, "stage", _cat.run_q72_partials,
             (d.cs_item, d.cs_date, d.cs_qty,
              d.inv_item, d.inv_date, d.inv_qty, d.item_id),
             items, max_week, cap, week0)
@@ -428,8 +484,9 @@ def _run_q72_incremental(params: dict, ctx: QueryContext):
     if _rc.cache_enabled() and batches > upto:
         _rc.CACHE.put_subplan(key, state,
                               {"upto": batches, "cap": cap})
-    i, w, c, g_of = _cat.run_q72_finish(state[0], state[1], items,
-                                        max_week, limit, week0)
+    i, w, c, g_of = _execute(ctx, "stage", _cat.run_q72_finish,
+                             state[0], state[1], items, max_week,
+                             limit, week0)
     if bool(np.asarray(g_of)):
         raise RuntimeError("q72 join capacity overflow")
     return _rows(i, w, c)

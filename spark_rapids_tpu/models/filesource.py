@@ -137,21 +137,27 @@ def run_q3_file(params: dict, ctx):
     seed = int(params.get("seed", 3))
     base = 10_957
     paths = q3_paths(rows, items, 730, brands, seed)
-    ss = _read(paths["store_sales"],
-               ["ss_sold_date_sk", "ss_item_sk", "ss_ext_sales_price"])
-    dd = _read(paths["date_dim"], ["d_moy", "d_year"])
-    it = _read(paths["item"], ["i_brand_id", "i_manufact_id"])
+
+    def load():
+        ss = _read(paths["store_sales"],
+                   ["ss_sold_date_sk", "ss_item_sk",
+                    "ss_ext_sales_price"])
+        dd = _read(paths["date_dim"], ["d_moy", "d_year"])
+        it = _read(paths["item"], ["i_brand_id", "i_manufact_id"])
+        return tpcds.Q3Data(
+            ss["ss_sold_date_sk"].data, ss["ss_item_sk"].data,
+            ss["ss_ext_sales_price"].data, dd["d_moy"].data,
+            dd["d_year"].data, it["i_brand_id"].data,
+            it["i_manufact_id"].data)
+
+    d = models._ingest(ctx, load)
     ctx.check_cancel()
-    d = tpcds.Q3Data(ss["ss_sold_date_sk"].data, ss["ss_item_sk"].data,
-                     ss["ss_ext_sales_price"].data, dd["d_moy"].data,
-                     dd["d_year"].data, it["i_brand_id"].data,
-                     it["i_manufact_id"].data)
     # SAME pipeline key as the in-memory runner: one shared executable
     q = models._pipeline(("q3", base, brands, manufact),
                          lambda: tpcds.make_q3(base, years=2,
                                                brands=brands,
                                                manufact=manufact))
-    year, brand, sums, total = q(d)
+    year, brand, sums, total = models._execute(ctx, "handfused", q, d)
     return models._rows(year, brand, sums) + [[int(total)]]
 
 
@@ -194,23 +200,26 @@ def run_q7_file(params: dict, ctx):
     items = int(params.get("items", 64))
     seed = int(params.get("seed", 7))
     paths = q7_paths(rows, items, 256, 32, seed)
-    ss = _read(paths["store_sales"],
-               ["ss_item_sk", "ss_cdemo_sk", "ss_promo_sk",
-                "ss_quantity", "ss_list_price", "ss_coupon_amt",
-                "ss_sales_price"])
-    cd = _read(paths["customer_demographics"], ["cd_match"])
-    pr = _read(paths["promotion"], ["p_match"])
-    it = _read(paths["item"], ["i_item_id"])
+
+    def load():
+        ss = _read(paths["store_sales"],
+                   ["ss_item_sk", "ss_cdemo_sk", "ss_promo_sk",
+                    "ss_quantity", "ss_list_price", "ss_coupon_amt",
+                    "ss_sales_price"])
+        cd = _read(paths["customer_demographics"], ["cd_match"])
+        pr = _read(paths["promotion"], ["p_match"])
+        it = _read(paths["item"], ["i_item_id"])
+        return tpcds.Q7Data(
+            ss["ss_item_sk"].data, ss["ss_cdemo_sk"].data,
+            ss["ss_promo_sk"].data, ss["ss_quantity"].data,
+            ss["ss_list_price"].data, ss["ss_coupon_amt"].data,
+            ss["ss_sales_price"].data, _jnp_bool(cd["cd_match"]),
+            _jnp_bool(pr["p_match"]), it["i_item_id"].data)
+
+    d = models._ingest(ctx, load)
     ctx.check_cancel()
-    d = tpcds.Q7Data(ss["ss_item_sk"].data, ss["ss_cdemo_sk"].data,
-                     ss["ss_promo_sk"].data, ss["ss_quantity"].data,
-                     ss["ss_list_price"].data,
-                     ss["ss_coupon_amt"].data,
-                     ss["ss_sales_price"].data,
-                     _jnp_bool(cd["cd_match"]),
-                     _jnp_bool(pr["p_match"]), it["i_item_id"].data)
     q = models._pipeline(("q7", items), lambda: tpcds.make_q7(items))
-    return models._rows(*q(d))
+    return models._rows(*models._execute(ctx, "handfused", q, d))
 
 
 # ------------------------------------------------------------------ q9
@@ -236,10 +245,15 @@ def run_q9_file(params: dict, ctx):
     rows = int(params.get("rows", 4096))
     seed = int(params.get("seed", 9))
     path = q9_path(rows, seed)
-    ss = _read(path, ["ss_quantity", "ss_ext_list_price",
-                      "ss_net_profit"])
+
+    def load():
+        ss = _read(path, ["ss_quantity", "ss_ext_list_price",
+                          "ss_net_profit"])
+        return (ss["ss_quantity"].data, ss["ss_ext_list_price"].data,
+                ss["ss_net_profit"].data)
+
+    data = models._ingest(ctx, load)
     ctx.check_cancel()
-    counts, avg_p, avg_n = tpcds.run_q9(
-        ss["ss_quantity"].data, ss["ss_ext_list_price"].data,
-        ss["ss_net_profit"].data)
+    counts, avg_p, avg_n = models._execute(ctx, "handfused",
+                                           tpcds.run_q9, *data)
     return models._rows(counts, avg_p, avg_n)

@@ -84,9 +84,11 @@ def is_enabled() -> bool:
 
 
 def enable_tracing() -> None:
-    """Turn on structured span tracing (independent of the metrics
-    switch: spans cost more than counters, so a metrics-on run does not
-    silently pay for them).  Span->journal and span->histogram fan-out
+    """Turn on structured span tracing of every kind.  The metrics
+    switch alone records the query timeline (kinds ``query``,
+    ``phase``, ``compile``: seven or eight spans per served query); ``op``,
+    ``stage``, shuffle and OOM spans cost more than counters and wait
+    for this switch.  Span->journal and span->histogram fan-out
     additionally requires the metrics switch."""
     TRACER.enabled = True
 
@@ -498,9 +500,20 @@ def _on_span_finish(rec: dict) -> None:
     JOURNAL.emit("span", **{k: v for k, v in rec.items() if k != "kind"})
 
 
+def _annotate(name: str):
+    """An entered ``jax.profiler.TraceAnnotation``: inside a profiler
+    session the span lands in the trace's host planes, on the device
+    plane's clock; outside one it is the profiler's own no-op."""
+    from jax.profiler import TraceAnnotation
+    ann = TraceAnnotation(name)
+    ann.__enter__()
+    return ann
+
+
 TRACER = Tracer(capacity=65536,
                 task_lookup=lambda: TASKS.tasks_for(),
-                on_finish=_on_span_finish)
+                on_finish=_on_span_finish,
+                timeline_ref=_SWITCH, annotate=_annotate)
 
 
 # ------------------------------------------------------- query profiler

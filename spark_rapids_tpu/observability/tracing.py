@@ -29,9 +29,17 @@ same JSONL dump) and at a span-duration histogram in MetricsRegistry
 (Prometheus exposition picks up per-op latency distributions for free).
 
 Everything is OFF by default.  When disabled, ``start_span`` returns a
-shared no-op span after ONE attribute read — no allocation, no lock —
+shared no-op span after attribute reads only — no allocation, no lock —
 so the instrumented layers (op_range, kudo, exchange, models) can call
 unconditionally.
+
+The query timeline (kinds ``query``, ``phase``, ``compile``: seven or
+eight spans per served query) is also recorded under the shared metrics
+switch (``timeline_ref``), so a server that counts says where each
+query's seconds went; every other kind keeps the tracing switch alone.
+A timeline span is mirrored into the profiler's trace as
+``srt:<name>`` through the injected ``annotate`` hook, which puts it on
+the device plane's clock inside a ``jax.profiler`` session.
 
 The module is dependency-free within the package: the task lookup and
 the finish hook are injected by ``observability/__init__`` (the same
@@ -51,6 +59,10 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Union
 MAX_ATTRS = 16          # bounded attributes per span
 MAX_ATTR_STR = 256      # value strings truncated beyond this
 ROOT_PARENT = 0         # parent_id of a trace root
+# the query timeline: recorded under the metrics switch too, mirrored
+# into the profiler's trace, and inheriting the query's id
+TIMELINE_KINDS = frozenset(("query", "phase", "compile"))
+ANNOTATION_PREFIX = "srt:"
 
 
 class SpanContext(NamedTuple):
@@ -104,7 +116,8 @@ class Span:
 
     __slots__ = ("tracer", "trace_id", "span_id", "parent_id", "name",
                  "span_kind", "t0_ns", "thread", "task", "attrs",
-                 "links", "_attached", "_ended", "_remote", "_stack")
+                 "links", "_attached", "_ended", "_remote", "_stack",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", trace_id: int, span_id: int,
                  parent_id: int, name: str, span_kind: str,
@@ -128,6 +141,8 @@ class Span:
         # tracer when attach=True): ending a span from a different
         # thread must pop the ORIGIN thread's stack, not the ender's
         self._stack: Optional[List["Span"]] = None
+        # the open profiler annotation of a timeline span
+        self._annotation = None
 
     # ------------------------------------------------------------ api
 
@@ -219,15 +234,25 @@ class Tracer:
     ``task_lookup``: zero-arg callable returning the current thread's
     task-id list (observability wires it to ``TASKS.tasks_for``); None
     leaves spans task-less.  ``on_finish``: called with each finished
-    span's record dict (observability wires journal + histogram)."""
+    span's record dict (observability wires journal + histogram).
+    ``timeline_ref``: object with a truthy ``.enabled`` (the shared
+    metrics switch) under which the TIMELINE_KINDS are recorded even
+    while ``enabled`` is off.  ``annotate``: called with
+    ``"srt:<name>"`` when a timeline span starts; returns an entered
+    context manager that is left when the span ends (observability
+    wires ``jax.profiler.TraceAnnotation``)."""
 
     def __init__(self, capacity: int = 65536,
                  task_lookup: Optional[Callable[[], list]] = None,
-                 on_finish: Optional[Callable[[dict], None]] = None):
+                 on_finish: Optional[Callable[[dict], None]] = None,
+                 timeline_ref=None,
+                 annotate: Optional[Callable[[str], object]] = None):
         self.enabled = False
         self.capacity = capacity
         self.task_lookup = task_lookup
         self.on_finish = on_finish
+        self.timeline_ref = timeline_ref
+        self.annotate = annotate
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=capacity)
         self._dropped = 0
@@ -244,7 +269,10 @@ class Tracer:
         root.  ``attach=False`` records the span without putting it on
         the thread's context stack (episodes that may close out of
         order, e.g. OOM block/unblock)."""
-        if not self.enabled:
+        timeline = kind in TIMELINE_KINDS
+        if not self.enabled and not (
+                timeline and self.timeline_ref is not None
+                and self.timeline_ref.enabled):
             return NOOP_SPAN
         stack = self._ctx.stack
         if parent is None and stack:
@@ -263,8 +291,19 @@ class Tracer:
                     task = ids[0] if len(ids) == 1 else list(ids)
             except Exception:
                 task = None
+        attrs = _clean_attrs(attrs)
+        if timeline and isinstance(parent, Span) and parent.attrs:
+            # every span of a served query carries its query_id
+            qid = parent.attrs.get("query_id")
+            if qid is not None and not (attrs and "query_id" in attrs):
+                attrs = dict(attrs or (), query_id=qid)
         span = Span(self, trace_id, _new_id(), parent_id, name, kind,
-                    task, _clean_attrs(attrs), attach)
+                    task, attrs, attach)
+        if timeline and self.annotate is not None:
+            try:
+                span._annotation = self.annotate(ANNOTATION_PREFIX + name)
+            except Exception:
+                pass  # the profiler must never break the traced path
         if attach:
             span._stack = stack
             stack.append(span)
@@ -315,6 +354,12 @@ class Tracer:
                     break
         if span._remote:
             return  # placeholder: nothing to record
+        ann, span._annotation = span._annotation, None
+        if ann is not None:
+            try:
+                ann.__exit__(None, None, None)
+            except Exception:
+                pass
         rec = {
             "kind": "span",
             "name": span.name,

@@ -209,10 +209,18 @@ def _tap_counts(plan: ir.StagePlan, env) -> list:
     return vals
 
 
-def _eval_node(node, env, reduce_axis: Optional[str]) -> None:
+def _eval_node(node, env, reduce_axis: Optional[str],
+               stage: str) -> None:
     """Evaluate one node into ``env`` (shared by the fused trace and
     the op-by-op walk — one evaluator, so the two engines cannot
-    drift)."""
+    drift), under the device-side name ``srt/<stage>/<node>``: the
+    node's first output column, which survives a rewrite of the op
+    underneath (a trace names a fusion by its HLO text otherwise)."""
+    with jax.named_scope(f"srt/{stage}/{node.outs()[0]}"):
+        _eval_kind(node, env, reduce_axis)
+
+
+def _eval_kind(node, env, reduce_axis: Optional[str]) -> None:
     if isinstance(node, ir.Project):
         env[node.out] = _eval(node.expr, env)
     elif isinstance(node, ir.JoinProbe):
@@ -401,7 +409,7 @@ class CompiledStage:
                     env[f"__mask__{inp.name}"] = jnp.ones(
                         rows, jnp.bool_)
             for node in plan.nodes:
-                _eval_node(node, env, None)
+                _eval_node(node, env, None, plan.name)
             outs = tuple(env[o] for o in plan.outputs)
             if taps:
                 vals = _tap_counts(plan, env)
@@ -429,7 +437,7 @@ class CompiledStage:
                 rows = first.shape[0] if first.ndim else 0
                 env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
             for node in plan.nodes:
-                _eval_node(node, env, reduce_axis)
+                _eval_node(node, env, reduce_axis, plan.name)
             return tuple(env[o] for o in plan.outputs)
 
         return fn
@@ -460,7 +468,9 @@ class CompiledStage:
         from spark_rapids_tpu import observability as _obs
         from spark_rapids_tpu.perf import jit_cache as _jc
 
-        args, parts, bucket = self._bind_args(inputs)
+        with _obs.TRACER.span("stage_bind", kind="phase") as span:
+            args, parts, bucket = self._bind_args(inputs)
+            span.set_attr("bucket", bucket)
         digest = run_digest or self._run_digest(parts)
         key_digest = f"{digest}|taps" if taps else digest
         fn = self._fused_callable(taps=taps)
@@ -481,17 +491,17 @@ class CompiledStage:
             ex = _jc.CACHE.get_or_build(
                 f"stage.{self.plan.name}", key_digest, bucket, build,
                 cost_bytes=_jc._tree_nbytes(args))
-            out = ex(*args)
         else:
             # cache disabled: keep ONE jit wrapper per shape class so
             # jit's trace cache still reuses the traced program — a
             # fresh wrapper per call would retrace+recompile every
             # query (the exchange._step_for discipline)
-            jf = self._nocache.get((digest, bucket, taps))
-            if jf is None:
-                jf = self._nocache.setdefault(
+            ex = self._nocache.get((digest, bucket, taps))
+            if ex is None:
+                ex = self._nocache.setdefault(
                     (digest, bucket, taps), jax.jit(fn))
-            out = jf(*args)
+        with _obs.TRACER.span("dispatch", kind="phase"):
+            out = ex(*args)
         counts = None
         if taps:
             counts, out = out[-1], out[:-1]
@@ -512,7 +522,7 @@ class CompiledStage:
             rows = first.shape[0] if first.ndim else 0
             env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
         for node in self.plan.nodes:
-            _eval_node(node, env, None)
+            _eval_node(node, env, None, self.plan.name)
         return env
 
     def _host_counts(self, env) -> list:
@@ -535,7 +545,17 @@ class CompiledStage:
         journal event either way.  Walls are measured past
         ``block_until_ready`` (an async backend's dispatch-only time
         would lie), and a first-call calibration's measurement time is
-        NOT folded into the winner's recorded wall."""
+        NOT folded into the winner's recorded wall.  The whole
+        execution is the timeline's ``stage_run:<plan>`` span, with
+        ``stage_bind``, ``stage_compile``, ``dispatch`` and
+        ``device_wait`` under it."""
+        from spark_rapids_tpu import observability as _obs
+
+        with _obs.TRACER.span(f"stage_run:{self.plan.name}",
+                              kind="phase") as span:
+            return self._run(inputs, span)
+
+    def _run(self, inputs: Mapping[str, Sequence], span) -> tuple:
         from spark_rapids_tpu import observability as _obs
 
         mode = fusion_mode()
@@ -571,8 +591,19 @@ class CompiledStage:
                     inputs, taps=taps)
                 compiled = bool(compile_ns)
                 outcome = "fused"
-            jax.block_until_ready(out)
+            with _obs.TRACER.span("device_wait", kind="phase"):
+                jax.block_until_ready(out)
             wall_ns = time.monotonic_ns() - t0
+        if span is not _obs.NOOP_SPAN:
+            from spark_rapids_tpu.perf.jit_cache import bucket_rows
+            rows = max((int(jnp.shape(inputs[i.name][0])[0])
+                        for i in self.plan.inputs if i.bucket),
+                       default=0)
+            bucket = bucket_rows(rows) if rows else 0
+            for k, v in (("digest", digest), ("engine", outcome),
+                         ("rows", rows), ("bucket", bucket),
+                         ("pad_rows", bucket - rows)):
+                span.set_attr(k, v)
         _obs.record_stage_fusion(
             self.plan.name, outcome, digest=digest,
             wall_ns=wall_ns, nodes=self.dispatch_count,
@@ -737,6 +768,7 @@ class CompiledStage:
         tapped row-count vector (None without ``taps``, and None when
         a sampled measurement won on sliced inputs — sliced counts
         would reconcile against nothing)."""
+        from spark_rapids_tpu import observability as _obs
         from spark_rapids_tpu.perf import calibrate
 
         parts, _bucket = self._shape_parts(inputs)
@@ -807,7 +839,8 @@ class CompiledStage:
                 inputs, run_digest=digest, taps=taps)
             if c:
                 compiled.append(c)
-        jax.block_until_ready(out)
+        with _obs.TRACER.span("device_wait", kind="phase"):
+            jax.block_until_ready(out)
         return (out, bool(compiled), outcome,
                 time.monotonic_ns() - t0, digest, sum(compiled),
                 counts)
@@ -941,7 +974,7 @@ def fused_pipeline_fn(pipeline: ir.Pipeline,
                     else 0
                 env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
             for node in stage.nodes:
-                _eval_node(node, env, reduce_axis)
+                _eval_node(node, env, reduce_axis, stage.name)
         return tuple(env[o] for o in last.outputs)
 
     return fn, n_args

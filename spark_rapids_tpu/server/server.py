@@ -653,7 +653,8 @@ class QueryServer:
                     attrs={"tenant": job.tenant,
                            "query_id": job.query_id,
                            "server_task_id": job.task_id,
-                           "demotions": job.demotions}):
+                           "demotions": job.demotions,
+                           "wait_ns": job.wait_ns}):
                 # profile session INSIDE the query-root span (begin
                 # captures the root trace context) and around the
                 # runner only — queue wait is the server's story, the

@@ -326,7 +326,12 @@ def test_srt_top_no_inputs_errors():
 def test_slo_burn_bundle_doctor_chain(tmp_path):
     obs.enable()
     obs.reset()
-    obs.enable_flight_recorder(out_dir=str(tmp_path / "inc"))
+    # the process-wide recorder limits bundles to one per 30 s: a
+    # bundle frozen by another test file on this worker just before
+    # must not suppress this one
+    prior_iv = obs.FLIGHT.min_interval_s
+    obs.enable_flight_recorder(out_dir=str(tmp_path / "inc"),
+                               min_interval_s=0.0)
     obs.enable_slo()
     obs.SLO.reset()
     try:
@@ -352,6 +357,7 @@ def test_slo_burn_bundle_doctor_chain(tmp_path):
                 if s["labels"] == ["acme"] and s["value"] == 1]
     finally:
         obs.disable_slo()
+        obs.FLIGHT.configure(min_interval_s=prior_iv)
         obs.disable_flight_recorder()
         obs.disable()
 
