@@ -85,6 +85,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "executions per calibrated kernel path"),
     "srt_stage_fusion_total": (
         "counter", "whole-stage executions by outcome"),
+    "srt_segment_sum_total": (
+        "counter", "segment sums traced by engine"),
     "srt_incidents_total": ("counter", "incident bundles written"),
     "srt_incidents_suppressed_total": (
         "counter", "incident triggers suppressed"),

@@ -23,9 +23,12 @@ TPU-first design decisions (vs the reference's row-iterator operators):
   * joins are the jittable padded-capacity inner join
     (ops/device_join.inner_join_device): static shapes, validity
     masks, int64 overflow accounting — XLA sees one fused program.
-  * group-bys ride jax.ops.segment_sum over dictionary-encoded keys
+  * group-bys are segment sums over dictionary-encoded keys
     (dimension keys ARE small dictionaries after the dim join, the
-    same reason Spark dictionary-encodes parquet strings).
+    same reason Spark dictionary-encodes parquet strings).  q3's ride
+    ops/segment_sum: exact one-hot products over 8-bit limbs on the
+    matrix unit; q5, q72 and q7 still ride jax.ops.segment_sum, a
+    scatter-add (ROADMAP S2b).
   * order-by is lax.sort over the padded group table with sentinel
     keys for invalid slots.
   * strings never enter the jitted program: dimension attributes are
@@ -53,6 +56,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from spark_rapids_tpu import observability as _obs
 from spark_rapids_tpu.ops.device_join import inner_join_device
+from spark_rapids_tpu.ops.segment_sum import segment_sum
 
 
 def _note_gen(source: str, **args) -> None:
@@ -598,10 +602,8 @@ def _q3_kernel(base, years, brands, manufact, month, limit,
         gid = jnp.where(keep, year_idx * brands + brand, 0)
         amt = jnp.where(keep, s_price, 0)
         with jax.named_scope("srt/q3/segment_sum"):
-            sums = reduce_sum(jax.ops.segment_sum(
-                amt, gid, num_segments=n_groups))
-            cnts = reduce_sum(jax.ops.segment_sum(
-                keep.astype(jnp.int64), gid, num_segments=n_groups))
+            sums = reduce_sum(segment_sum(amt, gid, n_groups))
+            cnts = reduce_sum(segment_sum(keep, gid, n_groups))
         gidx = jnp.arange(n_groups, dtype=jnp.int64)
         year_of_g = gidx // brands
         brand_of_g = gidx % brands

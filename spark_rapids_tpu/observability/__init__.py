@@ -263,6 +263,12 @@ STAGE_FUSION = METRICS.counter(
     "executable, unfused = op-by-op walk, compile = a fused "
     "executable was built this run)", labels=("stage", "outcome"),
     max_series=128)
+SEGMENT_SUM = METRICS.counter(
+    "srt_segment_sum_total",
+    "Segment sums built into executables by engine (dense = one-hot "
+    "products over 8-bit limbs on the matrix unit, scatter = "
+    "jax.ops.segment_sum); counted when a program is traced, not "
+    "when it runs", labels=("engine",))
 FLEET_EPOCH = METRICS.gauge(
     "srt_fleet_epoch",
     "Elastic-fleet membership epoch on this worker (bumps on every "
@@ -1363,6 +1369,14 @@ def record_stage_fusion(stage: str, outcome: str, *, digest: str = "",
                  digest=digest, wall_ns=int(wall_ns), nodes=int(nodes),
                  compiled=bool(compiled),
                  thread=threading.get_ident())
+
+
+def record_segment_sum(engine: str) -> None:
+    """Segment-sum hook (ops/segment_sum.py): one segment sum was
+    traced into a program on ``engine`` ('dense' / 'scatter').  The
+    choice is static per executable, so this counts builds."""
+    if _SWITCH.enabled:
+        SEGMENT_SUM.inc(labels=(engine,))
 
 
 def record_lockdep(kind: str, *, cycle=(), op: str = "", held=(),

@@ -99,6 +99,9 @@ _BIN = {
     "min": jnp.minimum,
 }
 
+# env key under which a trace leaves each SegmentSum node's engine
+_ENGINES = "__engines__"
+
 _CAST = {"i32": jnp.int32, "i64": jnp.int64, "f64": jnp.float64,
          "b": jnp.bool_}
 
@@ -239,9 +242,20 @@ def _eval_kind(node, env, reduce_axis: Optional[str]) -> None:
         env[f"{p}.valid"] = pairs.valid
         env[f"{p}.total"] = pairs.total
     elif isinstance(node, ir.SegmentSum):
-        env[node.out] = jax.ops.segment_sum(
-            _eval(node.value, env), _eval(node.ids, env),
-            num_segments=node.num_segments)
+        from spark_rapids_tpu.ops import segment_sum as _ss
+        value = node.value
+        if isinstance(value, ir.Un) and value.op == "i64":
+            # a widened predicate is a count: the helper counts
+            # booleans in one limb where an int64 takes eight
+            v = _eval(value.a, env)
+            if v.dtype != jnp.bool_:
+                v = v.astype(jnp.int64)
+        else:
+            v = _eval(value, env)
+        env.setdefault(_ENGINES, {})[node.out] = _ss.engine(
+            v.dtype, node.num_segments)
+        env[node.out] = _ss.segment_sum(
+            v, _eval(node.ids, env), node.num_segments)
     elif isinstance(node, ir.Sort):
         res = lax.sort(tuple(_eval(o, env) for o in node.operands),
                        num_keys=node.num_keys)
@@ -314,6 +328,9 @@ class CompiledStage:
         # reuse instead of retracing per call (bounded by the distinct
         # shape classes this stage object sees)
         self._nocache: Dict[tuple, object] = {}
+        # SegmentSum node -> "dense" | "scatter", left by the newest
+        # trace (static per executable; the profile record reads it)
+        self._engines: Dict[str, str] = {}
 
     # number of op dispatches the unfused walk pays (the fused program
     # pays exactly 1) — the before/after evidence in BENCH_r07
@@ -410,6 +427,7 @@ class CompiledStage:
                         rows, jnp.bool_)
             for node in plan.nodes:
                 _eval_node(node, env, None, plan.name)
+            self._engines.update(env.get(_ENGINES, {}))
             outs = tuple(env[o] for o in plan.outputs)
             if taps:
                 vals = _tap_counts(plan, env)
@@ -523,6 +541,7 @@ class CompiledStage:
             env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
         for node in self.plan.nodes:
             _eval_node(node, env, None, self.plan.name)
+        self._engines.update(env.get(_ENGINES, {}))
         return env
 
     def _host_counts(self, env) -> list:
@@ -730,7 +749,10 @@ class CompiledStage:
                            if engine == "unfused" else 1),
             "nodes_total": self.dispatch_count,
             "nodes": [{"kind": type(n).__name__,
-                       "outs": list(n.outs())}
+                       "outs": list(n.outs()),
+                       **({"engine": self._engines[n.out]}
+                          if isinstance(n, ir.SegmentSum)
+                          and n.out in self._engines else {})}
                       for n in self.plan.nodes],
             "inputs": ins,
         }

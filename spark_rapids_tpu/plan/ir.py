@@ -220,8 +220,12 @@ class JoinProbe(Node):
 
 @dataclass(frozen=True)
 class SegmentSum(Node):
-    """Hash-aggregate workhorse: ``segment_sum(value, ids,
-    num_segments)`` over dictionary-encoded group ids."""
+    """Hash-aggregate workhorse: ``ops.segment_sum.segment_sum(value,
+    ids, num_segments)`` over dictionary-encoded group ids: an exact
+    one-hot product on the matrix unit for integer and boolean values
+    into up to ``DENSE_MAX_SEGMENTS`` groups, ``jax.ops.segment_sum``
+    (a scatter-add) for floats and larger group tables.  A value
+    ``Un("i64", <predicate>)`` is counted from the booleans."""
     out: str
     value: Expr
     ids: Expr

@@ -125,6 +125,23 @@ def test_q3_fused_stage_compiles_at_sf10(shaped):
     assert _device_gib(compiled) < HBM_GIB
 
 
+def test_q3_kernel_compiles_to_matrix_products_at_sf10(shaped):
+    """The hand-written q3 at the benchmark's cardinalities (28,800,991
+    rows, 102,000 items, 2 x 1,000 groups): the chip's compiler makes
+    both segment sums matrix products and leaves no scatter."""
+    from spark_rapids_tpu.models import tpcds
+    kernel = tpcds._q3_kernel(10_957, 2, 1000, 3, 11, 100, lambda x: x)
+    args = [shaped(shape, dt) for shape, dt in
+            _vectors(28_800_991, (I32, I32, I64))
+            + _vectors(730, (I32, I32)) + _vectors(102_000, (I32, I32))]
+    compiled = jax.jit(kernel).lower(*args).compile()
+    assert _device_gib(compiled) < HBM_GIB
+    text = compiled.as_text()
+    products = [ln for ln in text.splitlines()
+                if "convolution(" in ln and "srt/q3/segment_sum" in ln]
+    assert len(products) == 2 and "scatter" not in text
+
+
 def test_q9_compiles_at_sf10_with_f64_divide(shaped):
     """q9 divides in float64 on the device; the chip has no f64 unit,
     so this is the compile that says whether XLA emulates or refuses."""
