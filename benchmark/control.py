@@ -38,7 +38,8 @@ def main(argv=None):
     ap.add_argument("--seeds", default="1,2,3")
     ap.add_argument("--size", choices=("full", "toy"), default="full")
     args = ap.parse_args(argv)
-    manifest = harness.load_json(harness.ROOT, "BENCHMARK.json")
+    manifest = harness.with_pending(
+        harness.load_json(harness.ROOT, "BENCHMARK.json"), args.workload)
     ok = True
     for seed in (int(s) for s in args.seeds.split(",")):
         cell = harness.Cell(manifest, args.workload, seed, args.size)

@@ -65,6 +65,33 @@ def load_json(*parts):
         return json.load(f)
 
 
+def with_pending(manifest, name):
+    """``manifest``, or where it holds no workload ``name`` and
+    ``pending/<name>.json`` does, a copy with that file's entries
+    copied in as a later PR would: an entry whose name a group already
+    has adds its ``workloads`` to that entry's list, any other is
+    appended.  So a cell that is built and not in ``BENCHMARK.json``
+    can still be rehearsed and tested; the driver's check never comes
+    this way."""
+    path = os.path.join(HERE, "pending", name + ".json")
+    if (name in [w["name"] for w in manifest["workloads"]]
+            or not os.path.exists(path)):
+        return manifest
+    merged = dict(manifest)
+    for group, entries in load_json(path).items():
+        if not isinstance(entries, list):
+            continue
+        have = {e["name"]: dict(e) for e in manifest.get(group, [])}
+        for e in entries:
+            if e["name"] in have and "workloads" in have[e["name"]]:
+                have[e["name"]]["workloads"] = (
+                    have[e["name"]]["workloads"] + e.get("workloads", []))
+            else:
+                have.setdefault(e["name"], e)
+        merged[group] = list(have.values())
+    return merged
+
+
 class Cell:
     """One workload of BENCHMARK.json with its files."""
 
@@ -153,7 +180,8 @@ def traced(driver, cell):
 
 def run_cell(args, manifest=None):
     """One run; returns (exit code, result or None)."""
-    manifest = manifest or load_json(ROOT, "BENCHMARK.json")
+    manifest = with_pending(
+        manifest or load_json(ROOT, "BENCHMARK.json"), args.workload)
     cell = Cell(manifest, args.workload, args.seed, args.size)
     for key in cell.config.get("must_be_unset", []):
         if os.environ.get(key):
