@@ -47,7 +47,9 @@ def _numpy_to_rows_reference(table, layout):
     out = np.zeros((rows, row_size), np.uint8)
     for c, st in zip(table.columns, starts):
         host = c.to_numpy()
-        b = host.view(np.uint8).reshape(rows, host.dtype.itemsize)
+        # decimal128 is four limbs a value, and a chip hands a 2-D
+        # array back in its own strides
+        b = np.ascontiguousarray(host).view(np.uint8).reshape(rows, -1)
         out[:, st:st + b.shape[1]] = b
     nb = (len(table.columns) + 7) // 8
     v = np.full((rows, nb), 0, np.uint8)
@@ -59,56 +61,6 @@ def _numpy_to_rows_reference(table, layout):
     return out
 
 
-def _calibrate_rowconv_path(table, layout):
-    """On a TPU, time the Pallas tile kernel against the XLA stack path
-    on a small slice and enable the winner for the timed run.  No-op
-    off-TPU or when the operator pinned a choice via env.  A Pallas
-    failure — a compile refusal included — propagates: the bench never
-    turns a kernel failure into the other path.  The verdict is cached
-    per (schema digest, backend) by perf/calibrate."""
-    import os
-
-    if jax.default_backend() != "tpu":
-        return "stack"
-    if os.environ.get("SPARK_RAPIDS_TPU_PALLAS_ROWCONV"):
-        return "pinned"
-    import jax.numpy as jnp
-
-    from spark_rapids_tpu.ops import row_conversion as RC
-    from spark_rapids_tpu.ops.row_assembly_pallas import \
-        assemble_fixed_words_pallas
-    from spark_rapids_tpu.perf import calibrate
-    from spark_rapids_tpu.perf.jit_cache import schema_digest
-
-    key = "%s@%s" % (schema_digest([c.dtype for c in table.columns]),
-                     jax.default_backend())
-    verdict = calibrate.cached_verdict(key)
-    if verdict is None:
-        starts, voff, fixed = layout
-        row_size = (fixed + 7) // 8 * 8
-        small = [type(c)(c.dtype, 1 << 14, data=c.data[:1 << 14],
-                         validity=None) for c in table.columns]
-
-        def timed(fn):
-            out = fn(small, starts, voff, row_size)     # compile
-            jax.block_until_ready(out)
-            t0 = time.perf_counter()
-            for _ in range(5):
-                last = fn(small, starts, voff, row_size)
-            jax.block_until_ready(last)
-            return out, time.perf_counter() - t0
-
-        w_p, t_p = timed(assemble_fixed_words_pallas)
-        w_s, t_s = timed(RC._assemble_fixed_words)
-        if not jnp.array_equal(w_p, w_s):
-            raise AssertionError("Pallas to-rows bytes != stack bytes")
-        verdict = "pallas" if t_p < t_s else "stack"
-        calibrate.store_verdict(key, verdict)
-    if verdict == "pallas":
-        os.environ["SPARK_RAPIDS_TPU_PALLAS_ROWCONV"] = "1"
-    return verdict
-
-
 def run():
     from spark_rapids_tpu.ops import row_conversion as RC
 
@@ -116,7 +68,6 @@ def run():
     ncols = 212
     table = _make_table(rows, ncols)
     layout = RC.compute_layout([c.dtype for c in table.columns])
-    rowconv_path = _calibrate_rowconv_path(table, layout)
     row_size = (layout[2] + 7) // 8 * 8
     total_bytes = rows * row_size
 
@@ -163,7 +114,6 @@ def run():
         "value": round(gbps, 3),
         "unit": "GB/s",
         "vs_baseline": round(gbps / gbps_np, 3),
-        "rowconv_path": rowconv_path,
     }
 
 

@@ -6,7 +6,7 @@ drives the system through the entry points a user calls — the resident
 ``QueryServer`` (submit/poll as two tenants) over the TPC-DS catalog at
 SF10 (``store_sales`` = 28,800,991 rows, TPC-DS spec table 3-2), the
 JCUDF row conversion at the reference benchmark's shape (212 columns x
-2^19 rows; the stack path, then the opt-in Pallas kernels) and the
+2^19 rows) and the
 eager group-by / join operators — and compares every
 answer with a plain numpy reference computed here over the same seeded
 arrays.  Any phase failing, any answer differing, any warm call that
@@ -14,6 +14,7 @@ compiles: non-zero exit.  No timing printed here is a benchmark number.
 
     python chip_smoke.py                   # one chip, full size
     python chip_smoke.py --chips 4         # mesh phase only, four chips
+    python chip_smoke.py --phases rowconv  # one chip, that phase only
     JAX_PLATFORMS=cpu python chip_smoke.py --size toy     # CPU rehearsal
 
 The last line of standard output is one JSON object naming the device
@@ -34,11 +35,21 @@ import time
 # bench_all.py shapes (rows, groups, join keyspace).
 SIZES = {
     "full": dict(rows=28_800_991, join_capacity=1 << 23,
-                 rowconv_rows=1 << 19,
+                 rowconv_rows=1 << 19, rowconv_schemas=8,
                  ops=(10_000_000, 10_000, 1_000_000), xchg_rows=1 << 22),
     "toy": dict(rows=4096, join_capacity=1 << 12, rowconv_rows=4096,
+                rowconv_schemas=4,
                 ops=(1 << 16, 100, 1 << 12), xchg_rows=1 << 12),
 }
+# (rows, columns, nulls in every column, every fifth column a
+# decimal128): the schemas around the 212-column phase.  On a TPU the
+# Pallas tile kernel takes the rows its VMEM holds (599 cycled columns
+# is the widest) and XLA's word assembly the wider ones; the toy size
+# runs the first four (the wide ones compile for a minute on a CPU).
+ROWCONV_SCHEMAS = ((7, 3, True, False), (100, 8, True, False),
+                   (1000, 20, True, True), (1024, 33, False, True),
+                   (5000, 212, True, False), (3000, 500, True, False),
+                   (3000, 599, True, False), (3000, 1000, True, False))
 Q5_STORES = 8
 Q5_DAYS = 60
 Q3 = dict(items=128, brands=16, manufact=3)
@@ -273,9 +284,9 @@ def phase_serve(rows: int, cap: int, seed0: int,
 def phase_rowconv(rows: int, compiles: Compiles) -> None:
     """convert_to_rows -> convert_from_rows round trip at the reference
     benchmark's shape (benchmarks/row_conversion.cpp: 212 cycled
-    fixed-width columns): the default stack path, then the opt-in
-    Pallas tile kernels.  Both must produce numpy's bytes, so Pallas
-    bytes == stack bytes."""
+    fixed-width columns) on the engine a fixed-width schema has per
+    direction on this backend; both directions must produce numpy's
+    bytes."""
     import jax
     import numpy as np
 
@@ -283,8 +294,6 @@ def phase_rowconv(rows: int, compiles: Compiles) -> None:
     from spark_rapids_tpu.ops import row_conversion as RC
     from spark_rapids_tpu.perf.jit_cache import CACHE
 
-    flag = "SPARK_RAPIDS_TPU_PALLAS_ROWCONV"
-    check(flag not in os.environ, f"{flag} is this phase's to set")
     table = bench_impl._make_table(rows, 212)
     schema = [c.dtype for c in table.columns]
     layout = RC.compute_layout(schema)
@@ -307,27 +316,107 @@ def phase_rowconv(rows: int, compiles: Compiles) -> None:
                   f"from-rows column {i} ({c.dtype.kind}) differs")
         return seconds
 
-    def kernels_built():
-        return {k: v["misses"] for k, v in CACHE.stats()["kernels"].items()
-                if k.startswith(("pallas.", "row_conversion."))}
-
     calls = timed_twice(round_trip, compiles, (0, 0))
-    say(phase="rowconv", path="stack", rows=rows, columns=212,
-        row_bytes=row_size, calls=calls, kernels=kernels_built(),
-        device=device_bytes())
-    os.environ[flag] = "1"
-    try:
-        calls = timed_twice(round_trip, compiles, (0, 0))
-    finally:
-        del os.environ[flag]
-    built = kernels_built()
-    check(built.get("pallas.to_rows") and built.get("pallas.from_rows"),
-          f"the Pallas kernels did not run: {built}")
-    # row_conversion interprets the kernels on the CPU backend only
-    say(phase="rowconv", path="pallas", rows=rows, calls=calls,
-        kernels=built, device=device_bytes(),
-        pallas=("interpreted" if jax.default_backend() == "cpu"
-                else "compiled"))
+    built = {k: v["misses"] for k, v in CACHE.stats()["kernels"].items()
+             if k.startswith(("pallas.", "row_conversion."))}
+    # to-rows is the Pallas tile kernel on a TPU, XLA's word assembly
+    # on a rehearsal; from-rows is the word slices everywhere
+    to_rows = ("pallas.to_rows" if jax.default_backend() == "tpu"
+               else "row_conversion.to_rows")
+    check(built.get(to_rows) and built.get("row_conversion.from_rows"),
+          f"an engine did not run: {built}")
+    say(phase="rowconv", rows=rows, columns=212, row_bytes=row_size,
+        calls=calls, kernels=built, device=device_bytes())
+
+
+def schema_table(rows: int, columns: int, nulls: bool, decimals: bool):
+    """A seeded table of the cycled fixed-width dtypes over the whole
+    range of each, optionally with nulls in every column and a
+    decimal128 in every fifth place."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from spark_rapids_tpu.columns import dtypes
+    from spark_rapids_tpu.columns.column import Column
+    from spark_rapids_tpu.columns.table import Table
+
+    cycle = [dtypes.INT64, dtypes.INT32, dtypes.FLOAT64, dtypes.FLOAT32,
+             dtypes.INT16, dtypes.INT8, dtypes.BOOL8,
+             dtypes.TIMESTAMP_MICROS]
+    rng = np.random.default_rng(rows + columns)
+    cols = []
+    for i in range(columns):
+        dt = cycle[i % len(cycle)]
+        valid = (rng.integers(0, 2, rows).astype(np.uint8) if nulls
+                 else None)
+        if decimals and i % 5 == 4:
+            limbs = rng.integers(-2 ** 31, 2 ** 31, (rows, 4))
+            cols.append(Column(
+                dtypes.decimal128(-2), rows,
+                data=jnp.asarray(limbs.astype(np.int32)),
+                validity=None if valid is None else jnp.asarray(valid)))
+            continue
+        if dt.kind == "float32":
+            arr = rng.normal(size=rows).astype(np.float32)
+        elif dt.kind == "float64":
+            arr = rng.normal(size=rows)
+        elif dt.kind == "bool8":
+            arr = rng.integers(0, 2, rows).astype(np.uint8)
+        else:
+            info = np.iinfo(dt.np_dtype)
+            arr = rng.integers(info.min, info.max, rows,
+                               endpoint=True).astype(dt.np_dtype)
+        cols.append(Column.from_numpy(arr, validity=valid, dtype=dt))
+    return Table(cols)
+
+
+def phase_rowconv_schemas(count: int) -> None:
+    """The round trip over the first ``count`` of ROWCONV_SCHEMAS:
+    to-rows must give numpy's bytes on the engine the schema has on
+    this backend, and every column and validity vector must come
+    back."""
+    import jax
+    import numpy as np
+
+    import bench_impl
+    from spark_rapids_tpu import observability as obs
+    from spark_rapids_tpu.ops import row_conversion as RC
+
+    def engines():
+        fam = obs.METRICS.snapshot().get("srt_row_conversion_total", {})
+        return {":".join(s["labels"]): s["value"]
+                for s in fam.get("series", [])}
+
+    for rows, columns, nulls, decimals in ROWCONV_SCHEMAS[:count]:
+        table = schema_table(rows, columns, nulls, decimals)
+        schema = [c.dtype for c in table.columns]
+        layout = RC.compute_layout(schema)
+        row_size = (layout[2] + 7) // 8 * 8
+        want = bench_impl._numpy_to_rows_reference(table, layout)
+        before = engines()
+        rows_col = RC.convert_to_rows(table)
+        back = RC.convert_from_rows(rows_col, schema)
+        ran = sorted(k for k, v in engines().items()
+                     if v > before.get(k, 0))
+        to_rows = ("pallas" if jax.default_backend() == "tpu"
+                   and RC._tile_fits(schema, row_size) else "words")
+        what = f"{rows} x {columns} (nulls {nulls}, decimals {decimals})"
+        check(ran == ["from_rows:words", "to_rows:" + to_rows],
+              f"{what}: engines {ran}")
+        got = np.asarray(rows_col.children[0].data).view(np.uint8)
+        check(np.array_equal(got.reshape(rows, row_size), want),
+              f"{what}: to-rows bytes != numpy bytes")
+        for i, (b, c) in enumerate(zip(back.columns, table.columns)):
+            check(np.asarray(b.data).tobytes()
+                  == np.asarray(c.data).tobytes(),
+                  f"{what}: from-rows column {i} ({c.dtype.kind}) differs")
+            valid = (np.ones(rows, np.uint8) if c.validity is None
+                     else np.asarray(c.validity))
+            check(np.array_equal(np.asarray(b.validity), valid),
+                  f"{what}: from-rows validity {i} differs")
+        say(phase="rowconv_schema", rows=rows, columns=columns,
+            nulls=nulls, decimals=decimals, row_bytes=row_size,
+            engines=ran)
 
 
 # ------------------------------------------------------------ phase: ops
@@ -526,6 +615,8 @@ def main(argv=None) -> int:
                     help="4 = the mesh phase and its comparison only")
     ap.add_argument("--seed", type=int, default=0,
                     help="added to every generated data set's seed")
+    ap.add_argument("--phases", default="serve,rowconv,ops",
+                    help="the one-chip phases to run, comma-separated")
     args = ap.parse_args(argv)
     toy = args.size == "toy"
     size = SIZES[args.size]
@@ -568,10 +659,15 @@ def main(argv=None) -> int:
         phase_mesh(size["rows"], size["join_capacity"], size["xchg_rows"],
                    args.seed, compiles)
     else:
-        phase_serve(size["rows"], size["join_capacity"], args.seed,
-                    compiles)
-        phase_rowconv(size["rowconv_rows"], compiles)
-        phase_ops(*size["ops"], args.seed, compiles)
+        phases = args.phases.split(",")
+        if "serve" in phases:
+            phase_serve(size["rows"], size["join_capacity"], args.seed,
+                        compiles)
+        if "rowconv" in phases:
+            phase_rowconv(size["rowconv_rows"], compiles)
+            phase_rowconv_schemas(size["rowconv_schemas"])
+        if "ops" in phases:
+            phase_ops(*size["ops"], args.seed, compiles)
     say(phase="done", seconds=round(time.perf_counter() - t0, 1))
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

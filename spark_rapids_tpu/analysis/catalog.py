@@ -87,6 +87,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "whole-stage executions by outcome"),
     "srt_segment_sum_total": (
         "counter", "segment sums traced by engine"),
+    "srt_row_conversion_total": (
+        "counter", "eager row conversions by direction and engine"),
     "srt_incidents_total": ("counter", "incident bundles written"),
     "srt_incidents_suppressed_total": (
         "counter", "incident triggers suppressed"),
@@ -213,7 +215,8 @@ KNOBS: Dict[str, str] = {
     "SPARK_RAPIDS_TPU_CALIB_CACHE_TTL": "verdict file TTL seconds",
     "SPARK_RAPIDS_TPU_CALIB_BUDGET_S": "calibration wall budget",
     "SPARK_RAPIDS_TPU_PALLAS_ROWCONV":
-        "pin the Pallas row-conversion path on/off",
+        "=1 routes the string paste of convert_to_rows through the "
+        "Pallas tile kernel (fixed-width conversion reads no knob)",
     "SPARK_RAPIDS_TPU_KUDO_CRC": "=0 disables kudo KCRC trailers",
     "SPARK_RAPIDS_TPU_DIST_MESH":
         "0=process harness, auto=attempt jax.distributed mesh",

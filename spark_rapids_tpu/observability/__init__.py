@@ -269,6 +269,13 @@ SEGMENT_SUM = METRICS.counter(
     "products over 8-bit limbs on the matrix unit, scatter = "
     "jax.ops.segment_sum); counted when a program is traced, not "
     "when it runs", labels=("engine",))
+ROW_CONVERSION = METRICS.counter(
+    "srt_row_conversion_total",
+    "Eager JCUDF row conversions by direction (to_rows / from_rows) "
+    "and engine (words = row words composed or sliced by XLA, pallas = "
+    "the to-rows tile kernel on a TPU, gather = byte gather for "
+    "strings and rows of differing size)",
+    labels=("direction", "engine"))
 FLEET_EPOCH = METRICS.gauge(
     "srt_fleet_epoch",
     "Elastic-fleet membership epoch on this worker (bumps on every "
@@ -1377,6 +1384,15 @@ def record_segment_sum(engine: str) -> None:
     choice is static per executable, so this counts builds."""
     if _SWITCH.enabled:
         SEGMENT_SUM.inc(labels=(engine,))
+
+
+def record_row_conversion(direction: str, engine: str) -> None:
+    """Row-conversion hook (ops/row_conversion.py): one eager
+    ``convert_to_rows`` / ``convert_from_rows`` call ran on ``engine``
+    ('words' / 'pallas' / 'gather').  The call's ``to_rows`` /
+    ``from_rows`` span carries rows, bytes and the same engine."""
+    if _SWITCH.enabled:
+        ROW_CONVERSION.inc(labels=(direction, engine))
 
 
 def record_lockdep(kind: str, *, cycle=(), op: str = "", held=(),

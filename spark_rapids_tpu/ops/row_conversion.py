@@ -33,8 +33,19 @@ The eager graph is additionally routed through the process-wide kernel
 compile cache (spark_rapids_tpu/perf/jit_cache.py): fixed-width
 conversions compile once per (schema digest, power-of-two row bucket)
 and every later batch in the same bucket reuses the executable with
-zero XLA compilation.  SPARK_RAPIDS_TPU_JIT_CACHE=0 falls back to the
-uncached (still width-grouped) graph.
+zero XLA compilation.  SPARK_RAPIDS_TPU_JIT_CACHE=0 runs the same
+graphs uncached.
+
+Fixed-width schemas read no environment variable and have one engine
+per direction and backend: to-rows composes row words (on a TPU in the
+Pallas tile kernel of ops/row_assembly_pallas.py, elsewhere by XLA:
+_assemble_fixed_words), from-rows transposes the word matrix once
+(_transpose_row_words) and slices them (_extract_fixed_words: every
+field a static slice, no gather) whenever the buffer holds uniform
+rows, which is read from the buffer; rows of differing size and schemas with
+strings keep the byte gather (_gather_fixed_region).  Eager calls
+record the spans ``to_rows`` / ``from_rows`` and
+srt_row_conversion_total{direction,engine}.
 
 Variable-width rows are assembled per-row padded then compacted by a
 gather keyed on searchsorted(row_offsets) — vectorized, no per-row loops.
@@ -233,10 +244,8 @@ def _assemble_fixed_words(cols, starts, validity_offset,
     per-byte python validity stacking, which _validity_bytes now packs
     in one vectorized scatter-add (a 212-column schema lowers+compiles
     in ~1 s).  Recompiles across batch sizes are absorbed by the
-    compile cache (perf/jit_cache.py row bucketing); the single-pass
-    Pallas tile kernel (row_assembly_pallas.py, env opt-in in
-    convert_to_rows) consumes the same build_plan.  Returns flat packed
-    u32 LE words."""
+    compile cache (perf/jit_cache.py row bucketing).  Returns flat
+    packed u32 LE words."""
     rows = cols[0].length
     n_words = row_size // 4
     inputs, plan = build_plan(cols, starts, validity_offset, n_words)
@@ -266,9 +275,9 @@ def field_word_slots(dt: DType, st: int):
     """[(word_index, shift_bits, nbits)] for the value pieces of one
     fixed-width field at byte offset `st` — THE single source of the
     JCUDF word layout.  Consumed by build_plan (assembly: piece arrays
-    zip with these coordinates) and by the Pallas from-rows extraction
-    plan (row_assembly_pallas.build_extract_plan), so the two
-    directions cannot drift."""
+    zip with these coordinates) and by _extract_fixed_words (from-rows
+    slices the same coordinates), so the two directions cannot
+    drift."""
     w = st // 4
     size = _col_byte_size(dt)
     if dt.kind == Kind.DECIMAL128:
@@ -287,8 +296,7 @@ def build_plan(cols: Sequence[Column], starts: Sequence[int],
     (rows, 2) u32 bitcasts are not tile-safe on this backend, see
     docs/tpu_design.md §2), and the (word_index, left_shift_bits) each
     lands at.  Word coordinates come from field_word_slots (the shared
-    layout source); this function supplies the matching piece arrays.
-    Consumed by the Pallas tile kernel (ops/row_assembly_pallas.py)."""
+    layout source); this function supplies the matching piece arrays."""
     inputs = []
     plan = []
 
@@ -342,44 +350,109 @@ def _is_traced(cols: Sequence[Column]) -> bool:
                if c.data is not None)
 
 
+def _pad_rows(arr: jnp.ndarray, bucket: int) -> jnp.ndarray:
+    """``arr`` zero-padded along axis 0 to ``bucket`` rows; ``arr``
+    itself where it already has them.  Neither direction donates its
+    operands (no result has an operand's shape, so a donation frees
+    nothing sooner), so the caller's buffer needs no protecting copy:
+    at 212 columns x 2^20 rows those copies were 63 of to-rows' 84 ms
+    (PERF.md, Findings, PR 32)."""
+    n = int(arr.shape[0])
+    if n == bucket:
+        return arr
+    return jnp.pad(arr, [(0, bucket - n)] + [(0, 0)] * (arr.ndim - 1))
+
+
 def _to_rows_fixed_cached(cols, schema, starts, validity_offset,
-                          row_size, rows) -> jnp.ndarray:
+                          row_size, rows, engine: str) -> jnp.ndarray:
     """Fixed-width to-rows through the process compile cache: operands
-    pad to the power-of-two row bucket, the width-grouped kernel
-    compiles once per (schema digest, bucket) with the padded operands
-    donated (TPU), and the padded tail rows are sliced off."""
+    pad to the power-of-two row bucket, the assembly compiles once per
+    (engine, schema digest, bucket), and the padded tail rows are
+    sliced off.  ``engine``: ``pallas`` builds the row tiles in VMEM
+    (ops/row_assembly_pallas.py, a TPU's engine), ``words`` is the XLA
+    word path."""
     from spark_rapids_tpu.perf import jit_cache as _jc
 
     nullable = tuple(c.validity is not None for c in cols)
     digest = _jc.schema_digest(schema, nullable,
                                extra=f"to_rows:{row_size}")
     bucket = _jc.bucket_rows(rows)
-    datas = tuple(_jc.pad_axis0(c.data, bucket) for c in cols)
+    datas = tuple(_pad_rows(c.data, bucket) for c in cols)
     valids = tuple(None if c.validity is None
-                   else _jc.pad_axis0(c.validity, bucket) for c in cols)
+                   else _pad_rows(c.validity, bucket) for c in cols)
     schema_t = tuple(schema)
     starts_t = tuple(starts)
 
     def kernel(datas, valids):
         kcols = [Column(dt, bucket, data=d, validity=v)
                  for dt, d, v in zip(schema_t, datas, valids)]
+        if engine == "pallas":
+            from spark_rapids_tpu.ops.row_assembly_pallas import \
+                assemble_rows_pallas
+            inputs, plan = build_plan(kcols, starts_t, validity_offset,
+                                      row_size // 4)
+            return assemble_rows_pallas(inputs, plan, bucket,
+                                        row_size // 4)
         return _assemble_fixed_words(kcols, starts_t, validity_offset,
                                      row_size)
 
     words = _jc.CACHE.cached_call(
-        "row_conversion.to_rows", digest, kernel, (datas, valids),
-        bucket=bucket, donate_argnums=(0,))
+        "pallas.to_rows" if engine == "pallas"
+        else "row_conversion.to_rows", digest, kernel, (datas, valids),
+        bucket=bucket)
+    if bucket == rows:
+        return words
     return words[: rows * (row_size // 4)]
+
+
+def _tile_fits(schema, row_size: int) -> bool:
+    """Whether the Pallas to-rows tile of this schema fits the chip's
+    VMEM (row_assembly_pallas.tile_fits_vmem)."""
+    from spark_rapids_tpu.ops.row_assembly_pallas import tile_fits_vmem
+
+    narrow = sum(1 for dt in schema if _col_byte_size(dt) < 4)
+    return tile_fits_vmem(row_size, narrow + (len(schema) + 7) // 8)
+
+
+def _note(span, direction: str, engine: str, rows: int,
+          nbytes: int) -> None:
+    """One eager conversion on ``engine`` into the open ``span`` and
+    the srt_row_conversion_total counter."""
+    from spark_rapids_tpu import observability as _obs
+
+    span.set_attr("rows", int(rows))
+    span.set_attr("bytes", int(nbytes))
+    span.set_attr("engine", engine)
+    _obs.record_row_conversion(direction, engine)
 
 
 def convert_to_rows(table: Table) -> Column:
     """Table -> LIST<INT8> column of JCUDF rows (RowConversion.convertToRows,
-    RowConversionJni.cpp).  Fixed-width and string columns."""
+    RowConversionJni.cpp).  Fixed-width and string columns.  An eager
+    call is the timeline span ``to_rows`` (it ends where the call
+    returns, at the enqueue: the host's share of the conversion); under
+    a jit trace nothing is recorded."""
+    from spark_rapids_tpu import observability as _obs
+
+    if not table.columns:
+        raise ValueError("cannot convert empty table")
+    if _is_traced(table.columns):
+        return _to_rows(table, True)[0]
+    with _obs.TRACER.start_span("to_rows", kind="phase") as span:
+        out, engine = _to_rows(table, False)
+        _note(span, "to_rows", engine, table.num_rows,
+              out.children[0].length)
+    return out
+
+
+def _to_rows(table: Table, traced: bool) -> Tuple[Column, str]:
+    """(rows column, engine): ``words`` and ``pallas`` compose each row
+    word from column vectors (fixed-width schemas; XLA, or on a TPU the
+    tile kernel); ``gather`` pads every row to the longest and compacts
+    by a gather (schemas with strings)."""
     from spark_rapids_tpu.perf import jit_cache as _jc
 
     cols = table.columns
-    if not cols:
-        raise ValueError("cannot convert empty table")
     rows = table.num_rows
     schema = [c.dtype for c in cols]
     starts, validity_offset, fixed_size = compute_layout(schema)
@@ -387,24 +460,27 @@ def convert_to_rows(table: Table) -> Column:
     str_cols = [c for c in cols if c.dtype.is_string]
     if not str_cols:
         row_size = _round_up(fixed_size, JCUDF_ROW_ALIGNMENT)
-        if os.environ.get("SPARK_RAPIDS_TPU_PALLAS_ROWCONV") == "1":
-            # single-pass Pallas tile kernel (opt-in until profiled on
-            # real hardware); interpret mode on the CPU backend.  The
-            # wrapper consults the compile cache itself.
-            from spark_rapids_tpu.ops.row_assembly_pallas import \
-                assemble_fixed_words_pallas
-            data = assemble_fixed_words_pallas(
-                cols, starts, validity_offset, row_size,
-                interpret=jax.default_backend() == "cpu")
-        elif _jc.cache_enabled() and rows > 0 and not _is_traced(cols):
+        # one engine a backend, chosen from chip runs (PERF.md,
+        # Findings, PR 32): on a TPU the Pallas tile kernel for every
+        # row its tile holds in VMEM, elsewhere (and for wider rows,
+        # under a jit trace, and without the executable cache) the XLA
+        # word path, which is also the kernel's reference
+        engine = "words"
+        if _jc.cache_enabled() and rows > 0 and not traced:
+            if jax.default_backend() == "tpu" and _tile_fits(schema,
+                                                             row_size):
+                engine = "pallas"
             data = _to_rows_fixed_cached(cols, schema, starts,
-                                         validity_offset, row_size, rows)
+                                         validity_offset, row_size, rows,
+                                         engine)
         else:
             data = _assemble_fixed_words(cols, starts, validity_offset,
                                          row_size)
         offsets = jnp.arange(rows + 1, dtype=_I32) * _I32(row_size)
-        return Column.make_list_from_parts(offsets, data,
-                                           nbytes=rows * row_size)
+        out = Column.make_list_from_parts(offsets, data,
+                                          nbytes=rows * row_size)
+        _note_uniform(out.offsets, row_size)
+        return out, engine
 
     # variable-width path
     str_lens = [c.string_lengths() for c in str_cols]
@@ -445,7 +521,7 @@ def convert_to_rows(table: Table) -> Column:
         m = j[None, :] < lens[:, None]
         mat = _masked_row_scatter(mat, dest, chars, m)
     flat = _compact(mat, offsets, row_sizes)
-    return Column.make_list_from_parts(offsets, flat)
+    return Column.make_list_from_parts(offsets, flat), "gather"
 
 
 def _masked_row_scatter(mat, dest, src, mask):
@@ -525,88 +601,185 @@ def _decode_validity(region: jnp.ndarray, schema, validity_offset: int):
             & _U8(1)).astype(jnp.uint8)
 
 
-# uniformity verdicts memoized per offsets array: the host readback +
-# O(rows) scan below would otherwise run on EVERY eager from-rows call
-# (a synchronous device-to-host copy).  Keyed by id()
-# with a weakref guard — the finalizer drops the entry when the array
-# dies, so a recycled id can never resurrect a stale verdict.
+# Whether an offsets array is 0, row_size, 2 * row_size, ...: known
+# per array object.  convert_to_rows notes it for the offsets it makes
+# (true by construction); for offsets from elsewhere the first
+# from-rows call reads them back and scans them (a synchronous
+# device-to-host copy and an O(rows) pass) and later calls on the same
+# array find the verdict.  Keyed by id() with a weakref guard: the
+# finalizer drops the entry when the array dies, so a recycled id can
+# never resurrect a stale verdict.
 _UNIFORM_VERDICTS: dict = {}
+
+
+def _note_uniform(offs, row_size: int, verdict: bool = True) -> None:
+    import weakref
+
+    if isinstance(offs, jax.core.Tracer):
+        return
+    key = id(offs)
+    try:
+        ref = weakref.ref(offs,
+                          lambda _r: _UNIFORM_VERDICTS.pop(key, None))
+    except TypeError:
+        return
+    if len(_UNIFORM_VERDICTS) > 512:
+        _UNIFORM_VERDICTS.clear()
+    _UNIFORM_VERDICTS[key] = (ref, row_size, verdict)
 
 
 def _uniform_row_offsets(offs, rows: int, row_size: int,
                          nbytes_total: int) -> bool:
     """True when the list column holds exactly rows x row_size uniform
     rows (what fixed-width convert_to_rows produces) — the shape the
-    bucketed from-rows kernel requires."""
-    import weakref
-
+    word-slice from-rows kernel requires."""
     if int(nbytes_total) != rows * row_size:
         return False
-    key = id(offs)
-    ent = _UNIFORM_VERDICTS.get(key)
+    ent = _UNIFORM_VERDICTS.get(id(offs))
     if ent is not None:
         ref, rs, verdict = ent
         if ref() is offs and rs == row_size:
             return verdict
     o = np.asarray(offs)
     verdict = bool(o[0] == 0 and np.all(np.diff(o) == row_size))
-    try:
-        ref = weakref.ref(offs,
-                          lambda _r: _UNIFORM_VERDICTS.pop(key, None))
-    except TypeError:
-        return verdict
-    if len(_UNIFORM_VERDICTS) > 512:
-        _UNIFORM_VERDICTS.clear()
-    _UNIFORM_VERDICTS[key] = (ref, row_size, verdict)
+    _note_uniform(offs, row_size, verdict)
     return verdict
 
 
+def _transpose_row_words(data: jnp.ndarray, rows: int,
+                         row_size: int) -> jnp.ndarray:
+    """Flat buffer of ``rows`` uniform rows (packed u32 words as
+    convert_to_rows emits them, or u8, packed here once) -> the row
+    words transposed, (row_size // 4, rows // 128, 128) u32: word w of
+    every row is the block [w], contiguous in the TPU's (8, 128)
+    tiling.  An executable of its own (_from_rows_fixed_cached): as the
+    result of a program the blocks have that layout for certain; inside
+    one program with its consumers XLA folds the transpose into their
+    reads, and each field then reads a column of the untransposed
+    matrix (300-400 GB of traffic at 212 columns x 2^20 rows), while as
+    rows of one (words, rows) matrix eight words share every tile and
+    each field's read costs eight (PERF.md, Findings, PR 32)."""
+    if data.dtype != _U32:      # little-endian bytes -> words
+        b = data.astype(_U32)
+        data = (b[0::4] | (b[1::4] << _U32(8))
+                | (b[2::4] << _U32(16)) | (b[3::4] << _U32(24)))
+    lane = 128 if rows % 128 == 0 else rows
+    return jnp.transpose(
+        data.reshape(rows // lane, lane, row_size // 4), (2, 0, 1))
+
+
+def _extract_fixed_words(blocks: jnp.ndarray, schema, starts,
+                         validity_offset: int):
+    """Transposed row words (_transpose_row_words) -> (values,
+    validity): the inverse of _assemble_fixed_words.  Every field is a
+    static slice of word vectors (field_word_slots: the layout the
+    assembly writes) with a shift, a mask and a bitcast, and every
+    validity bit a shift of its validity word: no gather, no index
+    matrix.  Values come back in the dtypes the columns carry (FLOAT64
+    and uint64 as raw u64 bits, DECIMAL128 as (rows, 4) int32 limbs)."""
+    rows = blocks.shape[1] * blocks.shape[2]
+
+    def word(w):
+        return blocks[w].reshape(rows)
+
+    vals, valids = [], []
+    for ci, (dt, st) in enumerate(zip(schema, starts)):
+        slots = field_word_slots(dt, st)
+        w, shift, nbits = slots[0]
+        kind = dt.kind
+        if kind == Kind.DECIMAL128:
+            v = lax.bitcast_convert_type(
+                blocks[w:w + 4].reshape(4, rows).T, _I32)
+        elif len(slots) == 2:
+            u = (word(w).astype(_U64)
+                 | (word(w + 1).astype(_U64) << _U64(32)))
+            raw = (kind == Kind.FLOAT64
+                   or dt.np_dtype == np.dtype(np.uint64))
+            v = u if raw else u.astype(jnp.int64)
+        elif nbits == 32:
+            v = lax.bitcast_convert_type(
+                word(w), jnp.float32 if kind == Kind.FLOAT32
+                else jnp.dtype(dt.np_dtype))
+        elif dt.np_dtype.kind == "i":   # sign-extend from the top
+            up = word(w) << _U32(32 - nbits - shift)
+            v = (lax.bitcast_convert_type(up, _I32)
+                 >> _I32(32 - nbits)).astype(dt.np_dtype)
+        else:
+            v = ((word(w) >> _U32(shift))
+                 & _U32((1 << nbits) - 1)).astype(dt.np_dtype)
+        vals.append(v)
+        off = validity_offset + ci // 8
+        valids.append(((word(off // 4) >> _U32((off % 4) * 8 + ci % 8))
+                       & _U32(1)).astype(_U8))
+    return tuple(vals), tuple(valids)
+
+
 def _from_rows_fixed_cached(list_col: Column, schema, starts,
-                            validity_offset: int, fixed_size: int,
-                            row_size: int) -> Table:
-    """Fixed-width from-rows through the compile cache: the flat row
-    buffer pads to bucket * row_size, offsets pad edge-replicated, and
-    the single-gather decode kernel compiles once per (schema digest,
-    bucket, buffer packing)."""
+                            validity_offset: int, row_size: int) -> Table:
+    """Uniform fixed-width from-rows through the compile cache, as two
+    executables: _transpose_row_words per (row size, bucket, buffer
+    packing), whatever the schema, and _extract_fixed_words per (schema
+    digest, bucket)."""
     from spark_rapids_tpu.perf import jit_cache as _jc
 
     rows = list_col.length
-    child = list_col.children[0]
-    data, offs = child.data, list_col.offsets
+    data = list_col.children[0].data
     packed = data.dtype == _U32
     bucket = _jc.bucket_rows(rows)
-    unit = row_size // 4 if packed else row_size
-    data_p = _jc.pad_axis0(data, bucket * unit)
-    offs_p = (offs if bucket == rows
-              else jnp.pad(offs, (0, bucket - rows), mode="edge"))
-    digest = _jc.schema_digest(
-        schema, extra=f"from_rows:{row_size}:{'u32' if packed else 'u8'}")
+    data = _pad_rows(data, bucket * (row_size // 4 if packed else row_size))
     schema_t = tuple(schema)
     starts_t = tuple(starts)
-    total_bytes = bucket * row_size
 
-    def kernel(data_p, offs_p):
-        region = _gather_fixed_region(data_p, offs_p, fixed_size,
-                                      total_bytes)
-        valid_all = _decode_validity(region, schema_t, validity_offset)
-        vals = tuple(
-            _bytes_to_values(region[:, st:st + _col_byte_size(dt)], dt)
-            for dt, st in zip(schema_t, starts_t))
-        return vals, valid_all
+    def transpose(data):
+        return _transpose_row_words(data, bucket, row_size)
 
-    vals, valid_all = _jc.CACHE.cached_call(
-        "row_conversion.from_rows", digest, kernel, (data_p, offs_p),
-        bucket=bucket, donate_argnums=(0,))
-    out_cols = [Column(dt, rows, data=v[:rows],
-                       validity=valid_all[:rows, ci])
-                for ci, (dt, v) in enumerate(zip(schema, vals))]
-    return Table(out_cols)
+    def extract(blocks):
+        return _extract_fixed_words(blocks, schema_t, starts_t,
+                                    validity_offset)
+
+    if _jc.cache_enabled():
+        blocks = _jc.CACHE.cached_call(
+            "row_conversion.from_rows.transpose",
+            _jc.schema_digest(
+                (), extra=f"{row_size}:{'u32' if packed else 'u8'}"),
+            transpose, (data,), bucket=bucket)
+        vals, valids = _jc.CACHE.cached_call(
+            "row_conversion.from_rows",
+            _jc.schema_digest(schema, extra=f"from_rows:{row_size}"),
+            extract, (blocks,), bucket=bucket)
+    else:
+        vals, valids = extract(transpose(data))
+    if bucket != rows:
+        vals = [v[:rows] for v in vals]
+        valids = [v[:rows] for v in valids]
+    return Table([Column(dt, rows, data=v, validity=valid)
+                  for dt, v, valid in zip(schema, vals, valids)])
 
 
 def convert_from_rows(list_col: Column, schema: Sequence[DType]) -> Table:
-    """LIST<INT8> of JCUDF rows -> Table (RowConversion.convertFromRows)."""
+    """LIST<INT8> of JCUDF rows -> Table (RowConversion.convertFromRows).
+    An eager call is the timeline span ``from_rows`` (it ends where the
+    call returns, at the enqueue); under a jit trace nothing is
+    recorded."""
+    from spark_rapids_tpu import observability as _obs
+
+    child = list_col.children[0]
+    if isinstance(child.data, jax.core.Tracer) or \
+            isinstance(list_col.offsets, jax.core.Tracer):
+        return _from_rows(list_col, schema, True)[0]
+    with _obs.TRACER.start_span("from_rows", kind="phase") as span:
+        out, engine = _from_rows(list_col, schema, False)
+        _note(span, "from_rows", engine, list_col.length, child.length)
+    return out
+
+
+def _from_rows(list_col: Column, schema: Sequence[DType],
+               traced: bool) -> Tuple[Table, str]:
+    """(table, engine): ``words`` slices every field out of the row
+    words (fixed-width schema, uniform rows: what the buffer is, not an
+    option); ``gather`` fetches every row's fixed section by byte index
+    (rows of differing size, schemas with strings, a jit trace)."""
     from spark_rapids_tpu.columns import bytesview
-    from spark_rapids_tpu.perf import jit_cache as _jc
 
     rows = list_col.length
     starts, validity_offset, fixed_size = compute_layout(schema)
@@ -616,30 +789,11 @@ def convert_from_rows(list_col: Column, schema: Sequence[DType]) -> Table:
     data = child.data  # flat byte buffer (u8 or packed u32 words)
     offs = list_col.offsets
     nbytes_total = child.length
-    traced = isinstance(data, jax.core.Tracer) or \
-        isinstance(offs, jax.core.Tracer)
 
-    if (os.environ.get("SPARK_RAPIDS_TPU_PALLAS_ROWCONV") == "1"
-            and rows > 0
-            and not has_strings
-            and data.dtype == jnp.uint32):
-        # single-pass tile disassembly (one HBM read of the row matrix
-        # feeds all column extractions); interpret mode on CPU.  The
-        # kernel needs uniform contiguous rows — any other buffer
-        # shape falls through to the gather path below.
-        if int(data.size) == rows * (row_size // 4):
-            from spark_rapids_tpu.ops.row_assembly_pallas import \
-                convert_from_rows_pallas
-            return convert_from_rows_pallas(
-                list_col, schema,
-                interpret=jax.default_backend() == "cpu")
-
-    if (_jc.cache_enabled() and rows > 0 and not has_strings
-            and not traced
+    if (rows > 0 and not has_strings and not traced
             and _uniform_row_offsets(offs, rows, row_size, nbytes_total)):
         return _from_rows_fixed_cached(list_col, schema, starts,
-                                       validity_offset, fixed_size,
-                                       row_size)
+                                       validity_offset, row_size), "words"
 
     # eager width-grouped decode: one region gather + static slices
     region = _gather_fixed_region(data, offs, fixed_size, nbytes_total)
@@ -669,4 +823,4 @@ def convert_from_rows(list_col: Column, schema: Sequence[DType]) -> Table:
             vals = _bytes_to_values(
                 region[:, st:st + _col_byte_size(dt)], dt)
             out_cols.append(Column(dt, rows, data=vals, validity=valid))
-    return Table(out_cols)
+    return Table(out_cols), "gather"

@@ -13,9 +13,8 @@ centralizes the fix:
     buffer donation on the padded operands (TPU), and LRU eviction
     under a byte/entry budget.
 
-Consumers: ops/row_conversion.py (to-rows / from-rows),
-ops/row_assembly_pallas.py (tile kernels), ops/hash.py (row hashes),
-parallel/exchange.py (capacity-retry step builders).  Stats surface
+Consumers: ops/row_conversion.py (to-rows / from-rows), ops/hash.py
+(row hashes), parallel/exchange.py (capacity-retry step builders).  Stats surface
 through srt_jit_cache_* metrics (observability), the shim
 (jit_cache_stats / jit_cache_clear), and tools/metrics_report.py.
 

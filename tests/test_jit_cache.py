@@ -175,7 +175,8 @@ def test_second_call_same_bucket_zero_compiles():
     RC.convert_from_rows(out2, schema)
     f2 = CACHE.stats()
     assert f2["compiles"] == f1["compiles"]
-    assert f2["hits"] == f1["hits"] + 1
+    # from-rows is two executables: the transpose and the extraction
+    assert f2["hits"] == f1["hits"] + 2
     del out3
 
 
@@ -259,26 +260,6 @@ def test_decimal_string_schema_roundtrip_cached():
     back = RC.convert_from_rows(rows_col, [c.dtype for c in t.columns])
     assert back.columns[1].to_pylist() == ["a", "bb", None, "dddd"]
     assert back.columns[2].to_pylist() == [1, None, 3, 4]
-
-
-def test_pallas_path_cached(monkeypatch):
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_PALLAS_ROWCONV", "1")
-    t = _wide_table(50, ncols=10, seed=31)
-    schema = [c.dtype for c in t.columns]
-    out1 = RC.convert_to_rows(t)
-    s1 = CACHE.stats()
-    t2 = _wide_table(60, ncols=10, seed=32)       # same bucket (64)
-    out2 = RC.convert_to_rows(t2)
-    s2 = CACHE.stats()
-    assert s2["compiles"] == s1["compiles"]
-    assert s2["kernels"].get("pallas.to_rows", {}).get("hits", 0) >= 1
-    back = RC.convert_from_rows(out2, schema)
-    monkeypatch.delenv("SPARK_RAPIDS_TPU_PALLAS_ROWCONV")
-    ref = RC.convert_from_rows(out1, schema)
-    for orig, rec in zip(t2.columns, back.columns):
-        assert orig.to_pylist() == rec.to_pylist()
-    for orig, rec in zip(t.columns, ref.columns):
-        assert orig.to_pylist() == rec.to_pylist()
 
 
 # ------------------------------------------------- exchange step builders

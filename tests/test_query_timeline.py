@@ -405,3 +405,121 @@ def test_rehearsal_line_holds_the_seven_new_metrics(cell):
     assert metrics["window_compiles"]["value"] == 0
     assert 0.0 <= metrics["device_unfed_pct"]["value"] <= 100.0
     assert metrics["dispatch_host_ms"]["value"] > 0
+
+
+# ------------------------------- (f) the row-conversion cell's readers
+
+ROWCONV_CELL = "rowconv-fixed-212x1m-roundtrip"
+
+
+def test_rehearsal_line_holds_the_row_conversion_metrics():
+    """The cell's line on a rehearsal: the program's own ``to_rows`` /
+    ``from_rows`` spans are read (``rowconv_dispatch_ms``) beside the
+    benchmark's blocking spans; the two roofline shares are device
+    numbers and are left out off the chip."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
+         ROWCONV_CELL, "--size", "toy", "--seconds", "1", "--trace", "1",
+         "--seed", "2147483659"],
+        env=env, cwd=ROOT, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    result = json.loads(out.stdout.strip().splitlines()[-1])
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["compared"]["row_bytes_differing"]["value"] == 0
+    assert result["compared"]["column_bytes_differing"]["value"] == 0
+    metrics = result["metrics"]
+    for name in ("to_rows_ms.hostpaced", "from_rows_ms.hostpaced",
+                 "rowconv_dispatch_ms", "window_compiles"):
+        assert name in metrics, (name, sorted(metrics))
+    for name in ("to_rows_roofline", "from_rows_roofline",
+                 "hbm_roofline", "device_idle_pct"):
+        assert name not in metrics
+    assert metrics["window_compiles"]["value"] == 0
+    # the program's spans lie inside the benchmark's blocking ones
+    assert 0 < metrics["rowconv_dispatch_ms"]["value"] <= (
+        metrics["to_rows_ms.hostpaced"]["value"]
+        + metrics["from_rows_ms.hostpaced"]["value"])
+
+
+@pytest.mark.parametrize("metric,span", [("to_rows_roofline", "to_rows"),
+                                         ("from_rows_roofline",
+                                          "from_rows")])
+def test_direction_roofline_on_hand_built_busy_times(metric, span):
+    """A direction moves the 2^20 x 212 table once each way:
+    2,155,872,256 B, 2.632 ms at 819 GB/s; a median device-busy time of
+    12 ms inside its brackets is 21.94 % of the roofline.  Untraced
+    runs, rehearsals and a profile without the bracket read nothing."""
+    manifest = harness.load_json(harness.ROOT, "BENCHMARK.json")
+    cell = harness.Cell(manifest, ROWCONV_CELL, 1, "full")
+    assert cell.reference.min_bytes(
+        cell.sizes, cell.traffic["params"]) == 4_311_744_512
+    run = types.SimpleNamespace(
+        cell=cell, trace={"busy_s": 0.025}, device_kind="TPU v5 lite",
+        rehearsal=False, direction_busy={span: [0.011, 0.012, 0.3]})
+    read = harness.load("layer_metrics", metric).read
+    assert read(run) == pytest.approx(100 * 2_155_872_256 / 819e9 / 0.012)
+    assert 21.9 < read(run) < 22.0
+    run.rehearsal = True
+    assert read(run) is None
+    run.rehearsal, run.trace = False, None
+    assert read(run) is None
+    run.trace, run.direction_busy = {"busy_s": 0.025}, {}
+    assert read(run) is None
+    run.direction_busy = None       # a profile with no device events
+    assert read(run) is None
+
+
+def test_direction_profile_brackets_both_directions(switches):
+    """The readers' own profile at toy size (the CPU's XLA threads stand
+    in for the device plane, as in ``lib/trace.read_xplane``): one busy
+    time per round and direction, each above 0 and inside the round.
+    The counters are on, as ``run.py`` has them: the cell's binding
+    reads which engine served its warm round trip."""
+    from lib import direction
+
+    obs.enable()
+    manifest = harness.load_json(harness.ROOT, "BENCHMARK.json")
+    cell = harness.Cell(manifest, ROWCONV_CELL, 2147483659, "toy")
+    busy = direction.profile_directions(cell, rounds=2)
+    assert sorted(busy) == ["from_rows", "to_rows"]
+    for seconds in busy.values():
+        assert len(seconds) == 2 and all(0 < s < 5 for s in seconds)
+    cell.traffic = {k: v for k, v in cell.traffic.items()
+                    if k != "op_binding"}
+    assert direction.profile_directions(cell) is None
+
+
+@pytest.mark.parametrize("metric", ["to_rows_roofline",
+                                    "from_rows_roofline"])
+def test_direction_rooflines_are_read_from_the_device_trace(metric):
+    """A share of a roofline is a device number; its
+    manifest entry says where it comes from."""
+    manifest = harness.load_json(harness.ROOT, "BENCHMARK.json")
+    entry = [m for m in manifest["per_layer"] if m["name"] == metric][0]
+    assert entry["source"] == "device_trace"
+    assert entry["workloads"] == [ROWCONV_CELL]
+
+
+def test_rowconv_dispatch_reads_one_span_of_each_name_per_operation(
+        switches):
+    """Hand-built operations around real spans; an operation that lacks
+    one of the two spans leaves the metric out."""
+    import time
+
+    obs.enable()
+    records = []
+    for k in range(3):
+        t0 = time.perf_counter()
+        for name in ("to_rows", "from_rows"):
+            if k == 2 and name == "from_rows":
+                continue
+            with obs.TRACER.start_span(name, kind="phase"):
+                time.sleep(0.002)
+        records.append({"ok": True, "t_start": t0,
+                        "t_end": time.perf_counter()})
+    read = harness.load("layer_metrics", "rowconv_dispatch_ms").read
+    whole = types.SimpleNamespace(records=records[:2])
+    assert 4.0 <= read(whole) < 40.0
+    assert read(types.SimpleNamespace(records=records)) is None

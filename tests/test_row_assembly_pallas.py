@@ -1,5 +1,8 @@
-"""Pallas single-pass row assembly vs the word-stack reference
-(interpret mode on CPU; real-hardware profiling is round-2 work)."""
+"""The Pallas tile kernels of row conversion against the XLA paths they
+replace (interpret mode on the CPU): the to-rows row assembly, which is
+a TPU's engine for fixed-width schemas, and the string-payload paste
+(opt-in, not brought up on the chip).  The from-rows tile kernel and
+the tests of the environment switch went with PR 32."""
 
 import numpy as np
 import pytest
@@ -8,8 +11,7 @@ from spark_rapids_tpu.columns import dtypes
 from spark_rapids_tpu.columns.column import Column
 from spark_rapids_tpu.columns.table import Table
 from spark_rapids_tpu.ops import row_conversion as RC
-from spark_rapids_tpu.ops.row_assembly_pallas import \
-    assemble_fixed_words_pallas
+from spark_rapids_tpu.ops.row_assembly_pallas import assemble_rows_pallas
 
 CYCLE = [dtypes.INT64, dtypes.INT32, dtypes.FLOAT64, dtypes.FLOAT32,
          dtypes.INT16, dtypes.INT8, dtypes.BOOL8, dtypes.TIMESTAMP_MICROS]
@@ -58,65 +60,11 @@ def test_pallas_assembly_matches_reference(rows, ncols, br):
     row_size = (fixed + 7) // 8 * 8
     ref = np.asarray(RC._assemble_fixed_words(cols, starts, voff,
                                               row_size))
-    got = np.asarray(assemble_fixed_words_pallas(
-        cols, starts, voff, row_size, block_rows=br, interpret=True))
+    inputs, plan = RC.build_plan(cols, starts, voff, row_size // 4)
+    got = np.asarray(assemble_rows_pallas(
+        inputs, plan, rows, row_size // 4, block_rows=br,
+        interpret=True))
     np.testing.assert_array_equal(ref, got)
-
-
-def test_pallas_env_opt_in(monkeypatch):
-    """convert_to_rows routes through the kernel when opted in, with
-    byte-identical output."""
-    rng = np.random.default_rng(3)
-    cols = _make_cols(rng, 300, 9)
-    t = Table(cols)
-    base = RC.convert_to_rows(t)
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_PALLAS_ROWCONV", "1")
-    via_pallas = RC.convert_to_rows(t)
-    assert np.array_equal(np.asarray(base.children[0].data),
-                          np.asarray(via_pallas.children[0].data))
-
-
-# ------------------------------------------- from-rows direction (r5)
-
-
-@pytest.mark.parametrize("rows,ncols,br", [
-    (1000, 20, 256),
-    (512, 64, 128),
-    (7, 3, 512),
-])
-def test_pallas_from_rows_matches_reference(rows, ncols, br):
-    """Round trip through the tile disassembly kernel must reproduce
-    convert_from_rows bit-for-bit (fixed-width schemas)."""
-    from spark_rapids_tpu.ops.row_assembly_pallas import \
-        convert_from_rows_pallas
-
-    rng = np.random.default_rng(1000 + rows + ncols)
-    cols = _make_cols(rng, rows, ncols, with_dec=(ncols == 64))
-    t = Table(cols)
-    rows_col = RC.convert_to_rows(t)
-    ref = RC.convert_from_rows(rows_col, [c.dtype for c in cols])
-    got = convert_from_rows_pallas(rows_col, [c.dtype for c in cols],
-                                   block_rows=br, interpret=True)
-    for ci, (a, b) in enumerate(zip(ref.columns, got.columns)):
-        np.testing.assert_array_equal(
-            np.asarray(a.data), np.asarray(b.data), err_msg=f"col {ci}")
-        av = None if a.validity is None else np.asarray(a.validity)
-        bv = None if b.validity is None else np.asarray(b.validity)
-        if av is None:
-            assert bv is None or bv.all()
-        else:
-            np.testing.assert_array_equal(av, bv, err_msg=f"col {ci}")
-
-
-def test_pallas_from_rows_env_opt_in(monkeypatch):
-    rng = np.random.default_rng(8)
-    cols = _make_cols(rng, 200, 6)
-    t = Table(cols)
-    rows_col = RC.convert_to_rows(t)
-    base = RC.convert_from_rows(rows_col, [c.dtype for c in cols])
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_PALLAS_ROWCONV", "1")
-    via = RC.convert_from_rows(rows_col, [c.dtype for c in cols])
-    assert base.to_pylist() == via.to_pylist()
 
 
 # --------------------------------------- string payload tiling (r5)

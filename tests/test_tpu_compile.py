@@ -5,7 +5,8 @@ topology that is described, not attached (``on-chip-measurement`` guide,
 section 2).  These tests compile the main path's jitted programs at the
 sizes ``chip_smoke.py`` runs on the chip — TPC-DS SF10 facts in the 2^25
 row bucket with join capacity 2^23, row conversion at 212 columns x 2^19
-rows — so that what the chip's compiler refuses costs no chip time.
+rows — and at the benchmark's 2^20-row row-conversion cell, so that what
+the chip's compiler refuses costs no chip time.
 Nothing runs here: a compile that passes is not a chip run.
 
 The topology is described inside a module-scoped fixture, never at
@@ -25,6 +26,7 @@ FACT_BUCKET = 1 << 25        # bucket_rows(28_800_991)
 RETURNS_BUCKET = 1 << 22     # bucket_rows(28_800_991 // 8)
 JOIN_CAPACITY = 1 << 23
 ROWCONV_ROWS = 1 << 19
+ROWCONV_CELL_ROWS = 1 << 20    # rowconv-fixed-212x1m-roundtrip
 ROWCONV_COLS = 212
 
 I32, I64, U32 = jnp.int32, jnp.int64, jnp.uint32
@@ -177,10 +179,14 @@ def _column_structs(schema, shaped, rows):
     import numpy as np
 
     from spark_rapids_tpu.columns.column import Column
-    return tuple(
-        shaped((rows,), Column.from_numpy(
+
+    def struct(dt):
+        if dt.kind == "decimal128":         # four int32 limbs a value
+            return shaped((rows, 4), jnp.int32)
+        return shaped((rows,), Column.from_numpy(
             np.zeros(1, dt.np_dtype), dtype=dt).data.dtype)
-        for dt in schema)
+
+    return tuple(struct(dt) for dt in schema)
 
 
 def _columns(schema, datas, rows):
@@ -202,46 +208,168 @@ def test_rowconv_stack_path_compiles(shaped, rowconv):
     assert _device_gib(compiled) < HBM_GIB
 
 
-# The Pallas kernels are opt-in (SPARK_RAPIDS_TPU_PALLAS_ROWCONV=1).  As
-# they stood the chip's compiler refused all three: i64 block indices
-# under x64, 512-row 1-D blocks against XLA's T(1024) layout, and a tile
-# orientation that asked Mosaic for one relayout per word (to-rows: VMEM
-# exhausted at 274 words after a 57 s compile; from-rows: compile time
-# quadratic in the field count, 408 s at 40 fields).  Selected on a chip
-# a kernel raises what the compiler raises — it never gives way to the
-# stack path.
-
-
-def test_pallas_to_rows_compiles(shaped, rowconv):
-    from spark_rapids_tpu.ops import row_assembly_pallas as RP
+def test_rowconv_from_rows_compiles_at_the_cell_size(shaped, rowconv):
+    """The two from-rows executables of the benchmark's cell: 2^20 rows
+    of 212 columns as one flat word buffer transposed to (274, 8192,
+    128) blocks, then every field a static slice of a block."""
     from spark_rapids_tpu.ops import row_conversion as RC
     schema, starts, voff, row_size = rowconv
+    n_words = row_size // 4
+
+    def transpose(flat):
+        return RC._transpose_row_words(flat, ROWCONV_CELL_ROWS, row_size)
+
+    def extract(blocks):
+        return RC._extract_fixed_words(blocks, schema, starts, voff)
+
+    first = jax.jit(transpose).lower(shaped(
+        (ROWCONV_CELL_ROWS * n_words,), U32)).compile()
+    second = jax.jit(extract).lower(shaped(
+        (n_words, ROWCONV_CELL_ROWS // 128, 128), U32)).compile()
+    for compiled in (first, second):
+        assert _device_gib(compiled) < HBM_GIB
+        assert " gather(" not in compiled.as_text()
+    # the slices read the blocks in place: no temporaries to speak of
+    assert second.memory_analysis().temp_size_in_bytes < 2 ** 26
+
+
+# The Pallas to-rows kernel is a TPU's engine for fixed-width schemas
+# (ops/row_conversion._to_rows).  As first written the chip's compiler
+# refused it: i64 block indices under x64, 512-row 1-D blocks against
+# XLA's T(1024) layout, and a tile orientation that asked Mosaic for one
+# relayout per word (VMEM exhausted at 274 words after a 57 s compile).
+# The from-rows tile kernel went with PR 32; the string paste is opt-in
+# and not brought up.
+
+
+def _cycled(n):
+    from spark_rapids_tpu.columns import dtypes
+    cycle = [dtypes.INT64, dtypes.INT32, dtypes.FLOAT64, dtypes.FLOAT32,
+             dtypes.INT16, dtypes.INT8, dtypes.BOOL8,
+             dtypes.TIMESTAMP_MICROS]
+    return [cycle[i % len(cycle)] for i in range(n)]
+
+
+def _all_of(name, n):
+    from spark_rapids_tpu.columns import dtypes
+    dt = dtypes.decimal128(-2) if name == "decimal128" \
+        else getattr(dtypes, name)
+    return [dt] * n
+
+
+def _with_decimals(n):
+    from spark_rapids_tpu.columns import dtypes
+    return [dtypes.decimal128(-2) if i % 11 == 10 else dt
+            for i, dt in enumerate(_cycled(n))]
+
+
+def _layout(schema):
+    from spark_rapids_tpu.ops import row_conversion as RC
+    starts, voff, fixed = RC.compute_layout(schema)
+    return starts, voff, (fixed + 7) // 8 * 8
+
+
+def _pallas_to_rows(schema, shaped, rows, nullable):
+    """The tile kernel of ``schema`` compiled for the chip as
+    ``_to_rows_fixed_cached`` builds it."""
+    from spark_rapids_tpu.columns.column import Column
+    from spark_rapids_tpu.ops import row_assembly_pallas as RP
+    from spark_rapids_tpu.ops import row_conversion as RC
+    starts, voff, row_size = _layout(schema)
+
+    def to_rows(datas, valids):
+        cols = [Column(dt, rows, data=d, validity=v)
+                for dt, d, v in zip(schema, datas, valids)]
+        inputs, plan = RC.build_plan(cols, starts, voff, row_size // 4)
+        return RP.assemble_rows_pallas(inputs, plan, rows, row_size // 4,
+                                       interpret=False)
+
+    valids = tuple(shaped((rows,), jnp.uint8) if nullable else None
+                   for _ in schema)
+    return jax.jit(to_rows).lower(
+        _column_structs(schema, shaped, rows), valids).compile()
+
+
+# what ``row_conversion._tile_fits`` admits the chip's compiler takes:
+# the cell, the small buckets, and the widest schema of each family
+# that the rule lets through (the tile's VMEM does not depend on the
+# row count, so those compile at 2^18 rows)
+PALLAS_ADMITTED = [
+    ("cell_all_valid", lambda: _cycled(212), ROWCONV_CELL_ROWS, False),
+    ("cell_nullable", lambda: _cycled(212), ROWCONV_CELL_ROWS, True),
+    ("three_columns_bucket_8", lambda: _cycled(3), 8, True),
+    ("three_columns_bucket_512", lambda: _cycled(3), 512, False),
+    ("decimal128_among_40", lambda: _with_decimals(40), 1 << 18, True),
+    ("widest_cycled_599", lambda: _cycled(599), 1 << 18, False),
+    ("widest_int8_1514", lambda: _all_of("INT8", 1514), 1 << 18, False),
+    ("widest_int16_1052", lambda: _all_of("INT16", 1052), 1 << 18, False),
+    ("widest_int64_427", lambda: _all_of("INT64", 427), 1 << 18, False),
+    ("widest_decimal128_218", lambda: _all_of("decimal128", 218),
+     1 << 18, True),
+]
+
+
+@pytest.mark.parametrize("make,rows,nullable",
+                         [c[1:] for c in PALLAS_ADMITTED],
+                         ids=[c[0] for c in PALLAS_ADMITTED])
+def test_pallas_to_rows_compiles_where_the_rule_admits(shaped, make, rows,
+                                                       nullable):
+    from spark_rapids_tpu.ops import row_conversion as RC
+    schema = make()
+    assert RC._tile_fits(schema, _layout(schema)[2])
+    compiled = _pallas_to_rows(schema, shaped, rows, nullable)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_gib(compiled) < HBM_GIB
+
+
+def test_widest_admitted_schemas_are_the_widest():
+    """One column more and the rule sends each family to the word
+    path: the cases above are its boundary."""
+    from spark_rapids_tpu.ops import row_conversion as RC
+    for wider in (_cycled(600), _all_of("INT8", 1515),
+                  _all_of("INT16", 1053), _all_of("INT64", 428),
+                  _all_of("decimal128", 219)):
+        assert not RC._tile_fits(wider, _layout(wider)[2])
+
+
+def test_mosaic_refuses_a_row_the_rule_refuses(shaped):
+    """800 cycled columns are 1,026 words a row: the tile no longer
+    fits the kernel's VMEM, which is why such rows are the word
+    path's.  (700 columns still compile; the rule gives way at 600.)"""
+    from spark_rapids_tpu.ops import row_conversion as RC
+    schema = _cycled(800)
+    assert not RC._tile_fits(schema, _layout(schema)[2])
+    with pytest.raises(Exception, match="vmem"):
+        _pallas_to_rows(schema, shaped, 1 << 18, False)
+
+
+def test_thousand_columns_compile_on_the_word_path(shaped):
+    """A schema five times the cell's width (5,128 B a row): to rows by
+    XLA's word assembly, from rows by the transposed word slices, both
+    under the chip's memory at 2^18 rows (1.3 GB of rows) and without a
+    gather."""
+    from spark_rapids_tpu.ops import row_conversion as RC
+    schema, rows = _cycled(1000), 1 << 18
+    starts, voff, row_size = _layout(schema)
+    assert not RC._tile_fits(schema, row_size)
 
     def to_rows(datas):
-        inputs, plan = RC.build_plan(
-            _columns(schema, datas, ROWCONV_ROWS), starts, voff,
-            row_size // 4)
-        return RP.assemble_rows_pallas(inputs, plan, ROWCONV_ROWS,
-                                       row_size // 4, interpret=False)
+        return RC._assemble_fixed_words(_columns(schema, datas, rows),
+                                        starts, voff, row_size)
 
-    compiled = jax.jit(to_rows).lower(
-        _column_structs(schema, shaped, ROWCONV_ROWS)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    def transpose(flat):
+        return RC._transpose_row_words(flat, rows, row_size)
 
+    def extract(blocks):
+        return RC._extract_fixed_words(blocks, schema, starts, voff)
 
-def test_pallas_from_rows_compiles(shaped, rowconv):
-    from spark_rapids_tpu.ops import row_assembly_pallas as RP
-    schema, starts, voff, row_size = rowconv
-    plan, _cols, _valid = RP.build_extract_plan(schema, starts, voff,
-                                                row_size // 4)
-
-    def from_rows(mat):
-        return tuple(RP.disassemble_rows_pallas(mat, plan,
-                                                interpret=False))
-
-    compiled = jax.jit(from_rows).lower(
-        shaped((ROWCONV_ROWS, row_size // 4), U32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    n_words = row_size // 4
+    for fn, arg in ((to_rows, _column_structs(schema, shaped, rows)),
+                    (transpose, shaped((rows * n_words,), U32)),
+                    (extract, shaped((n_words, rows // 128, 128), U32))):
+        compiled = jax.jit(fn).lower(arg).compile()
+        assert _device_gib(compiled) < HBM_GIB
+        assert " gather(" not in compiled.as_text()
 
 
 @pytest.mark.xfail(strict=True, reason=(
