@@ -87,6 +87,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "whole-stage executions by outcome"),
     "srt_segment_sum_total": (
         "counter", "segment sums traced by engine"),
+    "srt_dense_lookup_total": (
+        "counter", "table lookups traced by engine"),
     "srt_row_conversion_total": (
         "counter", "eager row conversions by direction and engine"),
     "srt_incidents_total": ("counter", "incident bundles written"),

@@ -29,6 +29,12 @@ TPU-first design decisions (vs the reference's row-iterator operators):
     ops/segment_sum: exact one-hot products over 8-bit limbs on the
     matrix unit; q5, q72 and q7 still ride jax.ops.segment_sum, a
     scatter-add (ROADMAP S2b).
+  * a join to a dense dimension (the key IS the row number) is a
+    lookup, ``dim_column[key]``.  q3's ride ops/dense_lookup: integer
+    and boolean 1-D tables of up to DENSE_MAX_TABLE_LIMBS
+    (rows x 8-bit limbs) are read by an exact one-hot product on the
+    matrix unit, the tables of one dim sharing its one-hots; q9, q7
+    and the hand-written q5/q72 index directly, a gather.
   * order-by is lax.sort over the padded group table with sentinel
     keys for invalid slots.
   * strings never enter the jitted program: dimension attributes are
@@ -55,6 +61,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from spark_rapids_tpu import observability as _obs
+from spark_rapids_tpu.ops.dense_lookup import lookup
 from spark_rapids_tpu.ops.device_join import inner_join_device
 from spark_rapids_tpu.ops.segment_sum import segment_sum
 
@@ -570,7 +577,10 @@ def make_q3(base: int, years: int, brands: int, manufact: int,
     """q3-shape single-jit pipeline: store_sales JOIN date_dim (dense
     lookup, d_moy filter) JOIN item (dense lookup, manufacturer
     filter) GROUP BY (d_year, brand) SUM(price) ORDER BY year ASC,
-    sum DESC, brand ASC LIMIT `limit`.  Rows outside the `years`-wide
+    sum DESC, brand ASC LIMIT `limit`.  Both lookups go through
+    ops/dense_lookup.lookup, two tables on one index each: one-hot
+    products on the matrix unit while ``rows x limbs`` of the dim's
+    tables is within DENSE_MAX_TABLE_LIMBS, ``table[idx]`` past it.  Rows outside the `years`-wide
     window starting at d_year[0] are filtered (the date-dim join scope);
     dead output slots carry the 2^31-1 year sentinel."""
     kernel = _q3_kernel(base, years, brands, manufact, month, limit,
@@ -594,11 +604,13 @@ def _q3_kernel(base, years, brands, manufact, month, limit,
         # of the op underneath (the trace names a fusion by HLO text)
         with jax.named_scope("srt/q3/dim_gather"):
             di = s_date - base
-            year_idx = d_year[di] - d_year[0]
-            keep = ((d_moy[di] == month)
-                    & (i_manufact[s_item] == manufact)
+            # the tables of a dim share its index, so one pair of
+            # one-hots serves both (ops/dense_lookup)
+            year, moy = lookup((d_year, d_moy), di)
+            manu, brand = lookup((i_manufact, i_brand), s_item)
+            year_idx = year - d_year[0]
+            keep = ((moy == month) & (manu == manufact)
                     & (year_idx >= 0) & (year_idx < years))
-            brand = i_brand[s_item]
         gid = jnp.where(keep, year_idx * brands + brand, 0)
         amt = jnp.where(keep, s_price, 0)
         with jax.named_scope("srt/q3/segment_sum"):

@@ -269,6 +269,12 @@ SEGMENT_SUM = METRICS.counter(
     "products over 8-bit limbs on the matrix unit, scatter = "
     "jax.ops.segment_sum); counted when a program is traced, not "
     "when it runs", labels=("engine",))
+DENSE_LOOKUP = METRICS.counter(
+    "srt_dense_lookup_total",
+    "Table lookups built into executables by engine (dense = one-hot "
+    "products over 8-bit limbs on the matrix unit, gather = "
+    "table[idx]); counted per table when a program is traced, not "
+    "when it runs", labels=("engine",))
 ROW_CONVERSION = METRICS.counter(
     "srt_row_conversion_total",
     "Eager JCUDF row conversions by direction (to_rows / from_rows) "
@@ -1384,6 +1390,14 @@ def record_segment_sum(engine: str) -> None:
     choice is static per executable, so this counts builds."""
     if _SWITCH.enabled:
         SEGMENT_SUM.inc(labels=(engine,))
+
+
+def record_dense_lookup(engine: str) -> None:
+    """Lookup hook (ops/dense_lookup.py): one table's lookup was
+    traced into a program on ``engine`` ('dense' / 'gather').  The
+    choice is static per executable, so this counts builds."""
+    if _SWITCH.enabled:
+        DENSE_LOOKUP.inc(labels=(engine,))
 
 
 def record_row_conversion(direction: str, engine: str) -> None:

@@ -101,6 +101,12 @@ def _split(num_segments: int, limbs: int):
     return -(-num_segments >> lo_bits), lo_bits
 
 
+def _n_limbs(dtype) -> int:
+    """How many limbs :func:`_limbs` splits a value of ``dtype`` into."""
+    dt = np.dtype(dtype)
+    return 1 if dt.kind == "b" else dt.itemsize
+
+
 def _limbs(values):
     """``[L, n]`` float32 limbs (0..255) of the unsigned view of
     ``values``, least significant first.  64-bit values are taken as
@@ -125,7 +131,7 @@ def _dense(values, ids, num_segments: int):
     counted = values.dtype == jnp.bool_
     out_dtype = (jax.dtypes.canonicalize_dtype(_COUNT_DTYPE) if counted
                  else values.dtype)
-    n_limbs = 1 if counted else values.dtype.itemsize
+    n_limbs = _n_limbs(values.dtype)
     hi_n, lo_bits = _split(num_segments, n_limbs)
     lo_n = 1 << lo_bits
     # the carry wraps in the result's own width (modulo 2^32 or 2^64)

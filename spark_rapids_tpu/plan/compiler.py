@@ -32,6 +32,7 @@ Execution modes from one plan:
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
 from typing import Dict, Mapping, Optional, Sequence, Tuple
@@ -99,8 +100,13 @@ _BIN = {
     "min": jnp.minimum,
 }
 
-# env key under which a trace leaves each SegmentSum node's engine
+# env key under which a trace leaves each node's engine: a SegmentSum's
+# "dense" | "scatter", an Idx-bearing Project's "dense" | "gather"
 _ENGINES = "__engines__"
+# env keys of the stage's lookups: index expression key -> {Idx key:
+# Idx}, and Idx key -> (value, engine) of those evaluated so far
+_IDX_PEERS = "__idx_peers__"
+_LOOKED_UP = "__looked_up__"
 
 _CAST = {"i32": jnp.int32, "i64": jnp.int64, "f64": jnp.float64,
          "b": jnp.bool_}
@@ -128,7 +134,7 @@ def _eval(e, env):
         return jnp.where(_eval(e.cond, env), _eval(e.a, env),
                          _eval(e.b, env))
     if isinstance(e, ir.Idx):
-        return _eval(e.src, env)[_eval(e.idx, env)]
+        return _lookup(e, env)
     if isinstance(e, ir.Mask):
         return env[f"__mask__{e.input}"]
     if isinstance(e, ir.Arange):
@@ -138,6 +144,57 @@ def _eval(e, env):
     if isinstance(e, ir.Stack):
         return jnp.stack([_eval(p, env) for p in e.parts])
     raise TypeError(f"unknown expr {type(e).__name__}")
+
+
+def _children(x):
+    """The expressions directly under a node or an expression."""
+    for f in dataclasses.fields(x):
+        v = getattr(x, f.name)
+        for part in v if isinstance(v, tuple) else (v,):
+            if isinstance(part, ir.Expr):
+                yield part
+
+
+def _idx_exprs(x) -> list:
+    """Every ``Idx`` under a node or an expression, inner ones first."""
+    found = [i for c in _children(x) for i in _idx_exprs(c)]
+    return found + [x] if isinstance(x, ir.Idx) else found
+
+
+def _bound(e, env) -> bool:
+    """Whether every column ``e`` reads is in ``env`` already."""
+    if isinstance(e, ir.Col):
+        return e.name in env
+    if isinstance(e, ir.Mask):
+        return f"__mask__{e.input}" in env
+    return all(_bound(c, env) for c in _children(e))
+
+
+def _lookup(e: ir.Idx, env):
+    """``src[idx]`` through ``ops.dense_lookup``.  The stage's other
+    lookups on the same index expression whose tables are bound are
+    evaluated with it, those of one table length in one call, so that
+    they share the one-hots of the index as the hand kernel's do."""
+    from spark_rapids_tpu.ops import dense_lookup as _dl
+    done = env.setdefault(_LOOKED_UP, {})
+    if e.key() not in done:
+        peers = [p for p in env.get(_IDX_PEERS, {}).get(
+                     e.idx.key(), {}).values()
+                 if p.key() not in done and _bound(p.src, env)]
+        if e not in peers:
+            peers.append(e)
+        idx = _eval(e.idx, env)
+        by_len: Dict[tuple, list] = {}
+        for p in peers:
+            table = jnp.asarray(_eval(p.src, env))
+            by_len.setdefault(table.shape[:1], []).append((p, table))
+        for group in by_len.values():
+            tables = [table for _p, table in group]
+            which = _dl.engines(tables, jnp.asarray(idx))
+            for (p, _t), value, w in zip(group, _dl.lookup(tables, idx),
+                                         which):
+                done[p.key()] = (value, w)
+    return done[e.key()][0]
 
 
 def _expr_is_bool(e, bool_names=frozenset()) -> bool:
@@ -210,6 +267,25 @@ def _tap_counts(plan: ir.StagePlan, env) -> list:
             vals.append(jnp.sum(v.astype(jnp.int32))
                         .astype(jnp.int32))
     return vals
+
+
+def _eval_nodes(plan: ir.StagePlan, env,
+                reduce_axis: Optional[str]) -> None:
+    """Evaluate the stage's nodes in order into ``env``, which holds
+    the inputs; leaves each SegmentSum's and each Idx-bearing
+    Project's engine under ``_ENGINES``."""
+    lookups = [(node, _idx_exprs(node)) for node in plan.nodes]
+    peers = env[_IDX_PEERS] = {}
+    for _node, found in lookups:
+        for i in found:
+            peers.setdefault(i.idx.key(), {}).setdefault(i.key(), i)
+    done = env[_LOOKED_UP] = {}
+    for node in plan.nodes:
+        _eval_node(node, env, reduce_axis, plan.name)
+    for node, found in lookups:
+        if found and isinstance(node, ir.Project):
+            env.setdefault(_ENGINES, {})[node.out] = "+".join(
+                sorted({done[i.key()][1] for i in found}))
 
 
 def _eval_node(node, env, reduce_axis: Optional[str],
@@ -328,8 +404,10 @@ class CompiledStage:
         # reuse instead of retracing per call (bounded by the distinct
         # shape classes this stage object sees)
         self._nocache: Dict[tuple, object] = {}
-        # SegmentSum node -> "dense" | "scatter", left by the newest
-        # trace (static per executable; the profile record reads it)
+        # SegmentSum node -> "dense" | "scatter", Idx-bearing Project
+        # -> "dense" | "gather" ("dense+gather" where its lookups
+        # differ), left by the newest trace (static per executable;
+        # the profile record reads it)
         self._engines: Dict[str, str] = {}
 
     # number of op dispatches the unfused walk pays (the fused program
@@ -425,8 +503,7 @@ class CompiledStage:
                     rows = first.shape[0] if first.ndim else 0
                     env[f"__mask__{inp.name}"] = jnp.ones(
                         rows, jnp.bool_)
-            for node in plan.nodes:
-                _eval_node(node, env, None, plan.name)
+            _eval_nodes(plan, env, None)
             self._engines.update(env.get(_ENGINES, {}))
             outs = tuple(env[o] for o in plan.outputs)
             if taps:
@@ -454,8 +531,7 @@ class CompiledStage:
                 first = env[inp.columns[0].name]
                 rows = first.shape[0] if first.ndim else 0
                 env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
-            for node in plan.nodes:
-                _eval_node(node, env, reduce_axis, plan.name)
+            _eval_nodes(plan, env, reduce_axis)
             return tuple(env[o] for o in plan.outputs)
 
         return fn
@@ -539,8 +615,7 @@ class CompiledStage:
             first = arrs[0]
             rows = first.shape[0] if first.ndim else 0
             env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
-        for node in self.plan.nodes:
-            _eval_node(node, env, None, self.plan.name)
+        _eval_nodes(self.plan, env, None)
         self._engines.update(env.get(_ENGINES, {}))
         return env
 
@@ -751,7 +826,7 @@ class CompiledStage:
             "nodes": [{"kind": type(n).__name__,
                        "outs": list(n.outs()),
                        **({"engine": self._engines[n.out]}
-                          if isinstance(n, ir.SegmentSum)
+                          if isinstance(n, (ir.SegmentSum, ir.Project))
                           and n.out in self._engines else {})}
                       for n in self.plan.nodes],
             "inputs": ins,
@@ -995,8 +1070,7 @@ def fused_pipeline_fn(pipeline: ir.Pipeline,
                 rows = first.shape[0] if getattr(first, "ndim", 0) \
                     else 0
                 env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
-            for node in stage.nodes:
-                _eval_node(node, env, reduce_axis, stage.name)
+            _eval_nodes(stage, env, reduce_axis)
         return tuple(env[o] for o in last.outputs)
 
     return fn, n_args

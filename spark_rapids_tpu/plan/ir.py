@@ -115,8 +115,15 @@ class Where(Expr):
 
 @dataclass(frozen=True)
 class Idx(Expr):
-    """Gather: ``src[idx]`` — dense-dimension lookups and join-pair
-    gathers."""
+    """Lookup: ``src[idx]`` — dense-dimension lookups and join-pair
+    gathers, through ``ops.dense_lookup.lookup``: an exact one-hot
+    product on the matrix unit for integer and boolean 1-D tables
+    under a 1-D integer index while ``rows x 8-bit limbs`` of the
+    tables that share the index is within ``DENSE_MAX_TABLE_LIMBS``,
+    ``src[idx]`` (a gather) for floats, longer tables, tables that
+    are not 1-D and a scalar index.  The ``Idx`` of a stage that share
+    an index expression and a table length are evaluated together, so
+    they share the index's one-hots."""
     src: Expr
     idx: Expr
 

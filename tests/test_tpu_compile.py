@@ -115,33 +115,70 @@ def test_q5_fused_stage_compiles_at_sf10(shaped):
     jax.jit(finish._fused_callable()).lower(*args).compile()
 
 
+Q3_DATE_ROWS, Q3_ITEM_ROWS = 730, 102_000   # the benchmark's dims
+
+
+def _q3_lookup_ops(text, scopes):
+    """(products, gathers) among the compiled operations under the
+    ``srt/...`` scopes that hold q3's dim lookups."""
+    lines = [ln for ln in text.splitlines()
+             if any(f"/{s}/" in ln for s in scopes)]
+    return ([ln for ln in lines if " convolution(" in ln],
+            [ln for ln in lines if " gather(" in ln])
+
+
+def _assert_q3_dims_are_looked_up_by_products(text, scopes):
+    """The date dim's two tables are one product (one ``convolution``
+    in the chunk loop's body); the item dim's two are another while
+    ``ops/dense_lookup``'s bound admits 102,000 rows x 8 limbs, else
+    two gathers; no gather is left for a table under the bound."""
+    from spark_rapids_tpu.ops import dense_lookup as dl
+    assert dl.engine((I32, I32), Q3_DATE_ROWS) == "dense"
+    items_dense = dl.engine((I32, I32), Q3_ITEM_ROWS) == "dense"
+    products, gathers = _q3_lookup_ops(text, scopes)
+    assert len(products) == (2 if items_dense else 1), products
+    assert len(gathers) == (0 if items_dense else 2), gathers
+    assert not any(f"s32[{Q3_DATE_ROWS}]" in ln for ln in gathers)
+
+
 def test_q3_fused_stage_compiles_at_sf10(shaped):
+    """The fused q3 stage on the 2^25 bucket with the benchmark's dims
+    (730 days, 102,000 items, 2 x 1,000 groups): the dim lookups are
+    products under the scope of the node that reads each index first."""
     from spark_rapids_tpu.plan import catalog
     from spark_rapids_tpu.plan.compiler import compile_stage
-    stage = compile_stage(catalog.q3_plan(10_957, 2, 16, 3))
+    stage = compile_stage(catalog.q3_plan(10_957, 2, 1000, 3))
     args = _stage_args(stage, shaped, {
         "s": _vectors(FACT_BUCKET, (I32, I32, I64)),
-        "dims": _vectors(730, (I32, I32)) + _vectors(128, (I32, I32)),
+        "dims": (_vectors(Q3_DATE_ROWS, (I32, I32))
+                 + _vectors(Q3_ITEM_ROWS, (I32, I32))),
     })
     compiled = jax.jit(stage._fused_callable()).lower(*args).compile()
     assert _device_gib(compiled) < HBM_GIB
+    _assert_q3_dims_are_looked_up_by_products(
+        compiled.as_text(), ("srt/q3/year_idx", "srt/q3/keep",
+                             "srt/q3/brand"))
 
 
 def test_q3_kernel_compiles_to_matrix_products_at_sf10(shaped):
     """The hand-written q3 at the benchmark's cardinalities (28,800,991
     rows, 102,000 items, 2 x 1,000 groups): the chip's compiler makes
-    both segment sums matrix products and leaves no scatter."""
+    both segment sums and the dim lookups matrix products and leaves
+    no scatter."""
     from spark_rapids_tpu.models import tpcds
     kernel = tpcds._q3_kernel(10_957, 2, 1000, 3, 11, 100, lambda x: x)
     args = [shaped(shape, dt) for shape, dt in
             _vectors(28_800_991, (I32, I32, I64))
-            + _vectors(730, (I32, I32)) + _vectors(102_000, (I32, I32))]
+            + _vectors(Q3_DATE_ROWS, (I32, I32))
+            + _vectors(Q3_ITEM_ROWS, (I32, I32))]
     compiled = jax.jit(kernel).lower(*args).compile()
     assert _device_gib(compiled) < HBM_GIB
     text = compiled.as_text()
     products = [ln for ln in text.splitlines()
                 if "convolution(" in ln and "srt/q3/segment_sum" in ln]
     assert len(products) == 2 and "scatter" not in text
+    _assert_q3_dims_are_looked_up_by_products(
+        text, ("srt/q3/dim_gather",))
 
 
 def test_q9_compiles_at_sf10_with_f64_divide(shaped):
