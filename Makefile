@@ -97,9 +97,8 @@ perf-smoke:
 # whole-stage fusion gate: the fused q3/q5/q72 catalog pipelines must
 # be byte-identical to the hand-fused oracles, compile exactly ONE
 # executable per stage with ZERO recompiles on a second same-bucket
-# query, beat the op-by-op walk on this box, match the window (q89)
-# and rollup+rank (q67) numpy goldens, and light up
-# srt_stage_fusion_total + the metrics_report stages table
+# query, match the window (q89) and rollup+rank (q67) numpy goldens,
+# and light up srt_stage_fusion_total + the metrics_report stages table
 fusion-smoke:
 	$(PY) scripts/fusion_smoke.py
 

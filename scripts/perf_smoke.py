@@ -225,26 +225,21 @@ def main() -> int:
         fail("tokenizer smoke extraction wrong")
 
     # (e) ISSUE 11: second fused stage query compiles ZERO executables
-    os.environ["SPARK_RAPIDS_TPU_STAGE_FUSION"] = "1"
-    try:
-        from spark_rapids_tpu.models import tpcds as T
-        from spark_rapids_tpu.plan import catalog as PC
-        d1 = T.gen_q5(rows=4000, stores=16, days=60)
-        PC.run_q5(d1, 16, 1 << 13)
-        s_f = CACHE.stats()
-        d2 = T.gen_q5(rows=3600, stores=16, days=60, seed=8)
-        out_f2 = PC.run_q5(d2, 16, 1 << 13)   # same row bucket
-        if CACHE.stats()["compiles"] != s_f["compiles"]:
-            fail(f"second fused q5 compiled "
-                 f"{CACHE.stats()['compiles'] - s_f['compiles']} new "
-                 f"executable(s); whole-stage reuse is broken")
-        ref_f = T.make_q5(16, join_capacity=1 << 13)(d2)
-        for g, w in zip(out_f2, ref_f):
-            if np.asarray(g).tobytes() != np.asarray(w).tobytes():
-                fail("fused q5 bytes differ from the hand-fused "
-                     "oracle")
-    finally:
-        os.environ.pop("SPARK_RAPIDS_TPU_STAGE_FUSION", None)
+    from spark_rapids_tpu.models import tpcds as T
+    from spark_rapids_tpu.plan import catalog as PC
+    d1 = T.gen_q5(rows=4000, stores=16, days=60)
+    PC.run_q5(d1, 16, 1 << 13)
+    s_f = CACHE.stats()
+    d2 = T.gen_q5(rows=3600, stores=16, days=60, seed=8)
+    out_f2 = PC.run_q5(d2, 16, 1 << 13)   # same row bucket
+    if CACHE.stats()["compiles"] != s_f["compiles"]:
+        fail(f"second fused q5 compiled "
+             f"{CACHE.stats()['compiles'] - s_f['compiles']} new "
+             f"executable(s); whole-stage reuse is broken")
+    ref_f = T.make_q5(16, join_capacity=1 << 13)(d2)
+    for g, w in zip(out_f2, ref_f):
+        if np.asarray(g).tobytes() != np.asarray(w).tobytes():
+            fail("fused q5 bytes differ from the hand-fused oracle")
 
     # (f) the kernel-path metric + report table light up
     text = obs.expose_text()

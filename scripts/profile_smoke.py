@@ -55,7 +55,6 @@ def main() -> int:
     from spark_rapids_tpu.plan import catalog as C
     from spark_rapids_tpu.tools import srt_explain as E
 
-    os.environ["SPARK_RAPIDS_TPU_STAGE_FUSION"] = "1"
     obs.enable()
     obs.enable_tracing()
     obs.enable_profiling()

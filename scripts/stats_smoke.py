@@ -100,7 +100,6 @@ def main() -> int:
     from spark_rapids_tpu.tools import doctor
 
     tmp = tempfile.mkdtemp(prefix="stats_smoke_")
-    os.environ["SPARK_RAPIDS_TPU_STAGE_FUSION"] = "1"
     os.environ["SPARK_RAPIDS_TPU_STATS_STORE"] = \
         os.path.join(tmp, "stats_store.json")
     os.environ["SPARK_RAPIDS_TPU_STATS_MISEST_RATIO"] = "8"

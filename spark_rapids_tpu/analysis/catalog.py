@@ -210,8 +210,6 @@ KNOBS: Dict[str, str] = {
     "SPARK_RAPIDS_TPU_JIT_CACHE": "=0 disables the kernel compile cache",
     "SPARK_RAPIDS_TPU_JIT_CACHE_ENTRIES": "compile-cache entry budget",
     "SPARK_RAPIDS_TPU_JIT_CACHE_BYTES": "compile-cache byte budget",
-    "SPARK_RAPIDS_TPU_STAGE_FUSION":
-        "1|0|unset=auto: whole-stage fusion engine choice",
     "SPARK_RAPIDS_TPU_CALIB_CACHE":
         "calibration verdict file (empty disables the file layer)",
     "SPARK_RAPIDS_TPU_CALIB_CACHE_TTL": "verdict file TTL seconds",

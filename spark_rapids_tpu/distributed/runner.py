@@ -12,11 +12,7 @@ rides the shuffle service — that is the contract under test):
                  in the process compile cache, under
                  ``exchange.with_capacity_retry`` (overflow doubles
                  the join budget, same as every other
-                 capacity-bounded pipeline).
-                 ``SPARK_RAPIDS_TPU_STAGE_FUSION=0`` falls back to
-                 the legacy per-op jit of the SHARED models/tpcds
-                 kernels (``_q5_partials`` / ``_q72_partials``) — the
-                 byte-identity oracle of the fused path;
+                 capacity-bounded pipeline);
   3. reduce-scatter — the partial group table is sliced into
                  rank-owned chunks, each chunk shipped to its owner as
                  kudo tables over the socket shuffle
@@ -27,11 +23,10 @@ rides the shuffle service — that is the contract under test):
                  reassembles the GLOBAL group table;
   5. finish    — the reduce side is the matching fused finish stage
                  (ONE executable again — a rank runs exactly one
-                 program between kudo exchanges), or the SHARED
-                 ``_q5_finish`` / ``_q72_finish`` jits under the
-                 escape hatch; either way the output bytes are
-                 identical to the single-process pipeline's by
-                 construction.
+                 program between kudo exchanges); the output bytes
+                 are identical to the single-process pipeline's
+                 (``single_q5`` / ``single_q72``, the hand-written
+                 models/tpcds kernels) by construction.
 
 Run as a module (``python -m spark_rapids_tpu.distributed.runner``)
 by scripts/dist_launch.py; the per-query entry points are also
@@ -163,14 +158,6 @@ def _shard(a, rank: int, world: int):
     return a[rank * per: (rank + 1) * per]
 
 
-def _fused() -> bool:
-    """Stage fusion on for this rank?  (The env escape hatch —
-    SPARK_RAPIDS_TPU_STAGE_FUSION=0 — restores the legacy per-op jit
-    of the shared models/tpcds kernel halves.)"""
-    from spark_rapids_tpu.plan.compiler import fusion_mode
-    return fusion_mode() != "off"
-
-
 @contextlib.contextmanager
 def _profiled(op: str, rank: int, world: int):
     """Per-rank query-profile session (ISSUE 13): when
@@ -198,12 +185,10 @@ def run_dist_q5(params: Optional[dict] = None, *, transport=None
     """Distributed q5 on this rank's shard.  Returns the FULL query
     result (every rank converges to the same bytes) as numpy arrays:
     key / sales / rets / profit / overflow."""
-    import jax
-    import jax.numpy as jnp
-
     from spark_rapids_tpu import observability as _obs
     from spark_rapids_tpu.models import tpcds as T
     from spark_rapids_tpu.parallel import exchange as X
+    from spark_rapids_tpu.plan import catalog as C
 
     p = dict(Q5_PARAMS, **(params or {}))
     if transport is None:
@@ -220,19 +205,8 @@ def run_dist_q5(params: Optional[dict] = None, *, transport=None
                       d.r_date, d.r_store, d.r_amt, d.r_loss)
         ) + (d.d_date,)
 
-        # one read per query: a mid-query env flip must not leave the
-        # finish step without the partials step's import/engine
-        fused = _fused()
-        if fused:
-            from spark_rapids_tpu.plan import catalog as C
-            outs, _cap = C.run_q5_partials(
-                shard_args, p["stores"], p["join_capacity"])
-        else:
-            def build(cap):
-                return jax.jit(T._q5_partials(p["stores"], cap))
-
-            outs, _cap = T.run_with_capacity_retry(
-                build, shard_args, p["join_capacity"])
+        outs, _cap = C.run_q5_partials(
+            shard_args, p["stores"], p["join_capacity"])
         sales, rets, profit, seen, of = outs
         (sales, rets, profit, seen), of_any = \
             _reduce_scatter_allgather(
@@ -241,16 +215,10 @@ def run_dist_q5(params: Optional[dict] = None, *, transport=None
                 [np.asarray(sales), np.asarray(rets),
                  np.asarray(profit), np.asarray(seen)],
                 bool(np.asarray(of)))
-        if fused:
-            key_s, sales_s, ret_s, profit_s, _of = C.run_q5_finish(
-                np.asarray(sales), np.asarray(rets),
-                np.asarray(profit), np.asarray(seen), of_any,
-                np.asarray(d.st_id), p["stores"])
-        else:
-            fin = jax.jit(T._q5_finish(p["stores"]))
-            key_s, sales_s, ret_s, profit_s = fin(
-                jnp.asarray(sales), jnp.asarray(rets),
-                jnp.asarray(profit), jnp.asarray(seen), d.st_id)
+        key_s, sales_s, ret_s, profit_s, _of = C.run_q5_finish(
+            np.asarray(sales), np.asarray(rets),
+            np.asarray(profit), np.asarray(seen), of_any,
+            np.asarray(d.st_id), p["stores"])
         return {"key": np.asarray(key_s), "sales": np.asarray(sales_s),
                 "rets": np.asarray(ret_s),
                 "profit": np.asarray(profit_s),
@@ -288,12 +256,10 @@ def run_elastic_q5(params: Optional[dict] = None, *, transport=None
     worker recomputes its own shards and catches up on the rest by
     CRC'd replay — every rank, however it got here, converges to
     bytes identical to ``single_q5``."""
-    import jax
-    import jax.numpy as jnp
-
     from spark_rapids_tpu import observability as _obs
     from spark_rapids_tpu.models import tpcds as T
     from spark_rapids_tpu.parallel import exchange as X
+    from spark_rapids_tpu.plan import catalog as C
     from spark_rapids_tpu.shuffle import kudo as _kudo
     from spark_rapids_tpu.shuffle.schema import schema_of_table
 
@@ -312,7 +278,6 @@ def run_elastic_q5(params: Optional[dict] = None, *, transport=None
         rows = max(int(p["rows"]) // (8 * world0), 1) * 8 * world0
         d = T.gen_q5(rows=rows, stores=p["stores"], days=p["days"])
         _maybe_die("q5:scan")
-        fused = _fused()
 
         def compute_part(shard: int, ctx=None):
             """Deterministic per-shard partials -> one int64 kudo
@@ -325,20 +290,8 @@ def run_elastic_q5(params: Optional[dict] = None, *, transport=None
                 for a in (d.s_date, d.s_store, d.s_price, d.s_profit,
                           d.r_date, d.r_store, d.r_amt, d.r_loss)
             ) + (d.d_date,)
-            if fused:
-                from spark_rapids_tpu.plan import catalog as C
-                outs, _cap = C.run_q5_partials(
-                    args, p["stores"], p["join_capacity"], ctx=ctx)
-            else:
-                def build(cap):
-                    return jax.jit(T._q5_partials(p["stores"], cap))
-
-                if ctx is not None:
-                    ctx.check_cancel()
-                outs, _cap = T.run_with_capacity_retry(
-                    build, args, p["join_capacity"])
-                if ctx is not None:
-                    ctx.check_cancel()
+            outs, _cap = C.run_q5_partials(
+                args, p["stores"], p["join_capacity"], ctx=ctx)
             sales, rets, profit, seen, of = (np.asarray(o)
                                              for o in outs)
             n = len(sales)
@@ -369,16 +322,9 @@ def run_elastic_q5(params: Optional[dict] = None, *, transport=None
             else:
                 vecs = [a + b for a, b in zip(vecs, cols[:-1])]
         sales, rets, profit, seen = vecs
-        if fused:
-            from spark_rapids_tpu.plan import catalog as C
-            key_s, sales_s, ret_s, profit_s, _of = C.run_q5_finish(
-                sales, rets, profit, seen, of_any,
-                np.asarray(d.st_id), p["stores"])
-        else:
-            fin = jax.jit(T._q5_finish(p["stores"]))
-            key_s, sales_s, ret_s, profit_s = fin(
-                jnp.asarray(sales), jnp.asarray(rets),
-                jnp.asarray(profit), jnp.asarray(seen), d.st_id)
+        key_s, sales_s, ret_s, profit_s, _of = C.run_q5_finish(
+            sales, rets, profit, seen, of_any,
+            np.asarray(d.st_id), p["stores"])
         return {"key": np.asarray(key_s), "sales": np.asarray(sales_s),
                 "rets": np.asarray(ret_s),
                 "profit": np.asarray(profit_s),
@@ -393,12 +339,10 @@ def run_dist_q72(params: Optional[dict] = None, *, transport=None
     """Distributed q72: catalog_sales sharded row-parallel, inventory
     + item dim replicated (the same plan as the mesh variant), counts
     reduce-scattered/allgathered over the kudo shuffle."""
-    import jax
-    import jax.numpy as jnp
-
     from spark_rapids_tpu import observability as _obs
     from spark_rapids_tpu.models import tpcds as T
     from spark_rapids_tpu.parallel import exchange as X
+    from spark_rapids_tpu.plan import catalog as C
 
     p = dict(Q72_PARAMS, **(params or {}))
     if transport is None:
@@ -416,32 +360,17 @@ def run_dist_q72(params: Optional[dict] = None, *, transport=None
             _shard(d.cs_qty, rank, world),
             d.inv_item, d.inv_date, d.inv_qty, d.item_id)
 
-        fused = _fused()
-        if fused:
-            from spark_rapids_tpu.plan import catalog as C
-            outs, _cap = C.run_q72_partials(
-                shard_args, p["items"], p["max_week"],
-                p["join_capacity"], p["week0"])
-        else:
-            def build(cap):
-                return jax.jit(T._q72_partials(
-                    p["items"], p["max_week"], cap, p["week0"]))
-
-            outs, _cap = T.run_with_capacity_retry(
-                build, shard_args, p["join_capacity"])
+        outs, _cap = C.run_q72_partials(
+            shard_args, p["items"], p["max_week"],
+            p["join_capacity"], p["week0"])
         counts, of = outs
         (counts,), of_any = _reduce_scatter_allgather(
             transport, OpIds.Q72_REDUCE_SCATTER,
             OpIds.Q72_ALLGATHER, [np.asarray(counts)],
             bool(np.asarray(of)))
-        if fused:
-            item, week, cnt, _of = C.run_q72_finish(
-                np.asarray(counts), of_any, p["items"],
-                p["max_week"], p["limit"], p["week0"])
-        else:
-            fin = jax.jit(T._q72_finish(
-                p["items"], p["max_week"], p["limit"], p["week0"]))
-            item, week, cnt = fin(jnp.asarray(counts))
+        item, week, cnt, _of = C.run_q72_finish(
+            np.asarray(counts), of_any, p["items"],
+            p["max_week"], p["limit"], p["week0"])
         return {"item": np.asarray(item), "week": np.asarray(week),
                 "cnt": np.asarray(cnt),
                 "overflow": np.asarray(of_any)}

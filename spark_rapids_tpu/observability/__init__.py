@@ -260,8 +260,8 @@ KERNEL_PATH = METRICS.counter(
 STAGE_FUSION = METRICS.counter(
     "srt_stage_fusion_total",
     "Whole-stage executions by stage and outcome (fused = one AOT "
-    "executable, unfused = op-by-op walk, compile = a fused "
-    "executable was built this run)", labels=("stage", "outcome"),
+    "executable, compile = a fused executable was built this run)",
+    labels=("stage", "outcome"),
     max_series=128)
 SEGMENT_SUM = METRICS.counter(
     "srt_segment_sum_total",
@@ -1368,11 +1368,11 @@ def record_stage_fusion(stage: str, outcome: str, *, digest: str = "",
                         wall_ns: int = 0, nodes: int = 0,
                         compiled: bool = False) -> None:
     """Whole-stage fusion hook (plan/compiler.py): one execution of
-    ``stage`` took ``outcome`` ('fused' = one AOT executable,
-    'unfused' = the op-by-op walk).  ``compiled`` marks runs that
+    ``stage`` took ``outcome`` ('fused' = one AOT executable, the
+    only value the compiler passes).  ``compiled`` marks runs that
     built a new fused executable (cache-hit runs don't); ``nodes`` is
-    the dispatch count the unfused walk would pay.  The journal event
-    feeds the metrics_report "stages" table."""
+    the dispatch count the reference walk would pay.  The journal
+    event feeds the metrics_report "stages" table."""
     if not _SWITCH.enabled:
         return
     STAGE_FUSION.inc(labels=(stage, outcome))

@@ -10,7 +10,7 @@ it by assembling, at query end, ONE typed artifact per query from
 seams that already exist:
 
   * stage records   — plan/compiler.py reports every stage execution
-                      (plan digest, fused/unfused engine, wall ns,
+                      (plan digest, engine, wall ns,
                       compile-vs-cache-hit, dispatch count, per-input
                       rows/bucket/pad-waste) while a session is
                       active on the executing thread;

@@ -64,7 +64,7 @@ _MAX_NODES_REPORTED = 64
 
 
 def misest_ratio() -> float:
-    """Sentinel threshold (dynamic read, like fusion_mode): actual
+    """Sentinel threshold (dynamic read): actual
     vs estimate divergence past this ratio fires the misestimate
     chain."""
     try:

@@ -8,26 +8,19 @@ power-of-two row bucket)`` — so a TPC-DS stage pays one dispatch and
 zero HBM round-trips between its ops, and the second same-bucket
 query compiles NOTHING.
 
-Engine choice is calibrated at STAGE granularity (perf/calibrate,
-promoted from the PR-9 per-op verdicts): the fused program inlines the
-device hash-join probe and friends, the op-by-op walk lets every op
-take its own calibrated engine — the first large stage of a given
-shape digest times both and the winner is cached.  Operators can force
-either side with ``SPARK_RAPIDS_TPU_STAGE_FUSION=1|0`` (the escape
-hatch); both paths are byte-identical by contract, fusion is a SPEED
-choice only.
-
-Execution modes from one plan:
+What runs a plan:
 
   * :meth:`CompiledStage.run` — single process, one AOT executable;
-  * :meth:`CompiledStage.run_unfused` — eager op-by-op walk (the
-    dispatch-per-op world this PR retires; kept as the calibration
-    candidate and the fused-vs-unfused bench oracle);
+    the only way a stage executes (no switch selects another);
   * :func:`fused_pipeline_fn` — the WHOLE pipeline (boundaries elided,
     ``Reduce`` -> ``lax.psum``) as one function for ``shard_map``: a
     mesh rank runs one program end to end;
   * stage-by-stage through the distributed runner, with the kudo
     socket shuffle carrying each boundary (distributed/runner.py).
+
+:meth:`CompiledStage.run_unfused` walks the same nodes eagerly, one
+dispatch each.  It is the REFERENCE the tests compare the executable
+with, byte for byte; ``run`` never calls it.
 """
 
 from __future__ import annotations
@@ -42,28 +35,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from spark_rapids_tpu.plan import ir
-
-# ------------------------------------------------------------------- knobs
-
-
-def fusion_mode() -> str:
-    """'off' | 'on' | 'auto' from SPARK_RAPIDS_TPU_STAGE_FUSION
-    (dynamic read — flipping it mid-process works, same contract as
-    the jit-cache switch).  'auto' calibrates fused vs op-by-op per
-    (stage, shape digest, backend)."""
-    v = os.environ.get("SPARK_RAPIDS_TPU_STAGE_FUSION", "")
-    if v == "0":
-        return "off"
-    if v == "1":
-        return "on"
-    return "auto"
-
-
-# stage calibration samples bucketed inputs past this many rows (the
-# PR-9 join discipline: timing both engines over an unbounded stage
-# would stall the first query under the lifeguard deadline; the size
-# CLASS still keys the verdict)
-_STAGE_CALIB_MAX_ROWS = 1 << 18
 
 
 def _canon_dtype(a) -> str:
@@ -291,8 +262,8 @@ def _eval_nodes(plan: ir.StagePlan, env,
 def _eval_node(node, env, reduce_axis: Optional[str],
                stage: str) -> None:
     """Evaluate one node into ``env`` (shared by the fused trace and
-    the op-by-op walk — one evaluator, so the two engines cannot
-    drift), under the device-side name ``srt/<stage>/<node>``: the
+    the reference walk — one evaluator, so the two cannot drift),
+    under the device-side name ``srt/<stage>/<node>``: the
     node's first output column, which survives a rewrite of the op
     underneath (a trace names a fusion by its HLO text otherwise)."""
     with jax.named_scope(f"srt/{stage}/{node.outs()[0]}"):
@@ -394,8 +365,9 @@ def _eval_kind(node, env, reduce_axis: Optional[str]) -> None:
 
 
 class CompiledStage:
-    """One stage, three engines (fused AOT / op-by-op / shard_map
-    body), one evaluator."""
+    """One stage: the fused AOT executable (``run``) and the
+    ``shard_map`` body (``fused_fn``), on one evaluator; the eager
+    walk (``run_unfused``) is the tests' reference."""
 
     def __init__(self, plan: ir.StagePlan):
         self.plan = plan.validate()
@@ -410,8 +382,8 @@ class CompiledStage:
         # the profile record reads it)
         self._engines: Dict[str, str] = {}
 
-    # number of op dispatches the unfused walk pays (the fused program
-    # pays exactly 1) — the before/after evidence in BENCH_r07
+    # number of op dispatches the reference walk pays (the fused
+    # program pays exactly 1)
     @property
     def dispatch_count(self) -> int:
         return len(self.plan.nodes)
@@ -540,13 +512,12 @@ class CompiledStage:
 
     def _run_digest(self, parts) -> str:
         """The full run key: stage-plan digest | all-operand schema
-        digest — the jit-cache key, the calibration verdict key, AND
-        the stage_fusion journal digest (one derivation, no drift)."""
+        digest — the jit-cache key AND the stage_fusion journal
+        digest (one derivation, no drift)."""
         from spark_rapids_tpu.perf.calibrate import operands_digest
         return f"{self.plan.digest}|{operands_digest(parts)}"
 
-    def _run_fused(self, inputs, run_digest: Optional[str] = None,
-                   taps: bool = False) -> tuple:
+    def _run_fused(self, inputs, taps: bool = False) -> tuple:
         """ONE AOT executable through the process compile cache,
         keyed by (stage-plan digest, all-operand schema digest, row
         bucket).  Returns (outputs, compile_ns, run_digest, counts) —
@@ -557,15 +528,15 @@ class CompiledStage:
         tapped per-node row-count vector (None without ``taps``); a
         tapped program is a DIFFERENT executable, so the compile-cache
         key gets a ``|taps`` suffix while the reported run digest
-        stays the base one — journal/profile/calibration rows fold
-        together whichever way the stats switch points."""
+        stays the base one — journal and profile rows fold together
+        whichever way the stats switch points."""
         from spark_rapids_tpu import observability as _obs
         from spark_rapids_tpu.perf import jit_cache as _jc
 
         with _obs.TRACER.span("stage_bind", kind="phase") as span:
             args, parts, bucket = self._bind_args(inputs)
             span.set_attr("bucket", bucket)
-        digest = run_digest or self._run_digest(parts)
+        digest = self._run_digest(parts)
         key_digest = f"{digest}|taps" if taps else digest
         fn = self._fused_callable(taps=taps)
         compiled_now = []
@@ -603,9 +574,9 @@ class CompiledStage:
             digest, counts
 
     def _walk_env(self, inputs) -> Dict[str, object]:
-        """The eager op-by-op walk's full environment (every node
-        output by name) — run_unfused projects the plan outputs out
-        of it, the stats tap reads the same count expressions the
+        """The reference walk's full environment (every node output by
+        name): ``run_unfused`` projects the plan outputs out of it, and
+        the stats tests read from it the same count expressions the
         fused program stacks."""
         env: Dict[str, object] = {}
         for inp in self.plan.inputs:
@@ -619,30 +590,26 @@ class CompiledStage:
         self._engines.update(env.get(_ENGINES, {}))
         return env
 
-    def _host_counts(self, env) -> list:
-        """Tapped counts off an eager walk's env, as python ints."""
-        return [int(v) for v in _tap_counts(self.plan, env)]
-
     def run_unfused(self, inputs) -> tuple:
-        """Op-by-op eager walk on unpadded inputs: every node pays its
-        own dispatch + HBM round trip.  Byte-identical to the fused
-        program (same evaluator, exact int aggregates) — the escape
-        hatch, the calibration rival, and the bench baseline."""
+        """The REFERENCE: an op-by-op eager walk on unpadded inputs,
+        every node paying its own dispatch + HBM round trip.  Same
+        evaluator and exact int aggregates as the fused program, so
+        the tests compare the two byte for byte.  ``run`` never calls
+        it and nothing selects it."""
         env = self._walk_env(inputs)
         return tuple(env[o] for o in self.plan.outputs)
 
     # -------------------------------------------------------------- entry
 
     def run(self, inputs: Mapping[str, Sequence]) -> tuple:
-        """Execute the stage under the current fusion mode, recording
+        """Execute the stage as its one fused executable, recording
         ``srt_stage_fusion_total{stage,outcome}`` + a ``stage_fusion``
-        journal event either way.  Walls are measured past
+        journal event.  The wall is measured past
         ``block_until_ready`` (an async backend's dispatch-only time
-        would lie), and a first-call calibration's measurement time is
-        NOT folded into the winner's recorded wall.  The whole
-        execution is the timeline's ``stage_run:<plan>`` span, with
-        ``stage_bind``, ``stage_compile``, ``dispatch`` and
-        ``device_wait`` under it."""
+        would lie).  The whole execution is the timeline's
+        ``stage_run:<plan>`` span, with ``stage_bind``,
+        ``stage_compile``, ``dispatch`` and ``device_wait`` under
+        it."""
         from spark_rapids_tpu import observability as _obs
 
         with _obs.TRACER.span(f"stage_run:{self.plan.name}",
@@ -652,54 +619,32 @@ class CompiledStage:
     def _run(self, inputs: Mapping[str, Sequence], span) -> tuple:
         from spark_rapids_tpu import observability as _obs
 
-        mode = fusion_mode()
         # data-statistics tap (ISSUE 20): ONE attribute read when the
         # stats plane is off — no observation dict, no extra outputs,
         # the exact executable PR 11 shipped
         taps = _obs.STATS.enabled
-        compiled = False
-        compile_ns = 0
-        wall_ns = None
-        counts = None
+        t0 = time.monotonic_ns()
         # the event digest is the full RUN key (plan | operand
         # shapes): the stages table must not average walls across row
-        # buckets, or a small escape-hatch run would skew the ratio a
-        # large fused workload reads as its regression signal
-        if mode == "auto":
-            out, compiled, outcome, wall_ns, digest, compile_ns, \
-                counts = self._run_calibrated(inputs, taps=taps)
-        else:
-            t0 = time.monotonic_ns()
-            if mode == "off":
-                if taps:
-                    env = self._walk_env(inputs)
-                    out = tuple(env[o] for o in self.plan.outputs)
-                    counts = self._host_counts(env)
-                else:
-                    out = self.run_unfused(inputs)
-                outcome = "unfused"
-                digest = self._run_digest(
-                    self._shape_parts(inputs)[0])
-            else:
-                out, compile_ns, digest, counts = self._run_fused(
-                    inputs, taps=taps)
-                compiled = bool(compile_ns)
-                outcome = "fused"
-            with _obs.TRACER.span("device_wait", kind="phase"):
-                jax.block_until_ready(out)
-            wall_ns = time.monotonic_ns() - t0
+        # buckets
+        out, compile_ns, digest, counts = self._run_fused(
+            inputs, taps=taps)
+        compiled = bool(compile_ns)
+        with _obs.TRACER.span("device_wait", kind="phase"):
+            jax.block_until_ready(out)
+        wall_ns = time.monotonic_ns() - t0
         if span is not _obs.NOOP_SPAN:
             from spark_rapids_tpu.perf.jit_cache import bucket_rows
             rows = max((int(jnp.shape(inputs[i.name][0])[0])
                         for i in self.plan.inputs if i.bucket),
                        default=0)
             bucket = bucket_rows(rows) if rows else 0
-            for k, v in (("digest", digest), ("engine", outcome),
+            for k, v in (("digest", digest), ("engine", "fused"),
                          ("rows", rows), ("bucket", bucket),
                          ("pad_rows", bucket - rows)):
                 span.set_attr(k, v)
         _obs.record_stage_fusion(
-            self.plan.name, outcome, digest=digest,
+            self.plan.name, "fused", digest=digest,
             wall_ns=wall_ns, nodes=self.dispatch_count,
             compiled=compiled)
         stats = (self._note_stats(inputs, digest, counts)
@@ -710,7 +655,7 @@ class CompiledStage:
         # record dict (node descriptors, pad-waste) is never built
         if _obs.PROFILER.active():
             _obs.PROFILER.note_stage(self._profile_record(
-                inputs, digest=digest, engine=outcome,
+                inputs, digest=digest,
                 wall_ns=wall_ns, compiled=compiled,
                 compile_ns=compile_ns, stats=stats))
         return out
@@ -720,9 +665,8 @@ class CompiledStage:
         return the profile's per-stage ``stats`` section.  Input row
         counts are host-known (the n_valid scalars the binder already
         computed); tapped counts arrive as the executable's int32
-        vector (fused) or python ints (eager walk) — np.asarray is
-        the only device sync and it reads values the program computed
-        anyway."""
+        vector — np.asarray is the only device sync and it reads
+        values the program computed anyway."""
         import numpy as np
 
         from spark_rapids_tpu import observability as _obs
@@ -787,7 +731,7 @@ class CompiledStage:
                 outs.append(self.run(stage_inputs))
         return outs
 
-    def _profile_record(self, inputs, *, digest: str, engine: str,
+    def _profile_record(self, inputs, *, digest: str,
                         wall_ns, compiled: bool,
                         compile_ns: int = 0,
                         stats: Optional[dict] = None) -> dict:
@@ -814,14 +758,13 @@ class CompiledStage:
         rec = {
             "stage": self.plan.name,
             "digest": digest,
-            "engine": ("unfused" if engine == "unfused" else "fused"),
+            "engine": "fused",
             "compiled": bool(compiled),
             "compile_ns": int(compile_ns),
             "wall_ns": int(wall_ns or 0),
             "t_start_ns": t_end_ns - int(wall_ns or 0),
             "t_end_ns": t_end_ns,
-            "dispatches": (self.dispatch_count
-                           if engine == "unfused" else 1),
+            "dispatches": 1,
             "nodes_total": self.dispatch_count,
             "nodes": [{"kind": type(n).__name__,
                        "outs": list(n.outs()),
@@ -834,113 +777,6 @@ class CompiledStage:
         if stats is not None:
             rec["stats"] = stats
         return rec
-
-    def _calibration_sample(self, inputs):
-        """Row-slice oversized bucketed inputs for the measurement
-        runs (the verdict still keys on the FULL-size digest — size
-        class separation is operands_digest's job).  Returns
-        (sample_inputs, sampled?)."""
-        sampled = False
-        out = {}
-        for inp in self.plan.inputs:
-            arrs = tuple(inputs[inp.name])
-            if inp.bucket and \
-                    int(arrs[0].shape[0]) > _STAGE_CALIB_MAX_ROWS:
-                arrs = tuple(a[:_STAGE_CALIB_MAX_ROWS] for a in arrs)
-                sampled = True
-            out[inp.name] = arrs
-        return out, sampled
-
-    def _run_calibrated(self, inputs, taps: bool = False):
-        """Stage-granularity engine verdict: the first stage of a
-        given (plan digest, operand shapes, backend) measures fused vs
-        op-by-op — on row-sliced samples past _STAGE_CALIB_MAX_ROWS,
-        so a huge first query can't stall under the lifeguard deadline
-        — and every later one takes the cached winner.  Both engines
-        are byte-identical, so calibration is a speed choice only (the
-        PR-9 contract, promoted from per-op to per-stage).  Returns
-        (outputs, compiled, outcome, wall_ns, run_digest, compile_ns,
-        counts) with the wall of the winning engine's OWN execution
-        (measurement runs excluded); ``counts`` is the winner's
-        tapped row-count vector (None without ``taps``, and None when
-        a sampled measurement won on sliced inputs — sliced counts
-        would reconcile against nothing)."""
-        from spark_rapids_tpu import observability as _obs
-        from spark_rapids_tpu.perf import calibrate
-
-        parts, _bucket = self._shape_parts(inputs)
-        digest = self._run_digest(parts)
-        compiled = []
-        last: Dict[str, tuple] = {}
-        walls: Dict[str, int] = {}
-        tap_cell: Dict[str, object] = {}
-        calib_inputs, sampled = self._calibration_sample(inputs)
-
-        def timed(tag, fn):
-            def go():
-                t0 = time.monotonic_ns()
-                out = fn()
-                jax.block_until_ready(out)
-                last[tag] = out
-                walls[tag] = time.monotonic_ns() - t0
-                return out
-            return go
-
-        def fused_body():
-            # sampled inputs key their own (smaller) executable; the
-            # full-size digest stays the verdict key
-            out, c, _d, cts = self._run_fused(
-                calib_inputs, run_digest=None if sampled else digest,
-                taps=taps)
-            if c:
-                compiled.append(c)
-            if cts is not None:
-                tap_cell["fused"] = cts
-            return out
-
-        def unfused_body():
-            if not taps:
-                return self.run_unfused(calib_inputs)
-            env = self._walk_env(calib_inputs)
-            tap_cell["op_by_op"] = self._host_counts(env)
-            return tuple(env[o] for o in self.plan.outputs)
-
-        path = calibrate.pick_path(
-            f"stage:{self.plan.name}", digest,
-            {"fused": timed("fused", fused_body),
-             "op_by_op": timed("op_by_op", unfused_body)},
-            default="fused")
-        if path not in ("fused", "op_by_op"):
-            # pick_path returns env pins verbatim — callers validate
-            # membership (the join-router discipline); an unknown pin
-            # falls back to the default rather than dereferencing it
-            path = "fused"
-        outcome = "unfused" if path == "op_by_op" else "fused"
-        if not sampled and path in last:
-            # calibration just ran the winner on the REAL inputs —
-            # reuse its outputs and its measured wall instead of
-            # paying a third execution
-            return (last[path], bool(compiled), outcome, walls[path],
-                    digest, sum(compiled), tap_cell.get(path))
-        t0 = time.monotonic_ns()
-        counts = None
-        if path == "op_by_op":
-            if taps:
-                env = self._walk_env(inputs)
-                out = tuple(env[o] for o in self.plan.outputs)
-                counts = self._host_counts(env)
-            else:
-                out = self.run_unfused(inputs)
-        else:
-            out, c, _d, counts = self._run_fused(
-                inputs, run_digest=digest, taps=taps)
-            if c:
-                compiled.append(c)
-        with _obs.TRACER.span("device_wait", kind="phase"):
-            jax.block_until_ready(out)
-        return (out, bool(compiled), outcome,
-                time.monotonic_ns() - t0, digest, sum(compiled),
-                counts)
 
 
 # plan-verify gate (ISSUE 12): every distinct plan digest is verified

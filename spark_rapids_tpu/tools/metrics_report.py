@@ -368,11 +368,10 @@ def render_kernel_path_table(registry: Optional[dict]) -> List[str]:
 
 def stage_rows(events: List[dict]) -> List[dict]:
     """Whole-stage fusion accounting from ``stage_fusion`` journal
-    events, one row per (stage, plan digest): executions by engine,
-    fused-executable compiles vs cache hits, and the measured
-    fused-vs-unfused wall ratio (>1 means fusion is winning).  The
-    ``srt_stage_fusion_total{stage,outcome}`` counter carries the same
-    outcomes to Prometheus."""
+    events, one row per (stage, plan digest): executions,
+    fused-executable compiles vs cache hits, and the steady-state
+    wall.  The ``srt_stage_fusion_total{stage,outcome}`` counter
+    carries the same outcomes to Prometheus."""
     agg: Dict[tuple, dict] = {}
     for e in events:
         if e.get("kind") != "stage_fusion":
@@ -380,41 +379,28 @@ def stage_rows(events: List[dict]) -> List[dict]:
         key = (str(e.get("stage", "?")), str(e.get("digest", "?")))
         a = agg.setdefault(key, {
             "stage": key[0], "digest": key[1], "nodes": 0,
-            "fused": 0, "fused_timed": 0, "unfused": 0,
-            "compiles": 0, "fused_ns": 0, "unfused_ns": 0})
+            "fused": 0, "fused_timed": 0, "compiles": 0,
+            "fused_ns": 0})
         a["nodes"] = max(a["nodes"], int(e.get("nodes", 0)))
-        outcome = str(e.get("outcome", "?"))
-        if outcome == "fused":
+        if str(e.get("outcome", "?")) == "fused":
             a["fused"] += 1
             # a run that BUILT its executable has lower+compile inside
-            # its wall; folding that into the mean would make a 7x win
-            # render as ratio << 1 — only steady-state walls count
+            # its wall — only steady-state walls count
             if not e.get("compiled"):
                 a["fused_timed"] += 1
                 a["fused_ns"] += int(e.get("wall_ns", 0))
-        elif outcome == "unfused":
-            a["unfused"] += 1
-            a["unfused_ns"] += int(e.get("wall_ns", 0))
         if e.get("compiled"):
             a["compiles"] += 1
     rows = []
     for a in agg.values():
         a["cache_hits"] = max(a["fused"] - a["compiles"], 0)
-        fused_mean = (a["fused_ns"] / a["fused_timed"]
-                      if a["fused_timed"] else 0.0)
-        unfused_mean = (a["unfused_ns"] / a["unfused"]
-                        if a["unfused"] else 0.0)
-        a["ratio"] = (unfused_mean / fused_mean
-                      if fused_mean and unfused_mean else 0.0)
         rows.append(a)
     return sorted(rows, key=lambda a: (a["stage"], a["digest"]))
 
 
 def render_stage_table(events: List[dict]) -> List[str]:
-    """Stage-fusion table: one executable per stage, zero compiles on
-    repeats, and unfused/fused wall ratio > 1 are the healthy signals;
-    a stage stuck on the unfused engine at scale is the 'fusion went
-    dead' regression signal."""
+    """Stage-fusion table: one executable per stage and zero compiles
+    on repeats are the healthy signals."""
     rows = stage_rows(events)
     out = ["", "stage fusion (per stage digest)", ""]
     if not rows:
@@ -422,15 +408,13 @@ def render_stage_table(events: List[dict]) -> List[str]:
         return out
     w = max(len(r["stage"]) for r in rows)
     hdr = (f"{'stage':<{w}}  {'digest':<16}  {'nodes':>5}  "
-           f"{'fused':>5}  {'unfus':>5}  {'cmpl':>4}  {'hits':>4}  "
-           f"{'fused_ms':>9}  {'unfus_ms':>9}  {'ratio':>6}")
+           f"{'fused':>5}  {'cmpl':>4}  {'hits':>4}  "
+           f"{'fused_ms':>9}")
     out.append(hdr)
     out.append("-" * len(hdr))
     for r in rows:
         fused_ms = (r["fused_ns"] / r["fused_timed"] / 1e6
                     if r["fused_timed"] else 0.0)
-        unfused_ms = (r["unfused_ns"] / r["unfused"] / 1e6
-                      if r["unfused"] else 0.0)
         # run digests are "plan|operands"; show a slice of BOTH
         # halves or same-plan rows at different buckets look identical
         dig = r["digest"]
@@ -439,10 +423,9 @@ def render_stage_table(events: List[dict]) -> List[str]:
             dig = f"{plan_d[:7]}|{ops_d[:8]}"
         out.append(
             f"{r['stage']:<{w}}  {dig[:16]:<16}  "
-            f"{r['nodes']:>5}  {r['fused']:>5}  {r['unfused']:>5}  "
+            f"{r['nodes']:>5}  {r['fused']:>5}  "
             f"{r['compiles']:>4}  {r['cache_hits']:>4}  "
-            f"{fused_ms:>9.3f}  {unfused_ms:>9.3f}  "
-            f"{r['ratio']:>6.2f}")
+            f"{fused_ms:>9.3f}")
     return out
 
 

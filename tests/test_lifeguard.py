@@ -468,12 +468,16 @@ def test_failed_probe_reopens_quarantine():
 
     s = lifeguard_server(runner, quarantine_failures=1,
                          cooldown_s=0.1)
+    # the breaker reads a clock the test moves: a sleep past the
+    # cooldown raced the wall clock under loaded workers
+    now = [1000.0]
+    s._quarantine.clock = lambda: now[0]
     try:
         qid = s.submit("t", "bad")
         assert s.poll(qid, timeout_s=20)["state"] == "failed"
         with pytest.raises(ServerOverloaded):
             s.submit("t", "bad")
-        time.sleep(0.15)
+        now[0] += 0.15
         probe = s.submit("t", "bad")     # half-open probe
         assert s.poll(probe, timeout_s=20)["state"] == "failed"
         # reopened, with escalated cooldown > the original 0.1

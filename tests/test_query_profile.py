@@ -56,11 +56,6 @@ def profiling():
         obs.disable()
 
 
-@pytest.fixture
-def fused_on(monkeypatch):
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_STAGE_FUSION", "1")
-
-
 # ------------------------------------------------------ session lifecycle
 
 
@@ -305,7 +300,7 @@ class TestAttribution:
 
 class TestStageRecords:
 
-    def test_fused_q3_stage_record(self, profiling, fused_on):
+    def test_fused_q3_stage_record(self, profiling):
         from spark_rapids_tpu.models import tpcds
         from spark_rapids_tpu.plan import catalog as C
         d = tpcds.gen_q3(rows=1500, items=64, days=730, brands=8)
@@ -324,19 +319,7 @@ class TestStageRecords:
         assert facts[0]["pad_rows"] == 548   # bucket - rows
         assert p["hot_stage"] == "q3"
 
-    def test_unfused_engine_recorded(self, profiling, monkeypatch):
-        monkeypatch.setenv("SPARK_RAPIDS_TPU_STAGE_FUSION", "0")
-        from spark_rapids_tpu.models import tpcds
-        from spark_rapids_tpu.plan import catalog as C
-        d = tpcds.gen_q3(rows=900, items=64, days=730, brands=8)
-        sess = obs.PROFILER.begin("q")
-        C.run_q3(d, 10_957, years=3, brands=8, manufact=2)
-        p = obs.PROFILER.end(sess)
-        (s,) = p["stages"]
-        assert s["engine"] == "unfused"
-        assert s["dispatches"] == s["nodes_total"] > 1
-
-    def test_repeat_calls_aggregate(self, profiling, fused_on):
+    def test_repeat_calls_aggregate(self, profiling):
         from spark_rapids_tpu.models import tpcds
         from spark_rapids_tpu.plan import catalog as C
         d = tpcds.gen_q3(rows=1100, items=64, days=730, brands=8)
@@ -348,7 +331,7 @@ class TestStageRecords:
         assert s["calls"] == 2
         assert s["wall_ns"] > 0
 
-    def test_noop_when_disabled(self, fused_on):
+    def test_noop_when_disabled(self):
         prior = obs.is_profiling_enabled()
         obs.disable_profiling()
         try:
@@ -725,7 +708,7 @@ class TestDoors:
 class TestBundleAndDoctor:
 
     def test_bundle_carries_profile_and_tools_read_it(
-            self, profiling, tmp_path, fused_on):
+            self, profiling, tmp_path):
         from spark_rapids_tpu.models import tpcds
         from spark_rapids_tpu.plan import catalog as C
         from spark_rapids_tpu.tools import expand_bundle_input

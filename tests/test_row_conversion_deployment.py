@@ -418,10 +418,14 @@ class TestTheCellRefusesAProgramOffItsEngines:
         assert set(result["metrics"]) == {"setup_s", "rows_per_s",
                                           "query_ms.p50"}
         manifest = harness.load_json(ROOT, "BENCHMARK.json")
-        listed = {m["name"] for m in harness.Cell(
-            manifest, CELL, 1, "toy").metrics(manifest, "per_layer")}
+        # a direction's time may carry a ``.hostpaced`` suffix or not:
+        # the benchmark names it, this test holds it to the quantity
+        listed = {m["name"].removesuffix(".hostpaced")
+                  for m in harness.Cell(
+                      manifest, CELL, 1, "toy").metrics(
+                          manifest, "per_layer")}
         assert listed == {
-            "to_rows_ms.hostpaced", "from_rows_ms.hostpaced",
+            "to_rows_ms", "from_rows_ms",
             "to_rows_roofline", "from_rows_roofline",
             "rowconv_dispatch_ms", "hbm_roofline", "device_idle_pct",
             "window_compiles"}

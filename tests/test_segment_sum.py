@@ -171,8 +171,7 @@ def test_q3_hand_fused_lowers_to_products(counting):
                _segment_sum_lines(text, "srt/q3/segment_sum"))
 
 
-def test_q3_fused_stage_lowers_to_products(counting, monkeypatch):
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_STAGE_FUSION", "1")
+def test_q3_fused_stage_lowers_to_products(counting):
     d = tpcds.gen_q3(rows=5_000, items=64, days=730, brands=8)
     # a plan of its own (another month), so this test's compile is the
     # one that traces it whatever ran before in the process

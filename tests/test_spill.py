@@ -381,10 +381,6 @@ class TestCorruptSpill:
 
 class TestFusedStageSpilled:
 
-    @pytest.fixture
-    def fused_on(self, monkeypatch):
-        monkeypatch.setenv("SPARK_RAPIDS_TPU_STAGE_FUSION", "1")
-
     def _plan(self):
         from spark_rapids_tpu.plan import ir
         return ir.StagePlan(
@@ -401,7 +397,7 @@ class TestFusedStageSpilled:
             ),
             outputs=("sums",)).validate()
 
-    def test_second_partition_is_a_cache_hit(self, fused_on, tmp_path):
+    def test_second_partition_is_a_cache_hit(self, tmp_path):
         from spark_rapids_tpu.perf.jit_cache import CACHE
         from spark_rapids_tpu.plan import compiler as PC
         rng = np.random.default_rng(3)

@@ -25,13 +25,6 @@ MAX_WEEK = 16
 WEEK0 = 11_000 // 7
 
 
-@pytest.fixture
-def fused_on(monkeypatch):
-    """Force the fused engine (bypasses per-stage calibration so the
-    compile-count assertions are deterministic)."""
-    monkeypatch.setenv("SPARK_RAPIDS_TPU_STAGE_FUSION", "1")
-
-
 def _assert_bytes(got, want):
     for i, (g, w) in enumerate(zip(got, want)):
         assert np.asarray(g).tobytes() == np.asarray(w).tobytes(), i
@@ -104,12 +97,12 @@ class TestDigests:
 
 class TestFusedByteIdentity:
 
-    def test_q5(self, fused_on):
+    def test_q5(self):
         d = tpcds.gen_q5(rows=4000, stores=STORES, days=60)
         _assert_bytes(C.run_q5(d, STORES, 1 << 13),
                       tpcds.make_q5(STORES, join_capacity=1 << 13)(d))
 
-    def test_q72(self, fused_on):
+    def test_q72(self):
         d = tpcds.gen_q72(cs_rows=3000, inv_rows=3000, items=ITEMS,
                           days=35)
         _assert_bytes(
@@ -117,17 +110,17 @@ class TestFusedByteIdentity:
             tpcds.make_q72(ITEMS, MAX_WEEK, join_capacity=1 << 18,
                            week0=WEEK0)(d))
 
-    def test_q3(self, fused_on):
+    def test_q3(self):
         d = tpcds.gen_q3(rows=6000, items=64, days=730, brands=8)
         _assert_bytes(
             C.run_q3(d, 10_957, years=3, brands=8, manufact=2),
             tpcds.make_q3(10_957, years=3, brands=8, manufact=2)(d))
 
-    def test_q9(self, fused_on):
+    def test_q9(self):
         q, p, n = tpcds.gen_q9(rows=20_000)
         _assert_bytes(C.run_q9(q, p, n), tpcds.run_q9(q, p, n))
 
-    def test_q72_fused_capacity_retry(self, fused_on):
+    def test_q72_fused_capacity_retry(self):
         """A too-small join budget doubles through the centralized
         capacity-retry driver until the fused stage's overflow flag
         clears — same contract as the hand pipeline."""
@@ -138,7 +131,7 @@ class TestFusedByteIdentity:
         assert _rows72(outs) == tpcds.oracle_q72(d, 4, MAX_WEEK,
                                                  week0=WEEK0)
 
-    def test_q5_string_presentation(self, fused_on):
+    def test_q5_string_presentation(self):
         """Strings stay at the presentation boundary: the fused q5
         output drives present_q5's dictionary-id -> string decode
         exactly like the hand pipeline's."""
@@ -148,14 +141,28 @@ class TestFusedByteIdentity:
         want = tpcds.oracle_q5(d, 8)
         assert rows == [(names[w[0]], w[1], w[2], w[3]) for w in want]
 
-    def test_unfused_engine_byte_identical(self, monkeypatch):
-        """The op-by-op escape hatch (SPARK_RAPIDS_TPU_STAGE_FUSION=0)
-        is byte-identical to the hand pipeline too — fusion is a speed
-        choice only."""
-        monkeypatch.setenv("SPARK_RAPIDS_TPU_STAGE_FUSION", "0")
-        d = tpcds.gen_q5(rows=1500, stores=STORES, days=60)
-        _assert_bytes(C.run_q5(d, STORES, 1 << 12),
-                      tpcds.make_q5(STORES, join_capacity=1 << 12)(d))
+    @pytest.mark.parametrize("stage", ["q3", "q5_partials"])
+    def test_unfused_engine_byte_identical(self, stage):
+        """The reference walk (``run_unfused``) and the fused
+        executable (``run``) give the same bytes."""
+        if stage == "q3":
+            d = tpcds.gen_q3(rows=6000, items=64, days=730, brands=8)
+            plan = C.q3_plan(10_957, 3, 8, 2)
+            inputs = {"s": (d.s_date, d.s_item, d.s_price),
+                      "dims": (d.d_moy, d.d_year, d.i_brand,
+                               d.i_manufact)}
+        else:
+            d = tpcds.gen_q5(rows=1500, stores=STORES, days=60)
+            plan = C.q5_partials_plan(STORES, 1 << 12)
+            inputs = {"s": (d.s_date, d.s_store, d.s_price,
+                            d.s_profit),
+                      "r": (d.r_date, d.r_store, d.r_amt, d.r_loss),
+                      "d": (d.d_date,)}
+        st = PC.compile_stage(plan)
+        want = st.run_unfused(inputs)
+        got = st.run(inputs)
+        assert len(got) == len(want) == len(plan.outputs)
+        _assert_bytes(got, want)
 
 
 def _rows72(outs):
@@ -171,7 +178,7 @@ def _rows72(outs):
 
 class TestNullValidity:
 
-    def test_join_probe_with_validity_column(self, fused_on):
+    def test_join_probe_with_validity_column(self):
         """A fact side carrying a null-validity column: invalid rows
         never match (the inner_join_device NULL-inequality contract),
         and bucket-pad rows ride the same validity lane (pad=0 ==
@@ -231,7 +238,7 @@ class TestNullValidity:
 
 class TestCompileReuse:
 
-    def test_one_executable_per_stage_zero_on_repeat(self, fused_on):
+    def test_one_executable_per_stage_zero_on_repeat(self):
         """The acceptance gate's core property: each stage compiles
         ONE executable, and a second same-bucket query (different row
         count) compiles ZERO."""
@@ -252,7 +259,7 @@ class TestCompileReuse:
         _assert_bytes(out2, tpcds.make_q5(
             STORES, join_capacity=1 << 13)(d2))
 
-    def test_q3_single_stage_single_executable(self, fused_on):
+    def test_q3_single_stage_single_executable(self):
         CACHE.clear(reset_stats=True)
         d = tpcds.gen_q3(rows=5000, items=64, days=730, brands=8)
         C.run_q3(d, 10_957, years=3, brands=8, manufact=2)
@@ -267,7 +274,7 @@ class TestCompileReuse:
 
 class TestWindowRollup:
 
-    def test_q67_rollup_rank_golden(self, fused_on):
+    def test_q67_rollup_rank_golden(self):
         ncat, ncls = 6, 10
         d = tpcds.gen_q67(rows=5000, ncat=ncat, ncls=ncls)
         cat_s, cls_s, sum_s, rank_s, cnt_s, sum1, sumt = \
@@ -283,7 +290,7 @@ class TestWindowRollup:
         assert np.asarray(sum1).tolist() == want_sum1
         assert int(sumt) == want_tot
 
-    def test_cube_grouping_sets_golden(self, fused_on):
+    def test_cube_grouping_sets_golden(self):
         ncat, ncls = 5, 7
         d = tpcds.gen_q67(rows=4000, ncat=ncat, ncls=ncls, seed=3)
         outs = C.run_cube(d, ncat, ncls)
@@ -292,7 +299,7 @@ class TestWindowRollup:
             want = want.tolist() if hasattr(want, "tolist") else want
             assert got == want
 
-    def test_q89_window_sum_golden(self, fused_on):
+    def test_q89_window_sum_golden(self):
         stores, items = 4, 8
         d = tpcds.gen_q89(rows=5000, stores=stores, items=items)
         store_s, item_s, sales_s, tot_s, cnt_s = C.run_q89(
@@ -305,7 +312,7 @@ class TestWindowRollup:
                        np.asarray(cnt_s)[live].tolist()))
         assert got == tpcds.oracle_q89(d, stores, items)
 
-    def test_window_rank_ties_break_by_row(self, fused_on):
+    def test_window_rank_ties_break_by_row(self):
         """Equal order keys rank by row index (stable) — the property
         the q67 presentation depends on."""
         plan = ir.StagePlan(
@@ -334,7 +341,7 @@ def mesh8():
 
 class TestMeshFused:
 
-    def test_q5_fused_one_program_per_rank(self, mesh8, fused_on):
+    def test_q5_fused_one_program_per_rank(self, mesh8):
         rows = 4096
         d = tpcds.gen_q5(rows=rows, stores=STORES, days=60)
         d = d._replace(r_date=d.r_date[:rows // 8 * 8],
@@ -349,7 +356,7 @@ class TestMeshFused:
             tpcds.make_q5_multichip(mesh8, STORES,
                                     join_capacity=1 << 11)(*args))
 
-    def test_q72_fused_one_program_per_rank(self, mesh8, fused_on):
+    def test_q72_fused_one_program_per_rank(self, mesh8):
         d = tpcds.gen_q72(cs_rows=2048, inv_rows=2048, items=ITEMS,
                           days=35)
         args = (d.cs_item, d.cs_date, d.cs_qty, d.inv_item,
@@ -376,7 +383,7 @@ class TestDistributedFused:
 
     @pytest.mark.slow  # tier-1 time budget: dist-smoke runs the
     # fused runner (the default) across real processes every CI run
-    def test_q5_world2_fused_byte_identical(self, tmp_path, fused_on,
+    def test_q5_world2_fused_byte_identical(self, tmp_path,
                                             crc_on):
         """Two in-process ranks over the real socket shuffle service:
         each rank runs ONE fused partials program, exchanges kudo
@@ -419,7 +426,7 @@ class TestDistributedFused:
 
 class TestStageObservability:
 
-    def test_counters_journal_and_report_table(self, fused_on):
+    def test_counters_journal_and_report_table(self):
         from spark_rapids_tpu import observability as obs
         from spark_rapids_tpu.tools.metrics_report import (
             build_report, render_stage_table, stage_rows)

@@ -294,25 +294,31 @@ class TestCompilerTaps:
         return d, C.run_q5(d, 16, 1 << 11)
 
     def test_fused_unfused_taps_agree_and_bytes_identical(
-            self, stats_on, monkeypatch):
-        monkeypatch.setenv("SPARK_RAPIDS_TPU_STAGE_FUSION", "1")
-        d, fused = self._run_q5()
+            self, stats_on):
+        """The fused executable's tapped counts against the same count
+        expressions read on the host off the reference walk."""
+        from spark_rapids_tpu.plan import compiler as PC
+        d = tpcds.gen_q5(rows=2000, stores=16, days=60)
+        plan = C.q5_partials_plan(16, 1 << 13)
+        st = PC.compile_stage(plan)
+        inputs = {"s": (d.s_date, d.s_store, d.s_price, d.s_profit),
+                  "r": (d.r_date, d.r_store, d.r_amt, d.r_loss),
+                  "d": (d.d_date,)}
+        fused = st.run(inputs)
         fsec = obs.STATS.last("q5_partials")
         assert fsec is not None and fsec["nodes"]
-        monkeypatch.setenv("SPARK_RAPIDS_TPU_STAGE_FUSION", "0")
-        obs.STATS.reset()
-        _, unfused = self._run_q5()
-        usec = obs.STATS.last("q5_partials")
-        frows = {n["node"]: n["rows"] for n in fsec["nodes"]}
-        urows = {n["node"]: n["rows"] for n in usec["nodes"]}
-        assert frows == urows
-        assert any(n["kind"] != "input" for n in fsec["nodes"])
-        for g, w in zip(fused, unfused):
-            assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+        frows = {n["node"]: n["rows"] for n in fsec["nodes"]
+                 if n["kind"] != "input"}
+        env = st._walk_env(inputs)
+        spec = PC._tap_spec(plan)
+        urows = {nid: int(v) for (nid, _kind, _key), v in
+                 zip(spec, PC._tap_counts(plan, env))}
+        assert frows and frows == urows
+        for g, o in zip(fused, plan.outputs):
+            assert np.asarray(g).tobytes() == \
+                np.asarray(env[o]).tobytes()
 
-    def test_stats_do_not_change_results(self, isolated_store,
-                                         monkeypatch):
-        monkeypatch.setenv("SPARK_RAPIDS_TPU_STAGE_FUSION", "1")
+    def test_stats_do_not_change_results(self, isolated_store):
         prior = obs.is_stats_enabled()
         obs.disable_stats()
         try:
@@ -324,9 +330,7 @@ class TestCompilerTaps:
         for g, w in zip(tapped, base):
             assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
 
-    def test_catalog_estimates_registered(self, stats_on,
-                                          monkeypatch):
-        monkeypatch.setenv("SPARK_RAPIDS_TPU_STAGE_FUSION", "1")
+    def test_catalog_estimates_registered(self, stats_on):
         self._run_q5()
         est = obs.STATS.estimate_for("q5_partials", "input:s")
         assert est is not None and est["rows"] == 2000
