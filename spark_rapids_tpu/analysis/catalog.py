@@ -91,6 +91,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "table lookups traced by engine"),
     "srt_row_conversion_total": (
         "counter", "eager row conversions by direction and engine"),
+    "srt_from_rows_validity_total": (
+        "counter", "from-rows columns by how their validity resolved"),
     "srt_incidents_total": ("counter", "incident bundles written"),
     "srt_incidents_suppressed_total": (
         "counter", "incident triggers suppressed"),

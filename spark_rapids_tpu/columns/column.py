@@ -13,7 +13,14 @@ int32 offsets, children).  Here a Column is an immutable pytree of jax arrays:
             decimal128:  (rows, 4) int32 little-endian limbs
   validity  (rows,) uint8, 1 = valid; None means all rows valid.  Unpacked on
             device (packed bits don't vectorize on 8x128 lanes); packed only at
-            serialization boundaries (Kudo / Arrow interop).
+            serialization boundaries (Kudo / Arrow interop).  A producer that
+            holds the bits in another form may hand over a DeferredValidity
+            in place of the vector (convert_from_rows does: the bits stay
+            the row words they are); the first read of ``validity`` (and so
+            ``has_validity``, ``null_count``, ``valid_mask``, a pytree
+            flatten) resolves it, once, to the vector or to None, and None
+            then says that no row is null.  A plain array or None assigned
+            to ``validity`` is read back as it is.
   offsets   (rows+1,) int32 for STRING and LIST (CUDF_LARGE_STRINGS_DISABLED
             semantics: offsets are int32, <=2^31 chars per column).
   children  LIST: (element column,); STRUCT: field columns.
@@ -34,8 +41,22 @@ from spark_rapids_tpu.columns import dtypes
 from spark_rapids_tpu.columns.dtypes import DType, Kind
 
 
+class DeferredValidity:
+    """A column's validity that is not made yet.  ``resolve()`` returns
+    the (rows,) uint8 vector, or None where every row is valid; it may
+    read from the device, gives the same answer every time, and is safe
+    from two threads.  ``Column.validity`` calls it at the first read
+    and keeps the answer."""
+
+    __slots__ = ()
+
+    def resolve(self) -> Optional[jnp.ndarray]:
+        raise NotImplementedError
+
+
 class Column:
-    __slots__ = ("dtype", "length", "data", "validity", "offsets", "children")
+    __slots__ = ("dtype", "length", "data", "_validity", "offsets",
+                 "children")
 
     def __init__(
         self,
@@ -60,6 +81,17 @@ class Column:
 
     def __repr__(self) -> str:
         return f"Column({self.dtype!r}, length={self.length})"
+
+    @property
+    def validity(self) -> Optional[jnp.ndarray]:
+        v = self._validity
+        if isinstance(v, DeferredValidity):
+            v = self._validity = v.resolve()
+        return v
+
+    @validity.setter
+    def validity(self, v) -> None:
+        self._validity = v
 
     @property
     def has_validity(self) -> bool:

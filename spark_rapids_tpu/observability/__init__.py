@@ -282,6 +282,13 @@ ROW_CONVERSION = METRICS.counter(
     "the to-rows tile kernel on a TPU, gather = byte gather for "
     "strings and rows of differing size)",
     labels=("direction", "engine"))
+FROM_ROWS_VALIDITY = METRICS.counter(
+    "srt_from_rows_validity_total",
+    "Columns of from-rows tables (engine words) whose deferred "
+    "validity was resolved, by outcome (absent = no null in the row "
+    "buffer, the column reads validity None; materialized = the "
+    "column's uint8 vector was made from the kept validity words)",
+    labels=("outcome",))
 FLEET_EPOCH = METRICS.gauge(
     "srt_fleet_epoch",
     "Elastic-fleet membership epoch on this worker (bumps on every "
@@ -1407,6 +1414,16 @@ def record_row_conversion(direction: str, engine: str) -> None:
     ``from_rows`` span carries rows, bytes and the same engine."""
     if _SWITCH.enabled:
         ROW_CONVERSION.inc(labels=(direction, engine))
+
+
+def record_from_rows_validity(outcome: str) -> None:
+    """Deferred-validity hook (ops/row_conversion.py): one column of a
+    table that ``convert_from_rows`` made on the ``words`` engine had
+    its validity read for the first time; ``outcome`` is 'absent' (no
+    null in the row buffer: ``validity`` is None) or 'materialized'
+    (its vector was made)."""
+    if _SWITCH.enabled:
+        FROM_ROWS_VALIDITY.inc(labels=(outcome,))
 
 
 def record_lockdep(kind: str, *, cycle=(), op: str = "", held=(),

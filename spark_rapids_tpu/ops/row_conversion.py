@@ -45,7 +45,12 @@ field a static slice, no gather) whenever the buffer holds uniform
 rows, which is read from the buffer; rows of differing size and schemas with
 strings keep the byte gather (_gather_fixed_region).  Eager calls
 record the spans ``to_rows`` / ``from_rows`` and
-srt_row_conversion_total{direction,engine}.
+srt_row_conversion_total{direction,engine}.  From-rows' word slices
+leave the validity as the row words it is: the executable hands over
+the validity words and their AND over the rows, the table's columns
+share them (_RowsValidity), and a column's (rows,) vector is made when
+the column is first asked and the buffer holds a null for it; else its
+validity is None (srt_from_rows_validity_total{outcome}).
 
 Variable-width rows are assembled per-row padded then compacted by a
 gather keyed on searchsorted(row_offsets) — vectorized, no per-row loops.
@@ -54,6 +59,7 @@ gather keyed on searchsorted(row_offsets) — vectorized, no per-row loops.
 from __future__ import annotations
 
 import os
+import threading
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -62,7 +68,7 @@ import numpy as np
 from jax import lax
 
 from spark_rapids_tpu.columns import dtypes
-from spark_rapids_tpu.columns.column import Column
+from spark_rapids_tpu.columns.column import Column, DeferredValidity
 from spark_rapids_tpu.columns.dtypes import DType, Kind
 from spark_rapids_tpu.columns.table import Table
 
@@ -350,17 +356,18 @@ def _is_traced(cols: Sequence[Column]) -> bool:
                if c.data is not None)
 
 
-def _pad_rows(arr: jnp.ndarray, bucket: int) -> jnp.ndarray:
-    """``arr`` zero-padded along axis 0 to ``bucket`` rows; ``arr``
-    itself where it already has them.  Neither direction donates its
-    operands (no result has an operand's shape, so a donation frees
-    nothing sooner), so the caller's buffer needs no protecting copy:
-    at 212 columns x 2^20 rows those copies were 63 of to-rows' 84 ms
-    (PERF.md, Findings, PR 32)."""
+def _pad_rows(arr: jnp.ndarray, bucket: int, fill: int = 0) -> jnp.ndarray:
+    """``arr`` padded with ``fill`` along axis 0 to ``bucket`` rows;
+    ``arr`` itself where it already has them.  Neither direction
+    donates its operands (no result has an operand's shape, so a
+    donation frees nothing sooner), so the caller's buffer needs no
+    protecting copy: at 212 columns x 2^20 rows those copies were 63 of
+    to-rows' 84 ms (PERF.md, Findings, PR 32)."""
     n = int(arr.shape[0])
     if n == bucket:
         return arr
-    return jnp.pad(arr, [(0, bucket - n)] + [(0, 0)] * (arr.ndim - 1))
+    return jnp.pad(arr, [(0, bucket - n)] + [(0, 0)] * (arr.ndim - 1),
+                   constant_values=fill)
 
 
 def _to_rows_fixed_cached(cols, schema, starts, validity_offset,
@@ -668,22 +675,42 @@ def _transpose_row_words(data: jnp.ndarray, rows: int,
         data.reshape(rows // lane, lane, row_size // 4), (2, 0, 1))
 
 
+def _validity_bits(ncols: int, validity_offset: int):
+    """(first validity word, one past the last, bit of column 0): where
+    a row's validity bits lie among its u32 words.  Column ci's bit is
+    ``bit0 + ci`` counted from bit 0 of the first validity word: word
+    ``bit >> 5`` of the kept words, bit ``bit & 31`` of that word (the
+    validity bytes start wherever the last field ends, so the first
+    word may hold field bytes below them)."""
+    vw0 = validity_offset // 4
+    vw1 = (validity_offset + (ncols + 7) // 8 - 1) // 4 + 1
+    return vw0, vw1, (validity_offset % 4) * 8
+
+
 def _extract_fixed_words(blocks: jnp.ndarray, schema, starts,
                          validity_offset: int):
-    """Transposed row words (_transpose_row_words) -> (values,
-    validity): the inverse of _assemble_fixed_words.  Every field is a
-    static slice of word vectors (field_word_slots: the layout the
-    assembly writes) with a shift, a mask and a bitcast, and every
-    validity bit a shift of its validity word: no gather, no index
+    """Transposed row words (_transpose_row_words) -> (values, validity
+    words, all-valid word): the inverse of _assemble_fixed_words, with
+    the validity left as the row words it is.  Every field is a static
+    slice of word vectors (field_word_slots: the layout the assembly
+    writes) with a shift, a mask and a bitcast: no gather, no index
     matrix.  Values come back in the dtypes the columns carry (FLOAT64
-    and uint64 as raw u64 bits, DECIMAL128 as (rows, 4) int32 limbs)."""
+    and uint64 as raw u64 bits, DECIMAL128 as (rows, 4) int32 limbs).
+    The validity words are the blocks that hold the validity bytes
+    (_validity_bits), sliced and not decoded; the all-valid word is the
+    AND over all rows of each of them, so a column has a null exactly
+    where its bit there is 0 (the caller pads the buffer with ones, so
+    a bucket's pad rows read as valid).  ``len(schema) + 2`` arrays,
+    where a validity vector a column made twice ``len(schema)``: the
+    host pays for every result buffer of an executable before it
+    enqueues it (PERF.md, Findings, PR 37)."""
     rows = blocks.shape[1] * blocks.shape[2]
 
     def word(w):
         return blocks[w].reshape(rows)
 
-    vals, valids = [], []
-    for ci, (dt, st) in enumerate(zip(schema, starts)):
+    vals = []
+    for dt, st in zip(schema, starts):
         slots = field_word_slots(dt, st)
         w, shift, nbits = slots[0]
         kind = dt.kind
@@ -708,25 +735,110 @@ def _extract_fixed_words(blocks: jnp.ndarray, schema, starts,
             v = ((word(w) >> _U32(shift))
                  & _U32((1 << nbits) - 1)).astype(dt.np_dtype)
         vals.append(v)
-        off = validity_offset + ci // 8
-        valids.append(((word(off // 4) >> _U32((off % 4) * 8 + ci % 8))
-                       & _U32(1)).astype(_U8))
-    return tuple(vals), tuple(valids)
+    vw0, vw1, _ = _validity_bits(len(schema), validity_offset)
+    vwords = blocks[vw0:vw1]
+    all_valid = lax.reduce(vwords, np.uint32(0xFFFFFFFF), lax.bitwise_and,
+                           (1, 2))
+    return tuple(vals), vwords, all_valid
+
+
+def _column_validity(vwords: jnp.ndarray, bit) -> jnp.ndarray:
+    """(bucket,) uint8 validity vector of the column whose bit is
+    ``bit`` (_validity_bits; an int32 operand, so one executable a
+    bucket serves every column) out of the kept validity words."""
+    w = lax.dynamic_index_in_dim(vwords, bit >> 5, 0, keepdims=False)
+    return ((w >> (bit & 31).astype(_U32)) & _U32(1)).astype(_U8).reshape(-1)
+
+
+def _read_all_valid(all_valid: jnp.ndarray) -> np.ndarray:
+    """The all-valid word on the host: the one device-to-host read a
+    table's validity costs (a few bytes, and a wait for ``extract``)."""
+    return np.asarray(all_valid)
+
+
+class _RowsValidity:
+    """What from-rows' ``words`` engine keeps of a table's validity,
+    shared by the table's columns: the validity words as they lie in
+    the transposed row blocks, the all-valid word, and column 0's bit
+    (_extract_fixed_words, _validity_bits).  The first column asked
+    reads the all-valid word back for all of them; a column whose bit
+    is set in every row has no vector made (None), any other gets its
+    (rows,) uint8 vector from one executable a bucket.  The answer is
+    what the row buffer holds, whoever wrote it."""
+
+    __slots__ = ("words", "all_valid", "bit0", "rows", "lock", "host")
+
+    def __init__(self, words, all_valid, bit0: int, rows: int):
+        self.words = words
+        self.all_valid = all_valid
+        self.bit0 = bit0
+        self.rows = rows
+        self.lock = threading.Lock()
+        self.host = None
+
+    def column(self, ci: int) -> Optional[jnp.ndarray]:
+        """Caller holds ``lock``."""
+        from spark_rapids_tpu import observability as _obs
+        from spark_rapids_tpu.perf import jit_cache as _jc
+
+        if self.host is None:
+            self.host = _read_all_valid(self.all_valid)
+        bit = self.bit0 + ci
+        if (int(self.host[bit >> 5]) >> (bit & 31)) & 1:
+            _obs.record_from_rows_validity("absent")
+            return None
+        bucket = int(self.words.shape[1] * self.words.shape[2])
+        args = (self.words, np.int32(bit))
+        if _jc.cache_enabled():
+            v = _jc.CACHE.cached_call(
+                "row_conversion.from_rows.validity",
+                str(int(self.words.shape[0])), _column_validity, args,
+                bucket=bucket)
+        else:
+            v = _column_validity(*args)
+        _obs.record_from_rows_validity("materialized")
+        return v if bucket == self.rows else v[:self.rows]
+
+
+class _DeferredColumnValidity(DeferredValidity):
+    """Column ``ci``'s share of a _RowsValidity: resolved once, under
+    the table's lock, whichever threads ask."""
+
+    __slots__ = ("table", "ci", "value")
+
+    def __init__(self, table: _RowsValidity, ci: int):
+        self.table = table
+        self.ci = ci
+        self.value = None
+
+    def resolve(self) -> Optional[jnp.ndarray]:
+        table = self.table
+        if table is not None:
+            with table.lock:
+                if self.table is not None:
+                    self.value = table.column(self.ci)
+                    self.table = None   # the kept words go with the last
+        return self.value
 
 
 def _from_rows_fixed_cached(list_col: Column, schema, starts,
-                            validity_offset: int, row_size: int) -> Table:
+                            validity_offset: int,
+                            row_size: int) -> Tuple[Table, int]:
     """Uniform fixed-width from-rows through the compile cache, as two
     executables: _transpose_row_words per (row size, bucket, buffer
     packing), whatever the schema, and _extract_fixed_words per (schema
-    digest, bucket)."""
+    digest, bucket).  Returns the table and the number of arrays the
+    second handed over.  The columns' validity is deferred
+    (_RowsValidity): nothing is read back here, and the buffer is
+    padded to the bucket with ones, so that a pad row is no null."""
     from spark_rapids_tpu.perf import jit_cache as _jc
 
     rows = list_col.length
     data = list_col.children[0].data
     packed = data.dtype == _U32
     bucket = _jc.bucket_rows(rows)
-    data = _pad_rows(data, bucket * (row_size // 4 if packed else row_size))
+    data = _pad_rows(data, bucket * (row_size // 4 if packed else row_size),
+                     fill=0xFFFFFFFF if packed else 0xFF)
     schema_t = tuple(schema)
     starts_t = tuple(starts)
 
@@ -743,24 +855,37 @@ def _from_rows_fixed_cached(list_col: Column, schema, starts,
             _jc.schema_digest(
                 (), extra=f"{row_size}:{'u32' if packed else 'u8'}"),
             transpose, (data,), bucket=bucket)
-        vals, valids = _jc.CACHE.cached_call(
+        vals, vwords, all_valid = _jc.CACHE.cached_call(
             "row_conversion.from_rows",
             _jc.schema_digest(schema, extra=f"from_rows:{row_size}"),
             extract, (blocks,), bucket=bucket)
     else:
-        vals, valids = extract(transpose(data))
+        vals, vwords, all_valid = extract(transpose(data))
     if bucket != rows:
         vals = [v[:rows] for v in vals]
-        valids = [v[:rows] for v in valids]
-    return Table([Column(dt, rows, data=v, validity=valid)
-                  for dt, v, valid in zip(schema, vals, valids)])
+    validity = _RowsValidity(
+        vwords, all_valid,
+        _validity_bits(len(schema_t), validity_offset)[2], rows)
+    return Table([Column(dt, rows, data=v,
+                         validity=_DeferredColumnValidity(validity, ci))
+                  for ci, (dt, v) in enumerate(zip(schema, vals))]), \
+        len(vals) + 2
 
 
 def convert_from_rows(list_col: Column, schema: Sequence[DType]) -> Table:
     """LIST<INT8> of JCUDF rows -> Table (RowConversion.convertFromRows).
     An eager call is the timeline span ``from_rows`` (it ends where the
-    call returns, at the enqueue); under a jit trace nothing is
-    recorded."""
+    call returns, at the enqueue; on the ``words`` engine the attribute
+    ``results`` is the number of arrays its second executable handed
+    over); under a jit trace nothing is recorded.
+
+    On the ``words`` engine the columns come back with their validity
+    deferred (columns/column.py): the call reads nothing back, the
+    first read of any column's ``validity`` reads one word a validity
+    word of the row back for the whole table, and only a column that
+    holds a null in this buffer has a vector made; every other reads
+    ``None``.  srt_from_rows_validity_total{outcome} counts the columns
+    either way.  The ``gather`` engine returns a vector a column."""
     from spark_rapids_tpu import observability as _obs
 
     child = list_col.children[0]
@@ -768,17 +893,21 @@ def convert_from_rows(list_col: Column, schema: Sequence[DType]) -> Table:
             isinstance(list_col.offsets, jax.core.Tracer):
         return _from_rows(list_col, schema, True)[0]
     with _obs.TRACER.start_span("from_rows", kind="phase") as span:
-        out, engine = _from_rows(list_col, schema, False)
+        out, engine, results = _from_rows(list_col, schema, False)
         _note(span, "from_rows", engine, list_col.length, child.length)
+        if results is not None:
+            span.set_attr("results", results)
     return out
 
 
 def _from_rows(list_col: Column, schema: Sequence[DType],
-               traced: bool) -> Tuple[Table, str]:
-    """(table, engine): ``words`` slices every field out of the row
-    words (fixed-width schema, uniform rows: what the buffer is, not an
-    option); ``gather`` fetches every row's fixed section by byte index
-    (rows of differing size, schemas with strings, a jit trace)."""
+               traced: bool) -> Tuple[Table, str, Optional[int]]:
+    """(table, engine, results of the engine's last executable):
+    ``words`` slices every field out of the row words (fixed-width
+    schema, uniform rows: what the buffer is, not an option) and defers
+    the validity; ``gather`` fetches every row's fixed section by byte
+    index, op by op, so it has no such count (rows of differing size,
+    schemas with strings, a jit trace)."""
     from spark_rapids_tpu.columns import bytesview
 
     rows = list_col.length
@@ -792,8 +921,9 @@ def _from_rows(list_col: Column, schema: Sequence[DType],
 
     if (rows > 0 and not has_strings and not traced
             and _uniform_row_offsets(offs, rows, row_size, nbytes_total)):
-        return _from_rows_fixed_cached(list_col, schema, starts,
-                                       validity_offset, row_size), "words"
+        table, results = _from_rows_fixed_cached(
+            list_col, schema, starts, validity_offset, row_size)
+        return table, "words", results
 
     # eager width-grouped decode: one region gather + static slices
     region = _gather_fixed_region(data, offs, fixed_size, nbytes_total)
@@ -823,4 +953,4 @@ def _from_rows(list_col: Column, schema: Sequence[DType],
             vals = _bytes_to_values(
                 region[:, st:st + _col_byte_size(dt)], dt)
             out_cols.append(Column(dt, rows, data=vals, validity=valid))
-    return Table(out_cols), "gather"
+    return Table(out_cols), "gather", None

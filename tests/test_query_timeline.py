@@ -466,6 +466,45 @@ def test_rehearsal_line_holds_the_row_conversion_metrics():
         + _metric(metrics, "from_rows_ms")["value"])
 
 
+def test_from_rows_span_counts_its_results_and_the_counter_the_columns(
+        switches):
+    """212 columns, one of them with a null: the span ``from_rows``
+    says how many arrays the extract executable handed over (values,
+    the validity words, the all-valid word), and
+    ``srt_from_rows_validity_total`` how each column's validity
+    resolved when it was first read (ISSUE 37)."""
+    import numpy as np
+
+    from spark_rapids_tpu.columns import dtypes
+    from spark_rapids_tpu.columns.column import Column
+    from spark_rapids_tpu.columns.table import Table
+    from spark_rapids_tpu.ops import row_conversion as RC
+
+    obs.enable()
+    rows = 256
+    valid = np.ones(rows, np.uint8)
+    valid[5] = 0
+    table = Table([Column.from_numpy(
+        np.arange(rows, dtype=np.int32) + i,
+        validity=valid if i == 100 else None, dtype=dtypes.INT32)
+        for i in range(212)])
+    back = RC.convert_from_rows(RC.convert_to_rows(table),
+                                [dtypes.INT32] * 212)
+    (span,) = [s for s in obs.TRACER.records() if s["name"] == "from_rows"]
+    assert span["span_kind"] == "phase"
+    assert span["attrs"]["results"] == 214
+    assert span["attrs"]["engine"] == "words"
+    family = obs.METRICS.family_snapshot("srt_from_rows_validity_total")
+    assert [s for s in family["series"] if s["value"]] == []
+    assert [c.has_validity for c in back.columns] == [
+        i == 100 for i in range(212)]
+    assert np.array_equal(np.asarray(back.columns[100].validity), valid)
+    family = obs.METRICS.family_snapshot("srt_from_rows_validity_total")
+    assert family["labels"] == ["outcome"]
+    assert {s["labels"][0]: s["value"] for s in family["series"]} == {
+        "absent": 211, "materialized": 1}
+
+
 @pytest.mark.parametrize("metric,span", [("to_rows_roofline", "to_rows"),
                                          ("from_rows_roofline",
                                           "from_rows")])

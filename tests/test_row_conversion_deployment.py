@@ -76,19 +76,32 @@ def back_bytes(table):
     return [a.view(DEC128).reshape(-1) if a.ndim == 2 else a for a in out]
 
 
-def check_round_trip(columns, table, valid):
-    rows = len(columns[0])
-    rows_col = RC.convert_to_rows(table)
-    got_rows = host_rows(rows_col, rows)
-    back = RC.convert_from_rows(rows_col, [c.dtype for c in table.columns])
-    numbers = REF.compare(
-        {"rows": got_rows, "columns": back_bytes(back)},
+def numbers_of(rows_col, back, columns, valid):
+    """The reference's comparison of one round trip's two outputs."""
+    return REF.compare(
+        {"rows": host_rows(rows_col, len(columns[0])),
+         "columns": back_bytes(back)},
         {"rows": expected_rows(columns, valid), "columns": columns})
-    assert numbers == {"row_bytes_differing": 0,
-                       "column_bytes_differing": 0}
+
+
+def check_round_trip(columns, table, valid):
+    rows_col = RC.convert_to_rows(table)
+    back = RC.convert_from_rows(rows_col, [c.dtype for c in table.columns])
+    assert numbers_of(rows_col, back, columns, valid) == {
+        "row_bytes_differing": 0, "column_bytes_differing": 0}
     for c, v in zip(back.columns, valid):
-        want = np.ones(rows, np.uint8) if v is None else v
-        np.testing.assert_array_equal(np.asarray(c.validity), want)
+        check_validity(c, v)
+
+
+def check_validity(c, v):
+    """What from-rows' ``words`` engine hands back for a column drawn
+    with the validity ``v``: no vector where no null was drawn (``v``
+    is ``None``, or a draw of few rows came out all ones), else ``v``."""
+    if v is None or v.all():
+        assert c.validity is None and not c.has_validity
+    else:
+        assert c.validity.dtype == jnp.uint8
+        np.testing.assert_array_equal(np.asarray(c.validity), v)
 
 
 @pytest.mark.parametrize("nulls", [False, True],
@@ -186,6 +199,11 @@ def conversions():
     return {tuple(s["labels"]): s["value"] for s in fam.get("series", [])}
 
 
+def validity_outcomes():
+    fam = obs.METRICS.snapshot().get("srt_from_rows_validity_total", {})
+    return {s["labels"][0]: s["value"] for s in fam.get("series", [])}
+
+
 def test_one_round_trip_counts_one_conversion_each_way_on_words(counting):
     inputs, table, _ = seeded(100, 8, 9, False)
     rows_col = RC.convert_to_rows(table)
@@ -196,10 +214,12 @@ def test_one_round_trip_counts_one_conversion_each_way_on_words(counting):
              if s["name"] in ("to_rows", "from_rows")}
     assert set(spans) == {"to_rows", "from_rows"}
     row_size = REF.layout([c.dtype.itemsize for c in inputs["columns"]])[2]
-    for s in spans.values():
+    for name, s in spans.items():
         assert s["span_kind"] == "phase"
-        assert s["attrs"] == {"rows": 100, "engine": "words",
-                              "bytes": 100 * row_size}
+        want = {"rows": 100, "engine": "words", "bytes": 100 * row_size}
+        if name == "from_rows":     # 8 values, validity words, all-valid
+            want["results"] = 10
+        assert s["attrs"] == want
 
 
 def test_a_conversion_under_jit_records_nothing(counting):
@@ -240,9 +260,14 @@ def test_rows_of_differing_size_keep_the_gather_path_and_agree(counting):
     obs.reset()
     back = RC.convert_from_rows(loose, schema)
     assert conversions() == {("from_rows", "gather"): 1}
+    gather_span = [s for s in obs.TRACER.records()
+                   if s["name"] == "from_rows"][-1]
+    assert "results" not in gather_span["attrs"]
     for c, a, v in zip(back.columns, inputs["columns"], valid):
         assert np.asarray(c.data).tobytes() == a.tobytes()
+        # the gather keeps handing over a vector a column
         np.testing.assert_array_equal(np.asarray(c.validity), v)
+    assert validity_outcomes() == {}
 
 
 def test_uniform_rows_in_a_byte_buffer_from_elsewhere_take_words(counting):
@@ -262,7 +287,7 @@ def test_uniform_rows_in_a_byte_buffer_from_elsewhere_take_words(counting):
     for t in (back, again):
         for c, a, v in zip(t.columns, inputs["columns"], valid):
             assert np.asarray(c.data).tobytes() == a.tobytes()
-            np.testing.assert_array_equal(np.asarray(c.validity), v)
+            check_validity(c, v)
 
 
 def test_without_the_executable_cache_the_engine_is_the_same(
@@ -297,6 +322,244 @@ def test_on_a_tpu_the_tile_kernel_takes_the_rows_its_vmem_holds(
     check_round_trip(inputs["columns"], table, valid)
     assert conversions() == {("to_rows", engine): 1,
                              ("from_rows", "words"): 1}
+
+
+# ------------- from-rows' validity is deferred (ISSUE 37): the extract
+# executable hands over the validity words and one all-valid word, and
+# a column's vector is made only where the row buffer holds a null
+
+
+@pytest.fixture
+def readbacks(monkeypatch):
+    """Counts the device-to-host reads of a table's all-valid word."""
+    seen = []
+    real = RC._read_all_valid
+    monkeypatch.setattr(RC, "_read_all_valid",
+                        lambda a: seen.append(a.shape) or real(a))
+    return seen
+
+
+@pytest.fixture
+def built(monkeypatch):
+    """Names of the executables the compile cache is asked for."""
+    seen = []
+    real = JC.CACHE.get_or_build
+
+    def spy(name, *a, **kw):
+        seen.append(name)
+        return real(name, *a, **kw)
+
+    monkeypatch.setattr(JC.CACHE, "get_or_build", spy)
+    return seen
+
+
+def with_nulls_in(rows, columns, seed, null_columns):
+    """``seeded`` with nulls in the columns listed alone; every such
+    column has a null (the last row) and, from two rows on, a valid
+    row (the first)."""
+    inputs, table, valid = seeded(rows, columns, seed, False)
+    rng = np.random.default_rng(seed + 2)
+    for ci in null_columns:
+        v = rng.integers(0, 2, rows).astype(np.uint8)
+        v[0], v[-1] = 1, 0              # one row: the null wins
+        valid[ci] = v
+        c = table.columns[ci]
+        table.columns[ci] = Column(c.dtype, rows, data=c.data,
+                                   validity=jnp.asarray(v))
+    return inputs, table, valid
+
+
+VALIDITY_VECTOR = "row_conversion.from_rows.validity"
+
+
+@pytest.mark.parametrize("null_columns", ["none", "one", "three", "all"])
+@pytest.mark.parametrize("columns,validity_bytes", [(1, 1), (9, 2),
+                                                    (212, 27)])
+@pytest.mark.parametrize("rows", [1, 300, 4096, 5000])
+def test_from_rows_defers_validity_and_makes_only_the_vectors_of_nulls(
+        counting, readbacks, built, rows, columns, validity_bytes,
+        null_columns):
+    """1, 9 and 212 columns hold their validity in 1, 2 and 27 bytes
+    (one word, one word, seven); 300 and 5,000 rows are neither a
+    multiple of 128 nor a power of two, so their buckets (512, 8,192)
+    hold pad rows, which are no nulls; 1 row shares the smallest."""
+    picked = {"none": [], "one": [columns - 1],
+              "three": sorted({0, columns // 2, columns - 1}),
+              "all": list(range(columns))}[null_columns]
+    inputs, table, valid = with_nulls_in(
+        rows, columns, 37 * rows + columns, picked)
+    schema = [c.dtype for c in table.columns]
+    assert (columns + 7) // 8 == validity_bytes
+    rows_col = RC.convert_to_rows(table)
+    back = RC.convert_from_rows(rows_col, schema)
+    jax.block_until_ready([c.data for c in back.columns])
+    # the call, and waiting on the values, read nothing back and
+    # resolve nothing
+    assert readbacks == [] and validity_outcomes() == {}
+    span = [s for s in obs.TRACER.records() if s["name"] == "from_rows"][-1]
+    assert span["attrs"]["results"] == columns + 2
+    for c, v in zip(back.columns, valid):
+        check_validity(c, v)
+        check_validity(c, v)            # a second read: the same answer
+        assert c.null_count() == (0 if v is None else int(rows - v.sum()))
+        np.testing.assert_array_equal(
+            np.asarray(c.valid_mask()),
+            np.ones(rows, bool) if v is None else v.astype(bool))
+    # one readback a table, of one u32 a validity word of the row;
+    # every column counted once, a vector made only where a null is
+    assert readbacks == [((validity_bytes + 3) // 4,)]
+    want = {"absent": columns - len(picked), "materialized": len(picked)}
+    assert validity_outcomes() == {k: n for k, n in want.items() if n}
+    assert (VALIDITY_VECTOR in built) == bool(picked)
+    assert built.count(VALIDITY_VECTOR) == len(picked)
+    # and back to rows: byte for byte what came in
+    again = RC.convert_to_rows(back)
+    assert np.array_equal(host_rows(again, rows), host_rows(rows_col, rows))
+    assert numbers_of(rows_col, back, inputs["columns"], valid) == {
+        "row_bytes_differing": 0, "column_bytes_differing": 0}
+
+
+@pytest.mark.parametrize("schema,bits", [
+    ([dtypes.INT8], [8]),                       # validity at byte 1
+    ([dtypes.INT16, dtypes.INT8], [24, 25]),    # at byte 3 of word 0
+    ([dtypes.INT32, dtypes.INT8] + [dtypes.BOOL8] * 29,
+     [16 + i for i in range(31)])])             # bytes 34-37: words 8, 9
+def test_validity_bytes_that_share_a_word_with_fields(counting, schema, bits):
+    """The validity bytes start where the last field ends, so the first
+    validity word may hold field bytes below them and the bytes may
+    straddle two words."""
+    starts, voff, _ = RC.compute_layout(schema)
+    vw0, vw1, bit0 = RC._validity_bits(len(schema), voff)
+    assert [bit0 + ci for ci in range(len(schema))] == bits
+    assert vw0 == voff // 4
+    assert vw1 == (voff + (len(schema) + 7) // 8 - 1) // 4 + 1
+    rows = 77
+    rng = np.random.default_rng(len(schema))
+    valid = [rng.integers(0, 2, rows).astype(np.uint8) if ci % 2 == 0
+             else None for ci in range(len(schema))]
+    for v in valid[::2]:
+        v[:2] = 0, 1
+    table = Table([Column.from_numpy(
+        rng.integers(0, 2, rows).astype(dt.np_dtype), validity=v, dtype=dt)
+        for dt, v in zip(schema, valid)])
+    back = RC.convert_from_rows(RC.convert_to_rows(table), schema)
+    for c, a, v in zip(back.columns, table.columns, valid):
+        assert np.asarray(c.data).tobytes() == np.asarray(a.data).tobytes()
+        check_validity(c, v)
+
+
+def test_rows_from_elsewhere_get_their_validity_from_the_buffer(counting):
+    """No side channel from ``convert_to_rows``: a u8 buffer written on
+    the host, with the spare bits of the last validity byte and the
+    row's tail bytes set to anything, answers from its own bits."""
+    inputs, table, valid = with_nulls_in(200, 9, 41, [4])
+    schema = [c.dtype for c in table.columns]
+    tight = host_rows(RC.convert_to_rows(table), 200).copy()
+    voff = REF.layout([c.dtype.itemsize for c in inputs["columns"]])[1]
+    tight[::2, voff + 1] |= 0xFE        # bits of columns that are none
+    tight[1::2, voff + 1] &= 0x01
+    tight[:, voff + 2:] = np.random.default_rng(5).integers(
+        0, 256, tight[:, voff + 2:].shape)
+    offs = np.arange(201, dtype=np.int32) * tight.shape[1]
+    foreign = Column.make_list_from_parts(jnp.asarray(offs),
+                                          jnp.asarray(tight.reshape(-1)))
+    back = RC.convert_from_rows(foreign, schema)
+    for c, a, v in zip(back.columns, inputs["columns"], valid):
+        assert np.asarray(c.data).tobytes() == a.tobytes()
+        check_validity(c, v)
+    # a null written into the buffer after the program made it is seen
+    tight[17, voff] &= ~np.uint8(1 << 2)
+    edited = Column.make_list_from_parts(jnp.asarray(offs),
+                                         jnp.asarray(tight.reshape(-1)))
+    v2 = np.ones(200, np.uint8)
+    v2[17] = 0
+    back = RC.convert_from_rows(edited, schema)
+    check_validity(back.columns[2], v2)
+    check_validity(back.columns[3], None)
+
+
+@pytest.mark.parametrize("null_columns", [[], [1, 7]])
+def test_a_from_rows_table_flattens_and_goes_through_jit(
+        counting, readbacks, null_columns):
+    inputs, table, valid = with_nulls_in(300, 9, 43, null_columns)
+    schema = [c.dtype for c in table.columns]
+    back = RC.convert_from_rows(RC.convert_to_rows(table), schema)
+    leaves, tree = jax.tree_util.tree_flatten(back)
+    assert len(readbacks) == 1
+    # values, and a vector for the two columns with nulls alone
+    assert len(leaves) == 9 + len(null_columns)
+    assert tree == jax.tree_util.tree_structure(table)
+    rebuilt = jax.tree_util.tree_unflatten(tree, leaves)
+    for c, v in zip(rebuilt.columns, valid):
+        check_validity(c, v)
+
+    @jax.jit
+    def nulls_and_rows(t):
+        return (sum(jnp.sum(~c.valid_mask()) for c in t.columns),
+                RC.convert_to_rows(t).children[0].data)
+
+    fresh = RC.convert_from_rows(RC.convert_to_rows(table), schema)
+    n, words = nulls_and_rows(fresh)
+    assert int(n) == sum(int(300 - v.sum()) for v in valid if v is not None)
+    assert np.array_equal(
+        np.asarray(words), np.asarray(nulls_and_rows(table)[1]))
+    assert len(readbacks) == 2          # one a table
+
+
+def test_two_threads_resolve_a_table_once(counting, readbacks):
+    import threading
+
+    inputs, table, valid = with_nulls_in(1000, 212, 47, [0, 100, 211])
+    schema = [c.dtype for c in table.columns]
+    back = RC.convert_from_rows(RC.convert_to_rows(table), schema)
+    start = threading.Barrier(4)
+    got, errors = [], []
+
+    def read():
+        try:
+            start.wait()
+            got.append([c.validity for c in back.columns])
+        except Exception as e:          # pragma: no cover
+            errors.append(e)
+
+    threads = [threading.Thread(target=read) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errors and len(got) == 4
+    for seen in got:
+        for a, b in zip(seen, got[0]):
+            assert a is b               # one vector a column, shared
+    assert len(readbacks) == 1
+    assert validity_outcomes() == {"absent": 209, "materialized": 3}
+    for c, v in zip(back.columns, valid):
+        check_validity(c, v)
+
+
+def test_a_validity_assigned_to_a_column_is_read_back_as_it_is():
+    from spark_rapids_tpu.columns.column import DeferredValidity
+
+    v = jnp.asarray(np.array([1, 0, 1], np.uint8))
+    c = Column(dtypes.INT32, 3, data=jnp.arange(3, dtype=jnp.int32))
+    assert c.validity is None and not c.has_validity
+    c.validity = v
+    assert c.validity is v and c.has_validity and c.null_count() == 1
+    c.validity = None
+    assert c.validity is None and c.null_count() == 0
+
+    class Once(DeferredValidity):
+        calls = 0
+
+        def resolve(self):
+            Once.calls += 1
+            return v
+
+    c = Column(dtypes.INT32, 3, data=c.data, validity=Once())
+    assert c.data is not None and Once.calls == 0
+    assert c.validity is v and c.validity is v and Once.calls == 1
+    leaves = jax.tree_util.tree_leaves(c)
+    assert len(leaves) == 2 and leaves[1] is v
 
 
 # ------------- the cell's binding: a program off the default engines

@@ -248,7 +248,9 @@ def test_rowconv_stack_path_compiles(shaped, rowconv):
 def test_rowconv_from_rows_compiles_at_the_cell_size(shaped, rowconv):
     """The two from-rows executables of the benchmark's cell: 2^20 rows
     of 212 columns as one flat word buffer transposed to (274, 8192,
-    128) blocks, then every field a static slice of a block."""
+    128) blocks, then every field a static slice of a block, the seven
+    validity blocks as they are and their AND over the rows: 214
+    results, where a validity vector a column made 424 (ISSUE 37)."""
     from spark_rapids_tpu.ops import row_conversion as RC
     schema, starts, voff, row_size = rowconv
     n_words = row_size // 4
@@ -268,6 +270,28 @@ def test_rowconv_from_rows_compiles_at_the_cell_size(shaped, rowconv):
         assert " gather(" not in compiled.as_text()
     # the slices read the blocks in place: no temporaries to speak of
     assert second.memory_analysis().temp_size_in_bytes < 2 ** 26
+    results = jax.tree_util.tree_leaves(second.out_info)
+    assert len(results) == len(schema) + 2
+    vals, vwords, all_valid = second.out_info
+    assert len(vals) == len(schema)
+    assert (vwords.shape, vwords.dtype) == (
+        (7, ROWCONV_CELL_ROWS // 128, 128), U32)
+    assert (all_valid.shape, all_valid.dtype) == ((7,), U32)
+
+
+def test_rowconv_validity_vector_compiles_at_the_cell_size(shaped):
+    """The executable that makes one column's validity vector out of
+    the kept validity words, which a column with a null alone asks for:
+    the column's bit is an operand, so one serves all 212."""
+    from spark_rapids_tpu.ops import row_conversion as RC
+
+    compiled = jax.jit(RC._column_validity).lower(
+        shaped((7, ROWCONV_CELL_ROWS // 128, 128), U32),
+        shaped((), I32)).compile()
+    assert _device_gib(compiled) < HBM_GIB
+    assert " gather(" not in compiled.as_text()
+    (out,) = jax.tree_util.tree_leaves(compiled.out_info)
+    assert (out.shape, out.dtype) == ((ROWCONV_CELL_ROWS,), jnp.uint8)
 
 
 # The Pallas to-rows kernel is a TPU's engine for fixed-width schemas
@@ -407,6 +431,7 @@ def test_thousand_columns_compile_on_the_word_path(shaped):
         compiled = jax.jit(fn).lower(arg).compile()
         assert _device_gib(compiled) < HBM_GIB
         assert " gather(" not in compiled.as_text()
+    assert len(jax.tree_util.tree_leaves(compiled.out_info)) == 1000 + 2
 
 
 @pytest.mark.xfail(strict=True, reason=(
