@@ -33,14 +33,24 @@ import time
 # fact rows: TPC-DS SF10 store_sales (spec table 3-2); q5's 14-of-60-day
 # window matches ~6.7 M pairs, hence the join capacity.  ops = the
 # bench_all.py shapes (rows, groups, join keyspace).
+# q5_tables: the template's q5 database (None = SF10's row counts,
+# models/tpcds.Q5_SF10; toy = the benchmark configuration's toy sizes)
 SIZES = {
     "full": dict(rows=28_800_991, join_capacity=1 << 23,
                  rowconv_rows=1 << 19, rowconv_schemas=8,
-                 ops=(10_000_000, 10_000, 1_000_000), xchg_rows=1 << 22),
+                 ops=(10_000_000, 10_000, 1_000_000), xchg_rows=1 << 22,
+                 q5_tables=None),
     "toy": dict(rows=4096, join_capacity=1 << 12, rowconv_rows=4096,
                 rowconv_schemas=4,
-                ops=(1 << 16, 100, 1 << 12), xchg_rows=1 << 12),
+                ops=(1 << 16, 100, 1 << 12), xchg_rows=1 << 12,
+                q5_tables=dict(store_sales=20_000, store_returns=2_000,
+                               catalog_sales=40_000,
+                               catalog_returns=4_000, web_sales=12_000,
+                               web_returns=1_200, date_dim=73_049,
+                               store=12, catalog_page=150, web_site=6,
+                               item=1_000)),
 }
+Q5_DB_SEED = 2002
 # (rows, columns, nulls in every column, every fifth column a
 # decimal128): the schemas around the 212-column phase.  On a TPU the
 # Pallas tile kernel takes the rows its VMEM holds (599 cycled columns
@@ -197,11 +207,26 @@ def ref_q9(quantity, price, profit):
 # ---------------------------------------------------------- phase: serve
 
 
-def phase_serve(rows: int, cap: int, seed0: int,
+def bench_reference(name: str):
+    """``benchmark/reference/<name>.py``, the benchmark's plain numpy
+    reference (it imports nothing of the program)."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark", "reference", name + ".py")
+    spec = importlib.util.spec_from_file_location("ref_" + name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def phase_serve(rows: int, cap: int, seed0: int, q5_tables,
                 compiles: Compiles) -> None:
     """q5_fused, q3_fused, q9 through QueryServer.submit/poll as two
     tenants, each once cold and once warm (another seed, same shapes,
-    result cache off so the device really runs)."""
+    result cache off so the device really runs); then the template's
+    q5 (``tpcds_q5_channels``) once, loading its database onto the
+    device, every row of its rollup against the benchmark's numpy
+    reference."""
     import itertools
     import math
 
@@ -274,6 +299,21 @@ def phase_serve(rows: int, cap: int, seed0: int,
                          if v > before.get(k, 0))
             say(phase="serve", query=name, rows=rows, calls=calls,
                 stages=ran, device=device_bytes())
+        ref = bench_reference("tpcds_q5")
+        sizes = dict(q5_tables or tpcds.Q5_SF10, db_seed=Q5_DB_SEED)
+        # every row of the rollup, so each channel's ids are compared
+        every = {"limit": 2 ** 31 - 1}
+        params = ref.query_params(sizes, every, seed0 + 5)
+        got, seconds = serve("tpcds_q5_channels", params)
+        bad = ref.compare(ref.from_served(got), ref.answer(
+            ref.make_inputs(sizes, every, seed0 + 5), every))
+        check(bad == {"values_differing": 0},
+              f"q5 channels {params['sales_date']}: {bad}")
+        say(phase="serve", query="tpcds_q5_channels",
+            rows=sum(sizes[t] for t in tpcds.Q5_FACTS),
+            sales_date=params["sales_date"],
+            calls=[{"call": "cold", "seconds": round(seconds, 3)}],
+            device=device_bytes())
     finally:
         stop_server()
 
@@ -664,7 +704,7 @@ def main(argv=None) -> int:
         phases = args.phases.split(",")
         if "serve" in phases:
             phase_serve(size["rows"], size["join_capacity"], args.seed,
-                        compiles)
+                        size["q5_tables"], compiles)
         if "rowconv" in phases:
             phase_rowconv(size["rowconv_rows"], compiles)
             phase_rowconv_schemas(size["rowconv_schemas"])

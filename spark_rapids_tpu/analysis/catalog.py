@@ -89,6 +89,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "segment sums traced by engine"),
     "srt_dense_lookup_total": (
         "counter", "table lookups traced by engine"),
+    "srt_resident_table_total": (
+        "counter", "resident catalog tables by load / hit / evict"),
     "srt_row_conversion_total": (
         "counter", "eager row conversions by direction and engine"),
     "srt_from_rows_validity_total": (

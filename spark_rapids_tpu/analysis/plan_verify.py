@@ -132,6 +132,9 @@ def _expr_refs(e, out: List[Tuple[str, str]]) -> None:
     elif isinstance(e, ir.Idx):
         _expr_refs(e.src, out)
         _expr_refs(e.idx, out)
+    elif isinstance(e, ir.IsIn):
+        _expr_refs(e.a, out)
+        _expr_refs(e.keys, out)
     elif isinstance(e, ir.Stack):
         for p in e.parts:
             _expr_refs(p, out)
@@ -155,6 +158,9 @@ def _check_expr_ops(plan_name: str, label: str, e) -> None:
     elif isinstance(e, ir.Idx):
         _check_expr_ops(plan_name, label, e.src)
         _check_expr_ops(plan_name, label, e.idx)
+    elif isinstance(e, ir.IsIn):
+        _check_expr_ops(plan_name, label, e.a)
+        _check_expr_ops(plan_name, label, e.keys)
     elif isinstance(e, ir.Sl):
         if e.start < 0 or e.stop < e.start:
             raise PlanVerifyError(
@@ -284,6 +290,11 @@ def _expr_dtype(plan_name: str, label: str, e, env: Dict[str, object]):
                 plan_name, label,
                 f"gather index has dtype {idx}, expected integer")
         return _expr_dtype(plan_name, label, e.src, env)
+    if isinstance(e, ir.IsIn):
+        _promote(plan_name, label,
+                 _expr_dtype(plan_name, label, e.a, env),
+                 _expr_dtype(plan_name, label, e.keys, env))
+        return "bool"
     if isinstance(e, ir.Arange):
         return str(e.dtype)
     if isinstance(e, ir.Sl):

@@ -361,6 +361,41 @@ def _run_q72_fused(params: dict, ctx: QueryContext):
     return _rows(i, w, c)
 
 
+def _run_q5_channels(params: dict, ctx: QueryContext):
+    """TPC-DS q5 as its template writes it (three channels, the web
+    returns-to-sales join, ROLLUP) over a database held on the device:
+    ``sizes`` and ``db_seed`` name the database (SF10 by default), which
+    the first query loads into ``resident.REGISTRY`` and later queries
+    bind to; ``sales_date`` and ``limit`` (the template's _LIMIT, 100
+    by default) are the substitutions.  Rows are (channel, id, sales,
+    returns, profit), NULL as -1."""
+    import numpy as np
+
+    from spark_rapids_tpu.models import resident, tpcds
+    from spark_rapids_tpu.plan import catalog as plan_catalog
+    ctx.check_cancel()
+    sizes = tpcds.q5_sizes(params.get("sizes"))
+    db_seed = int(params.get("db_seed", 5))
+    day = tpcds.q5_day(params.get("sales_date", tpcds.Q5_SALES_DATE))
+    tables = resident.REGISTRY.get(
+        ("tpcds_q5", tuple(sorted(sizes.items())), db_seed),
+        lambda: plan_catalog.q5_channels_tables(
+            tpcds.gen_q5_db(sizes, db_seed)))
+    shape = plan_catalog.q5_channels_shape(
+        sizes, tpcds.q5_dim_ids(sizes), tpcds.Q5_WINDOW_DAYS)
+    limit = int(params.get("limit", tpcds.Q5_LIMIT))
+    # _execute's span, with the probe's true pair count on it
+    with ctx.phase("execute", path="stage") as span:
+        *rows, of, pairs = plan_catalog.run_q5_channels(tables, shape,
+                                                        day, limit)
+        span.set_attr("join_pairs", int(np.asarray(pairs)))
+    if bool(np.asarray(of)):
+        raise RuntimeError("q5 capacity overflow: a web sale key "
+                           "repeats or a date window holds more than "
+                           f"{plan_catalog.Q5_WINDOW_KEYS} date keys")
+    return _rows(*rows)
+
+
 # file-backed variants (models/filesource.py): same seeded data via a
 # parquet round trip through io/parquet_reader, same cached pipeline,
 # byte-identical rows — registered thin so pyarrow loads on first use
@@ -510,6 +545,7 @@ register_query("tpcds_q72", _run_q72)
 register_query("tpcds_q3_fused", _run_q3_fused)
 register_query("tpcds_q5_fused", _run_q5_fused)
 register_query("tpcds_q72_fused", _run_q72_fused)
+register_query("tpcds_q5_channels", _run_q5_channels)
 register_query("tpcds_q3_file", _run_q3_file)
 register_query("tpcds_q7_file", _run_q7_file)
 register_query("tpcds_q9_file", _run_q9_file)

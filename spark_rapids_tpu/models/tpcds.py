@@ -12,7 +12,15 @@ queries have:
   * q5-shape : sales & returns facts joined to a date-filtered
                date_dim and to a store dim, grouped by store with
                decimal sums, ordered by store — join -> join ->
-               group-by -> order-by.
+               group-by -> order-by.  This is the STORE-CHANNEL shape
+               (gen_q5 / make_q5, ``tpcds_q5`` / ``tpcds_q5_fused``),
+               which the distributed, mesh and incremental paths keep;
+               it is not TPC-DS q5.  The template's q5 (query5.tpl:
+               three channels, the web returns-to-sales join, ROLLUP,
+               over a database held on the device) is
+               ``tpcds_q5_channels``: gen_q5_db below, the plan in
+               plan/catalog.py (q5_channels_pipeline), one channel
+               body shared with this shape.
   * q72-shape: catalog_sales joined to inventory on item (fact-fact),
                week-offset filter through date lookups, inventory
                shortage filter, item dim join, group by (item, week),
@@ -267,6 +275,126 @@ def oracle_q5(d: Q5Data, stores: int):
     rows = sorted((int(h.st_id[st]), a, b, c)
                   for st, (a, b, c) in out.items())
     return rows
+
+
+# ----------------------------------- q5 as its template writes it (SF10)
+
+# TPC-DS spec v3 table 3-2 at SF10 (rows), and the item count the web
+# keys draw from
+Q5_SF10 = dict(store_sales=28_800_991, store_returns=2_875_432,
+               catalog_sales=14_401_261, catalog_returns=1_439_749,
+               web_sales=7_197_566, web_returns=719_217, date_dim=73_049,
+               store=102, catalog_page=12_000, web_site=42, item=102_000)
+Q5_FACTS = ("store_sales", "store_returns", "catalog_sales",
+            "catalog_returns", "web_sales", "web_returns")
+# date_dim's first row: d_date_sk 2,415,022 is 1900-01-02 (days since
+# 1970-01-01); sale dates 1998-01-02 .. 2002-12-31; a return 1-90 days
+# after its sale; twelve lines a web order
+D_DATE_SK0, D_DATE0 = 2_415_022, -25_566
+SALE_FIRST, SALE_LAST = 10_228, 12_052
+RETURN_LAG = (1, 91)
+WEB_LINES = 12
+# SALES_DATE's qualification value, the window's length, and the
+# template's _LIMIT
+Q5_SALES_DATE = "2000-08-23"
+Q5_WINDOW_DAYS = 15
+Q5_LIMIT = 100
+
+
+def q5_sizes(sizes=None) -> dict:
+    """The table sizes of a q5 database: SF10's, or ``sizes`` with
+    every key of them (a toy database in the tests)."""
+    out = dict(Q5_SF10)
+    if sizes:
+        unknown = set(sizes) - set(out)
+        if unknown:
+            raise ValueError(f"unknown q5 tables {sorted(unknown)}")
+        out.update({k: int(v) for k, v in sizes.items()})
+    if out["item"] < WEB_LINES:
+        raise ValueError("q5 needs at least one item per web order line")
+    return out
+
+
+def q5_day(sales_date: str) -> int:
+    """SALES_DATE, an ISO date, as days since 1970-01-01."""
+    import datetime
+    return (datetime.date.fromisoformat(sales_date)
+            - datetime.date(1970, 1, 1)).days
+
+
+def q5_ids(outlets: int, shared: bool) -> int:
+    """Business ids of an outlet dim: two surrogate keys an id where
+    the dim keeps revisions (store, web_site), one otherwise."""
+    return (outlets + 1) // 2 if shared else outlets
+
+
+def q5_dim_ids(sizes: dict) -> dict:
+    """Business ids of each outlet dim of a q5 database of ``sizes``."""
+    return {dim: q5_ids(sizes[dim], dim != "catalog_page")
+            for dim in ("store", "catalog_page", "web_site")}
+
+
+def gen_q5_db(sizes: dict, seed: int) -> dict:
+    """The q5 database on the host (numpy), from ``seed``.  Draw order
+    (benchmark/reference/tpcds_q5.py repeats it): the three outlet
+    dims' id permutations (store, catalog_page, web_site), then per
+    fact in Q5_FACTS order its columns left to right.  date_dim is one
+    row a day from 1900-01-02 (no draw); outlet surrogate keys are
+    1..n and the dim holds each key's business id as a dictionary id
+    (ranked as the id strings sort); amounts are int64 cents."""
+    sizes = q5_sizes(sizes)
+    rng = np.random.default_rng(seed)
+    i32, i64 = np.int32, np.int64
+    n_dates = sizes["date_dim"]
+    db = {"d_date_sk": np.arange(D_DATE_SK0, D_DATE_SK0 + n_dates,
+                                 dtype=i32),
+          "d_date": np.arange(D_DATE0, D_DATE0 + n_dates, dtype=i32)}
+    for dim, shared in (("store", True), ("catalog_page", False),
+                        ("web_site", True)):
+        n = sizes[dim]
+        per = 2 if shared else 1
+        db[dim] = rng.permutation(q5_ids(n, shared)).astype(i32)[
+            np.arange(n) // per]
+    sale = (SALE_FIRST - D_DATE0 + D_DATE_SK0,
+            SALE_LAST - D_DATE0 + D_DATE_SK0 + 1)
+
+    def sales(n, outlets):
+        return (rng.integers(*sale, n, dtype=i32),
+                rng.integers(1, outlets + 1, n, dtype=i32),
+                rng.integers(0, 10_000_000, n, dtype=i64),
+                rng.integers(-5_000_000, 5_000_000, n, dtype=i64))
+
+    def returns(n, outlets):
+        date = rng.integers(*sale, n, dtype=i32)
+        date += rng.integers(*RETURN_LAG, n, dtype=i32)
+        return (date, rng.integers(1, outlets + 1, n, dtype=i32),
+                rng.integers(0, 10_000_000, n, dtype=i64),
+                rng.integers(0, 5_000_000, n, dtype=i64))
+
+    db["store_sales"] = sales(sizes["store_sales"], sizes["store"])
+    db["store_returns"] = returns(sizes["store_returns"], sizes["store"])
+    db["catalog_sales"] = sales(sizes["catalog_sales"],
+                                sizes["catalog_page"])
+    db["catalog_returns"] = returns(sizes["catalog_returns"],
+                                    sizes["catalog_page"])
+    n_ws, items = sizes["web_sales"], sizes["item"]
+    ws = sales(n_ws, sizes["web_site"])
+    # (item, order number) is web_sales' key: an order's lines take
+    # consecutive items from a drawn first one
+    first = rng.integers(0, items, -(-n_ws // WEB_LINES), dtype=i64)
+    row = np.arange(n_ws, dtype=i64)
+    ws_item = ((first[row // WEB_LINES] + row % WEB_LINES) % items
+               + 1).astype(i32)
+    ws_order = (row // WEB_LINES + 1).astype(i32)
+    db["web_sales"] = ws + (ws_item, ws_order)
+    # each return is one line of a sale, its date after the sale's
+    n_wr = sizes["web_returns"]
+    pick = rng.choice(n_ws, n_wr, replace=False)
+    wr_date = ws[0][pick] + rng.integers(*RETURN_LAG, n_wr, dtype=i32)
+    db["web_returns"] = (wr_date, ws_item[pick], ws_order[pick],
+                         rng.integers(0, 10_000_000, n_wr, dtype=i64),
+                         rng.integers(0, 5_000_000, n_wr, dtype=i64))
+    return db
 
 
 # ------------------------------------------------------------------- q9

@@ -275,6 +275,12 @@ DENSE_LOOKUP = METRICS.counter(
     "products over 8-bit limbs on the matrix unit, gather = "
     "table[idx]); counted per table when a program is traced, not "
     "when it runs", labels=("engine",))
+RESIDENT_TABLE = METRICS.counter(
+    "srt_resident_table_total",
+    "Catalog tables held on the device between queries "
+    "(models/resident.py), by outcome: load = generated and uploaded, "
+    "hit = a query bound to what was held, evict = dropped for the "
+    "byte budget, least recently used first", labels=("outcome",))
 ROW_CONVERSION = METRICS.counter(
     "srt_row_conversion_total",
     "Eager JCUDF row conversions by direction (to_rows / from_rows) "
@@ -1405,6 +1411,14 @@ def record_dense_lookup(engine: str) -> None:
     choice is static per executable, so this counts builds."""
     if _SWITCH.enabled:
         DENSE_LOOKUP.inc(labels=(engine,))
+
+
+def record_resident_table(outcome: str) -> None:
+    """Resident-table hook (models/resident.py): ``outcome`` is 'load'
+    (a database generated and put on the device), 'hit' (a query bound
+    to the one held) or 'evict' (dropped for the byte budget)."""
+    if _SWITCH.enabled:
+        RESIDENT_TABLE.inc(labels=(outcome,))
 
 
 def record_row_conversion(direction: str, engine: str) -> None:

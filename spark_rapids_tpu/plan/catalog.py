@@ -14,6 +14,11 @@ pipelines compiled through plan/compiler.py:
                  shuffle; single-process runs hand the carry straight
                  across, a mesh rank fuses the WHOLE pipeline into one
                  shard_map program with psum at the Reduce nodes;
+  * q5 as its template writes it (``tpcds_q5_channels``) — two
+                 stages over a database held on the device: the three
+                 channels' date filter, sums and web join probe, then
+                 ROLLUP(channel, id), NULLS FIRST, LIMIT 100; its
+                 sides share ``_fact_side`` with the store-channel q5;
   * q67-shape  — GROUP BY ROLLUP(category, class) + rank() OVER
                  (PARTITION BY category ORDER BY sales DESC): the new
                  Rollup and WindowRank nodes (real q67 uses exactly
@@ -36,10 +41,11 @@ from spark_rapids_tpu.plan.compiler import (compile_pipeline,
                                             compile_stage,
                                             fused_pipeline_fn)
 from spark_rapids_tpu.plan.ir import (Arange, Bin, Col, ColSpec, Idx,
-                                      JoinProbe, Lit, Mask, Pipeline,
-                                      Project, Reduce, Rollup, ScanBind,
-                                      SegmentSum, ShuffleBoundary, Sl,
-                                      Sort, StagePlan, Stack, Un, Where,
+                                      IsIn, JoinProbe, Lit, Mask,
+                                      Pipeline, Project, Reduce, Rollup,
+                                      ScanBind, SegmentSum,
+                                      ShuffleBoundary, Sl, Sort,
+                                      StagePlan, Stack, Un, Where,
                                       WindowRank, WindowSum)
 
 I64_SENTINEL = Lit(2 ** 62, "int64")
@@ -52,11 +58,38 @@ def _and(*es):
     return out
 
 
+def _or(*es):
+    out = es[0]
+    for e in es[1:]:
+        out = Bin("or", out, e)
+    return out
+
+
 def _gt0(e):
     return Bin("gt", e, Lit(0))
 
 
 # ------------------------------------------------------------------- q5
+
+
+def _fact_side(side: str, keep, outlet, amt_a, amt_b,
+               outlets: int) -> list:
+    """One fact of a q5 channel: the rows ``keep`` selects (the date
+    filter, and the join that found them), grouped by ``outlet`` (a
+    dense key, 0-based), with the exact sums of its two amounts and
+    its row count: ``<side>_sum_a``, ``<side>_sum_b``,
+    ``<side>_seen``, each ``outlets`` long.  The store-channel shape
+    and each of the template's three channels build their sales and
+    their returns side from it."""
+    return [
+        Project(f"{side}_st", Where(keep, outlet, Lit(0))),
+        SegmentSum(f"{side}_sum_a", Where(keep, amt_a, Lit(0)),
+                   Col(f"{side}_st"), outlets),
+        SegmentSum(f"{side}_sum_b", Where(keep, amt_b, Lit(0)),
+                   Col(f"{side}_st"), outlets),
+        SegmentSum(f"{side}_seen", Un("i64", keep), Col(f"{side}_st"),
+                   outlets),
+    ]
 
 
 def q5_partials_plan(stores: int, join_capacity: int) -> StagePlan:
@@ -67,21 +100,12 @@ def q5_partials_plan(stores: int, join_capacity: int) -> StagePlan:
     for side, (date, key, amt_a, amt_b, j) in (
             ("s", ("s_date", "s_store", "s_price", "s_profit", "j1")),
             ("r", ("r_date", "r_store", "r_amt", "r_loss", "j2"))):
-        valid = Col(f"{j}.valid")
         li = Col(f"{j}.li")
-        nodes += [
-            JoinProbe(j, Col(date), Col("d_date"), join_capacity),
-            Project(f"{side}_st",
-                    Where(valid, Idx(Col(key), li), Lit(0))),
-            SegmentSum(f"{side}_sum_a",
-                       Where(valid, Idx(Col(amt_a), li), Lit(0)),
-                       Col(f"{side}_st"), stores),
-            SegmentSum(f"{side}_sum_b",
-                       Where(valid, Idx(Col(amt_b), li), Lit(0)),
-                       Col(f"{side}_st"), stores),
-            SegmentSum(f"{side}_seen", Un("i64", valid),
-                       Col(f"{side}_st"), stores),
-        ]
+        nodes.append(JoinProbe(j, Col(date), Col("d_date"),
+                               join_capacity))
+        nodes += _fact_side(side, Col(f"{j}.valid"), Idx(Col(key), li),
+                            Idx(Col(amt_a), li), Idx(Col(amt_b), li),
+                            stores)
     nodes += [
         Project("profit", Bin("sub", Col("s_sum_b"), Col("r_sum_b"))),
         Project("seen", Bin("add", Col("s_seen"), Col("r_seen"))),
@@ -207,6 +231,294 @@ def run_q5_finish(sales, rets, profit, seen, of, st_id, stores: int):
     return st.run({"xchg": (sales, rets, profit, seen, of),
                    "dims": (st_id,)})
 
+
+# ------------------------------------- q5 as its template writes it
+
+# the three channels in the order their names sort ('catalog channel'
+# < 'store channel' < 'web channel'), which is their served code:
+# (channel, sales input, returns input, outlet dim input)
+Q5_CHANNELS = (("catalog", "cs", "cr", "catalog_page"),
+               ("store", "ss", "sr", "store"),
+               ("web", "ws", "wr", "web_site"))
+# date keys a 15-day window can hold (one date_dim row a date); more
+# survivors flag an overflow
+Q5_WINDOW_KEYS = 16
+NULL_CODE = -1                      # a rollup row's NULL channel / id
+DEAD_CODE = 2 ** 31 - 1             # an output slot past the live rows
+
+
+def _fact(name, *cols, pads=None):
+    pads = pads or {}
+    return ScanBind(name, tuple(ColSpec(f"{name}_{c}", pad=pads.get(c, 0))
+                                for c in cols))
+
+
+# the map stage's inputs: six facts, held on the device padded to their
+# row buckets (join keys with side-specific pads), date_dim, each outlet
+# dim's business id by surrogate key (key k at k - 1), and SALES_DATE
+Q5_CHANNEL_INPUTS = (
+    _fact("ss", "date", "outlet", "price", "profit"),
+    _fact("sr", "date", "outlet", "amt", "loss"),
+    _fact("cs", "date", "outlet", "price", "profit"),
+    _fact("cr", "date", "outlet", "amt", "loss"),
+    _fact("ws", "date", "outlet", "price", "profit", "item", "order",
+          pads={"item": -2}),
+    _fact("wr", "date", "item", "order", "amt", "loss",
+          pads={"item": -1}),
+    ScanBind("dd", (ColSpec("d_date_sk"), ColSpec("d_date")),
+             bucket=False),
+    ScanBind("store", (ColSpec("s_id"),), bucket=False),
+    ScanBind("catalog_page", (ColSpec("cp_id"),), bucket=False),
+    ScanBind("web_site", (ColSpec("web_id"),), bucket=False),
+    ScanBind("q", (ColSpec("sales_date"),), bucket=False),
+)
+_DIM_ID = {"store": "s_id", "catalog_page": "cp_id", "web_site": "web_id"}
+
+
+def q5_slots(ids):
+    """The group table's layout for ``ids`` business ids a channel (in
+    Q5_CHANNELS order): slot 0 the grand total, then per channel its
+    subtotal and its ids in dictionary order.  Returns (subtotal slot
+    per channel, slots)."""
+    subs, at = [], 1
+    for n in ids:
+        subs.append(at)
+        at += 1 + n
+    return tuple(subs), at
+
+
+def q5_channels_map_plan(outlets, ids, item_bits: int,
+                         join_capacity: int,
+                         window_days: int = 15) -> StagePlan:
+    """q5's map side, all three channels in one stage.
+
+    * date_dim is filtered first (``d_date`` between SALES_DATE and
+      SALES_DATE + ``window_days`` - 1, read at run time) and hands
+      over its surviving keys, as Spark's dynamic pruning does; each
+      fact keeps the rows whose date key is among them (``IsIn``);
+    * web returns find their sale through a JoinProbe on the packed
+      key (order number << ``item_bits``) | item over the whole of
+      web_sales, and take the sale's site;
+    * each fact side sums into its outlets (``_fact_side``), and each
+      channel's outlets fold through the outlet dim's business ids into
+      the one group table of ``q5_slots`` — the UNION ALL of the
+      channels."""
+    subs, n_slots = q5_slots(ids)
+    k = Q5_WINDOW_KEYS
+    nodes = [
+        Project("d_in", _and(
+            Bin("ge", Col("d_date"), Col("sales_date")),
+            Bin("le", Col("d_date"),
+                Bin("add", Col("sales_date"), Lit(window_days - 1))))),
+        Project("d_key", Where(Col("d_in"), Col("d_date_sk"),
+                               Lit(2 ** 31 - 1, "int32"))),
+        Sort(("d_key_s",), (Col("d_key"),), num_keys=1),
+        Project("win_n", Un("sum", Un("i32", Col("d_in")))),
+        Project("win_any", _gt0(Col("win_n"))),
+        # the empty slots repeat a surviving key: they match nothing new
+        Project("win_keys", Where(
+            Bin("lt", Arange(k, "int32"), Col("win_n")),
+            Sl(Col("d_key_s"), 0, k), Idx(Col("d_key_s"), Lit(0)))),
+    ]
+    for side in ("ss", "sr", "cs", "cr", "ws"):
+        nodes.append(Project(f"{side}_keep", _and(
+            IsIn(Col(f"{side}_date"), Col("win_keys")), Col("win_any"),
+            Mask(side))))
+    shift = Lit(1 << item_bits)
+    for side in ("wr", "ws"):
+        nodes.append(Project(f"{side}_key", Bin(
+            "add", Bin("mul", Un("i64", Col(f"{side}_order")), shift),
+            Un("i64", Col(f"{side}_item")))))
+    li, ri = Col("wj.li"), Col("wj.ri")
+    nodes += [
+        JoinProbe("wj", Col("wr_key"), Col("ws_key"), join_capacity,
+                  left_valid=Mask("wr"), right_valid=Mask("ws")),
+        Project("wr_keep", _and(
+            Col("wj.valid"),
+            IsIn(Idx(Col("wr_date"), li), Col("win_keys")),
+            Col("win_any"))),
+    ]
+    totals = {"sales": [], "returns": [], "profit": [], "cnt": []}
+    for (channel, sold, ret, dim), n_out, sub in zip(Q5_CHANNELS,
+                                                    outlets, subs):
+        nodes += _fact_side(sold, Col(f"{sold}_keep"),
+                            Bin("sub", Col(f"{sold}_outlet"), Lit(1)),
+                            Col(f"{sold}_price"), Col(f"{sold}_profit"),
+                            n_out)
+        if ret == "wr":
+            nodes += _fact_side(
+                ret, Col("wr_keep"),
+                Bin("sub", Idx(Col("ws_outlet"), ri), Lit(1)),
+                Idx(Col("wr_amt"), li), Idx(Col("wr_loss"), li), n_out)
+        else:
+            nodes += _fact_side(ret, Col(f"{ret}_keep"),
+                                Bin("sub", Col(f"{ret}_outlet"), Lit(1)),
+                                Col(f"{ret}_amt"), Col(f"{ret}_loss"),
+                                n_out)
+        slot = Col(f"{channel}_slot")
+        nodes.append(Project(f"{channel}_slot", Bin(
+            "add", Col(_DIM_ID[dim]), Lit(sub + 1))))
+        for name, value in (
+                ("sales", Col(f"{sold}_sum_a")),
+                ("returns", Col(f"{ret}_sum_a")),
+                ("profit", Bin("sub", Col(f"{sold}_sum_b"),
+                               Col(f"{ret}_sum_b"))),
+                ("cnt", Bin("add", Col(f"{sold}_seen"),
+                            Col(f"{ret}_seen")))):
+            nodes.append(SegmentSum(f"{channel}_{name}", value, slot,
+                                    n_slots))
+            totals[name].append(Col(f"{channel}_{name}"))
+    for name, parts in totals.items():
+        nodes.append(Project(name, Bin("add", Bin("add", *parts[:2]),
+                                       parts[2])))
+    nodes += [
+        Project("pairs", Col("wj.total")),
+        Project("of", Bin("or", Bin("gt", Col("wj.total"),
+                                    Lit(join_capacity)),
+                          Bin("gt", Col("win_n"), Lit(k)))),
+    ]
+    return StagePlan(name="q5_channels_map", inputs=Q5_CHANNEL_INPUTS,
+                     nodes=tuple(nodes),
+                     outputs=("sales", "returns", "profit", "cnt", "of",
+                              "pairs"))
+
+
+def q5_channels_finish_plan(ids, limit: int = 100) -> StagePlan:
+    """q5's finish: the global group table (``Reduce``, the seam a mesh
+    psums over), GROUP BY ROLLUP(channel, id) — a channel's subtotal
+    and the grand total summed from its ids — ORDER BY channel, id
+    with NULLS FIRST (the slot order), LIMIT ``limit``.  A NULL
+    channel or id is served as NULL_CODE, a slot past the live rows
+    with channel DEAD_CODE."""
+    subs, n_slots = q5_slots(ids)
+    slot = Col("slot")
+
+    def channel_of(e):
+        return Bin("add", Un("i64", Bin("ge", e, Lit(subs[1]))),
+                   Un("i64", Bin("ge", e, Lit(subs[2]))))
+
+    def is_rollup_row(e):
+        return _or(Bin("eq", e, Lit(0)),
+                   *(Bin("eq", e, Lit(s)) for s in subs))
+
+    nodes = [Reduce(f"g_{v}", Col(v))
+             for v in ("sales", "returns", "profit", "cnt")]
+    nodes += [Reduce("g_of", Col("of"), kind="any"),
+              Reduce("g_pairs", Col("pairs")),
+              Project("slot", Arange(n_slots, "int64")),
+              Project("ch", channel_of(slot)),
+              Project("is_sub", is_rollup_row(slot))]
+    for v in ("sales", "returns", "profit", "cnt"):
+        nodes += [
+            SegmentSum(f"sub_{v}", Col(f"g_{v}"), Col("ch"), 3),
+            Project(f"r_{v}", Where(
+                Bin("eq", slot, Lit(0)), Un("sum", Col(f"g_{v}")),
+                Where(Col("is_sub"), Idx(Col(f"sub_{v}"), Col("ch")),
+                      Col(f"g_{v}")))),
+        ]
+    top = Col("top")
+    nodes += [
+        Project("key", Where(_gt0(Col("r_cnt")), slot, I64_SENTINEL)),
+        Sort(("key_s", "sales_s", "returns_s", "profit_s"),
+             (Col("key"), Col("r_sales"), Col("r_returns"),
+              Col("r_profit")), num_keys=1),
+        Project("top", Sl(Col("key_s"), 0, limit)),
+        Project("top_ch", channel_of(top)),
+        Project("channel_out", Where(
+            Bin("eq", top, I64_SENTINEL), Lit(DEAD_CODE),
+            Where(Bin("eq", top, Lit(0)), Lit(NULL_CODE),
+                  Col("top_ch")))),
+        Project("id_out", Where(
+            _or(is_rollup_row(top), Bin("eq", top, I64_SENTINEL)),
+            Lit(NULL_CODE),
+            Bin("sub", Bin("sub", top, Lit(1)), Where(
+                Bin("eq", Col("top_ch"), Lit(0)), Lit(subs[0]),
+                Where(Bin("eq", Col("top_ch"), Lit(1)), Lit(subs[1]),
+                      Lit(subs[2])))))),
+        Project("sales_out", Sl(Col("sales_s"), 0, limit)),
+        Project("returns_out", Sl(Col("returns_s"), 0, limit)),
+        Project("profit_out", Sl(Col("profit_s"), 0, limit)),
+    ]
+    return StagePlan(
+        name="q5_channels_finish",
+        inputs=(ScanBind("xchg", tuple(ColSpec(c) for c in (
+            "sales", "returns", "profit", "cnt", "of", "pairs")),
+            bucket=False),),
+        nodes=tuple(nodes),
+        outputs=("channel_out", "id_out", "sales_out", "returns_out",
+                 "profit_out", "g_of", "g_pairs"))
+
+
+def q5_channels_pipeline(outlets, ids, item_bits: int,
+                         join_capacity: int, limit: int = 100,
+                         window_days: int = 15) -> Pipeline:
+    return Pipeline(
+        name="q5_channels",
+        stages=(q5_channels_map_plan(outlets, ids, item_bits,
+                                     join_capacity, window_days),
+                q5_channels_finish_plan(ids, limit)),
+        boundaries=(ShuffleBoundary(
+            ("sales", "returns", "profit", "cnt", "of", "pairs")),))
+
+
+def q5_channels_tables(host: dict) -> dict:
+    """A q5 database (``models.tpcds.gen_q5_db``) as the map stage
+    binds it, on the device: each fact padded once to its row bucket
+    with its columns' pad values (``Padded``), the dims as they are."""
+    import jax
+    import numpy as np
+
+    from spark_rapids_tpu.perf.jit_cache import bucket_rows
+    from spark_rapids_tpu.plan.compiler import Padded
+    facts = dict(zip(("ss", "sr", "cs", "cr", "ws", "wr"),
+                     ("store_sales", "store_returns", "catalog_sales",
+                      "catalog_returns", "web_sales", "web_returns")))
+    out = {}
+    for inp in Q5_CHANNEL_INPUTS:
+        if not inp.bucket:
+            continue
+        cols = host[facts[inp.name]]
+        rows = len(cols[0])
+        b = bucket_rows(rows)
+        out[inp.name] = Padded(tuple(
+            jax.device_put(np.concatenate(
+                [c, np.full(b - rows, spec.pad, c.dtype)]))
+            for spec, c in zip(inp.columns, cols)), rows)
+    out["dd"] = (jax.device_put(host["d_date_sk"]),
+                 jax.device_put(host["d_date"]))
+    for dim in _DIM_ID:
+        out[dim] = (jax.device_put(host[dim]),)
+    return out
+
+
+def q5_channels_shape(sizes: dict, ids: dict, window_days: int) -> dict:
+    """The static parameters of a q5 database's plan: outlets a channel
+    (from ``sizes``), business ids a channel (``ids``, by outlet dim),
+    the item key's bits, the probe's capacity (web_returns' row bucket:
+    web_sales' key is unique, so a return finds at most one sale) and
+    the date window's length in days."""
+    from spark_rapids_tpu.perf.jit_cache import bucket_rows
+    return {"outlets": tuple(sizes[dim] for _c, _s, _r, dim in Q5_CHANNELS),
+            "ids": tuple(ids[dim] for _c, _s, _r, dim in Q5_CHANNELS),
+            "item_bits": int(sizes["item"]).bit_length(),
+            "join_capacity": bucket_rows(sizes["web_returns"]),
+            "window_days": int(window_days)}
+
+
+def run_q5_channels(tables: dict, shape: dict, sales_day: int,
+                    limit: int = 100):
+    """q5 over a database held on the device (``q5_channels_tables``)
+    for SALES_DATE ``sales_day`` (days since 1970-01-01): the two-stage
+    pipeline, one executable a stage.  Returns (channel, id, sales,
+    returns, profit) of the first ``limit`` rows (a limit past the
+    rollup's rows serves every row, from one executable), the overflow
+    flag and the probe's true pair count."""
+    import numpy as np
+    limit = min(int(limit), q5_slots(shape["ids"])[1])
+    pipe = compile_pipeline(q5_channels_pipeline(
+        shape["outlets"], shape["ids"], shape["item_bits"],
+        shape["join_capacity"], limit, shape["window_days"]))
+    return pipe.run({**tables, "q": (np.int32(sales_day),)})
 
 # ------------------------------------------------------------------ q72
 

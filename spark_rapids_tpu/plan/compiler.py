@@ -50,6 +50,27 @@ def _canon_dtype(a) -> str:
     return str(canonicalize_dtype(dt))
 
 
+class Padded(tuple):
+    """A bucketed input's columns already padded to their power-of-two
+    row bucket (with each ColSpec's pad value), and the true row count:
+    a table held on the device binds as it is, with no copy a call
+    (models/resident.py)."""
+
+    def __new__(cls, columns, rows: int):
+        self = super().__new__(cls, columns)
+        self.rows = int(rows)
+        return self
+
+
+def _input_rows(arrs) -> int:
+    """True rows of a bound input: a Padded input's count, else the
+    first column's length."""
+    if isinstance(arrs, Padded):
+        return arrs.rows
+    shape = jnp.shape(arrs[0])
+    return int(shape[0]) if shape else 0
+
+
 # -------------------------------------------------------------- evaluation
 
 _BIN = {
@@ -106,6 +127,13 @@ def _eval(e, env):
                          _eval(e.b, env))
     if isinstance(e, ir.Idx):
         return _lookup(e, env)
+    if isinstance(e, ir.IsIn):
+        a, keys = _eval(e.a, env), _eval(e.keys, env)
+        # one elementwise compare a key: the fact stays one flat loop
+        hit = a == keys[0]
+        for k in range(1, keys.shape[0]):
+            hit = hit | (a == keys[k])
+        return hit
     if isinstance(e, ir.Mask):
         return env[f"__mask__{e.input}"]
     if isinstance(e, ir.Arange):
@@ -188,7 +216,7 @@ def _expr_is_bool(e, bool_names=frozenset()) -> bool:
     if isinstance(e, ir.Where):
         return (_expr_is_bool(e.a, bool_names)
                 and _expr_is_bool(e.b, bool_names))
-    if isinstance(e, ir.Mask):
+    if isinstance(e, (ir.Mask, ir.IsIn)):
         return True
     if isinstance(e, ir.Idx):
         return _expr_is_bool(e.src, bool_names)
@@ -406,7 +434,7 @@ class CompiledStage:
                     f"input {inp.name!r} expects {len(inp.columns)} "
                     f"columns, got {len(arrs)}")
             if inp.bucket:
-                b = bucket_rows(int(np.shape(arrs[0])[0]))
+                b = bucket_rows(_input_rows(inputs[inp.name]))
                 max_bucket = max(max_bucket, b)
                 parts.append((",".join(_canon_dtype(a)
                                        for a in arrs), b))
@@ -425,7 +453,16 @@ class CompiledStage:
         cols, nvalids = [], []
         for inp in self.plan.inputs:
             arrs = [jnp.asarray(a) for a in inputs[inp.name]]
-            if inp.bucket:
+            if inp.bucket and isinstance(inputs[inp.name], Padded):
+                rows = inputs[inp.name].rows
+                b = bucket_rows(rows)
+                if any(a.shape[0] != b for a in arrs):
+                    raise ValueError(
+                        f"input {inp.name!r}: padded to "
+                        f"{[a.shape[0] for a in arrs]} rows, bucket {b}")
+                cols.extend(arrs)
+                nvalids.append(jnp.int32(rows))
+            elif inp.bucket:
                 rows = int(arrs[0].shape[0])
                 b = bucket_rows(rows)
                 for spec, a in zip(inp.columns, arrs):
@@ -630,12 +667,13 @@ class CompiledStage:
         out, compile_ns, digest, counts = self._run_fused(
             inputs, taps=taps)
         compiled = bool(compile_ns)
-        with _obs.TRACER.span("device_wait", kind="phase"):
+        with _obs.TRACER.span("device_wait", kind="phase",
+                              attrs={"stage": self.plan.name}):
             jax.block_until_ready(out)
         wall_ns = time.monotonic_ns() - t0
         if span is not _obs.NOOP_SPAN:
             from spark_rapids_tpu.perf.jit_cache import bucket_rows
-            rows = max((int(jnp.shape(inputs[i.name][0])[0])
+            rows = max((_input_rows(inputs[i.name])
                         for i in self.plan.inputs if i.bucket),
                        default=0)
             bucket = bucket_rows(rows) if rows else 0
@@ -682,9 +720,7 @@ class CompiledStage:
             arrs = inputs.get(inp.name)
             if not arrs:
                 continue
-            shape = np.shape(arrs[0])
-            ins.append({"name": inp.name,
-                        "rows": int(shape[0]) if shape else 0})
+            ins.append({"name": inp.name, "rows": _input_rows(arrs)})
             cols[inp.name] = arrs[0]
         return _obs.STATS.note_stage(
             {"stage": self.plan.name,
@@ -748,8 +784,7 @@ class CompiledStage:
             arrs = inputs.get(inp.name)
             if not arrs:
                 continue
-            shape = np.shape(arrs[0])
-            rows = int(shape[0]) if shape else 0
+            rows = _input_rows(arrs)
             bucket = bucket_rows(rows) if inp.bucket else rows
             ins.append({"name": inp.name, "rows": rows,
                         "bucket": bucket,

@@ -132,6 +132,19 @@ class Idx(Expr):
 
 
 @dataclass(frozen=True)
+class IsIn(Expr):
+    """``a IN (keys)``: True where ``a`` equals one of the entries of
+    the short 1-D ``keys`` (a static length, one compare each) — the
+    fact side of a dynamically pruned join, whose dimension was
+    filtered first and handed over its surviving keys."""
+    a: Expr
+    keys: Expr
+
+    def key(self):
+        return f"in({_k(self.a)},{_k(self.keys)})"
+
+
+@dataclass(frozen=True)
 class Mask(Expr):
     """Row-validity of a bucketed input: True for real rows, False for
     the pad tail (``arange(bucket) < n_valid``).  All-true for
