@@ -8,12 +8,18 @@ compiles to one XLA program: static shapes, a caller-chosen pair
 capacity, and a true pair count so overflow is detectable (the same
 fixed-capacity-plus-true-count contract as parallel/exchange.py).
 
-TPU-first shape: both sides sort by key (total-order integer ranks —
-callers canonicalize floats/strings first, as ops/joins does), the
-right side's run for every left row comes from two vectorized
-searchsorteds, and pair slot j reverse-maps to its (left row, offset
-within run) with another searchsorted — no data-dependent loops, no
-dynamic shapes, O(P log N) work for P = capacity.
+TPU-first shape: a merge, not a search.  Keys are total-order integer
+ranks (callers canonicalize floats/strings first, as ops/joins does).
+One sort of both sides' keys puts every left row right behind the
+valid right rows equal to it, so its run ``[lo, hi)`` of matches is
+the count of valid right rows ahead of it (a cumsum) and that count at
+its key's first row (a cummax).  A second sort hands the bounds back
+in left-row order beside the right rows in key order.  Pair slot j
+maps back to its left row by a scatter-max of each row at its first
+slot and a cummax over the slots.  Sorts and scans only: no
+data-dependent loops, no dynamic shapes, no rounds of dependent
+gathers — a binary search's round into HBM costs several times a
+sort's pass per element on a TPU (PERF.md, section 5).
 """
 
 from __future__ import annotations
@@ -21,6 +27,7 @@ from __future__ import annotations
 from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
+from jax import lax
 
 
 class JoinPairs(NamedTuple):
@@ -31,67 +38,92 @@ class JoinPairs(NamedTuple):
     #                               capacity: caller must retry bigger)
 
 
+def merge_run_bounds(left_keys: jnp.ndarray, right_keys: jnp.ndarray,
+                     right_valid: jnp.ndarray | None = None
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Each left key's run among the valid right keys, by merge.
+
+    Returns ``(lo, hi, r_order)``: ``r_order`` (nr,) int32 lists the
+    valid right rows in (key, row) order, then the invalid ones, and
+    ``r_order[lo[i]:hi[i]]`` (int32 bounds) are the valid right rows
+    whose key equals ``left_keys[i]``, in row order.  Rows with
+    ``right_valid`` False match nothing, whatever their key."""
+    nl, nr = left_keys.shape[0], right_keys.shape[0]
+    n = nl + nr
+    keys = jnp.concatenate([right_keys.astype(jnp.int64),
+                            left_keys.astype(jnp.int64)])
+    # unique tags: valid right rows 0..nr-1, left rows nr..n-1, invalid
+    # right rows n..n+nr-1 — so at equal keys a left row has every valid
+    # right row of its key ahead of it and no invalid one
+    r_row = lax.iota(jnp.int32, nr)
+    if right_valid is not None:
+        r_row = jnp.where(right_valid, r_row, n + r_row)
+    tag = jnp.concatenate([r_row, nr + lax.iota(jnp.int32, nl)])
+    skey, stag = lax.sort((keys, tag), num_keys=2)
+
+    is_vr = stag < nr
+    is_left = (stag >= nr) & (stag < n)
+    vr = is_vr.astype(jnp.int32)
+    hi = lax.cumsum(vr) - vr            # valid right rows ahead
+    # lo is hi at the key's first row; hi never falls, so a cummax
+    # carries it along the run (the first row compares with itself and
+    # is no start, which changes nothing: hi is 0 there)
+    prev = jnp.concatenate([skey[:1], skey[:-1]])
+    lo = lax.cummax(jnp.where(skey != prev, hi, 0))
+
+    # left rows first in row order, then the valid right rows in key
+    # order, then the invalid ones
+    order = jnp.where(is_left, stag - nr,
+                      jnp.where(is_vr, nl + lax.iota(jnp.int32, n),
+                                nl + stag))
+    row = jnp.where(stag >= n, stag - n, stag)
+    _, a, b = lax.sort((order, jnp.where(is_left, lo, row), hi),
+                       num_keys=1)
+    return a[:nl], b[:nl], a[nl:]
+
+
 def inner_join_device(left_keys: jnp.ndarray, right_keys: jnp.ndarray,
                       capacity: int,
                       left_valid: jnp.ndarray | None = None,
                       right_valid: jnp.ndarray | None = None
                       ) -> JoinPairs:
     """Jittable inner join on integer key arrays (join_primitives.hpp
-    sort_merge_inner_join contract, device-resident).  Rows with
-    valid=False never match (NULL-inequality semantics; encode
-    null-equals by mapping nulls to a shared sentinel key AND a
+    sort_merge_inner_join contract, device-resident).  Pairs fill the
+    slots in left-row order, each left row's matches in right-row
+    order, and stop at ``capacity``; empty slots are 0 and not valid.
+    Rows with valid=False never match (NULL-inequality semantics;
+    encode null-equals by mapping nulls to a shared sentinel key AND a
     dedicated validity column upstream, as ops/joins._key_ids does)."""
     nl = left_keys.shape[0]
     nr = right_keys.shape[0]
-    lk = left_keys.astype(jnp.int64)
-    rk = right_keys.astype(jnp.int64)
-    if left_valid is None:
-        left_valid = jnp.ones(nl, jnp.bool_)
-    if right_valid is None:
-        right_valid = jnp.ones(nr, jnp.bool_)
-
     if nl == 0 or nr == 0:
         z = jnp.zeros(capacity, jnp.int32)
         return JoinPairs(z, z, jnp.zeros(capacity, jnp.bool_),
                          jnp.int64(0))
 
-    # sort right by (invalid, key): invalid rows go last and are excluded
-    # from every searched run by searching only the valid prefix
-    # (lexsort's primary key is the LAST entry).  Invalid keys map to
-    # INT64_MAX so rk_sorted stays globally ascending — searchsorted
-    # requires it; the n_valid_r clip below breaks the tie when valid
-    # keys legitimately equal INT64_MAX.
-    from jax import lax
-
-    r_sortkey = jnp.where(right_valid, rk, jnp.int64(2**63 - 1))
-    # one lax.sort delivers the sorted keys AND the permutation: keys
-    # (invalid-last, key, iota-for-stability); rk_sorted stays globally
-    # ascending because invalid keys are already INT64_MAX
-    _, rk_sorted, r_order = lax.sort(
-        ((~right_valid).astype(jnp.int32), r_sortkey,
-         lax.iota(jnp.int32, nr)), num_keys=3)
-    n_valid_r = jnp.sum(right_valid.astype(jnp.int32))
-
-    # run bounds for each left key within the valid prefix
-    lo = jnp.searchsorted(rk_sorted, lk, side="left")
-    hi = jnp.searchsorted(rk_sorted, lk, side="right")
-    lo = jnp.minimum(lo, n_valid_r)
-    hi = jnp.minimum(hi, n_valid_r)
+    lo, hi, r_order = merge_run_bounds(left_keys, right_keys, right_valid)
     # pair accounting is int64: two 64k-row sides sharing one key are
     # 2^32 pairs, which would wrap int32 and defeat overflow detection
-    counts = jnp.where(left_valid, hi - lo, 0).astype(jnp.int64)
+    counts = (hi - lo).astype(jnp.int64)
+    if left_valid is not None:
+        counts = jnp.where(left_valid, counts, 0)
 
     offs = jnp.cumsum(counts) - counts          # exclusive prefix sum
     total = offs[-1] + counts[-1]
 
-    # reverse map: pair slot j -> left row i with offs[i] <= j < offs[i+1]
+    # slot map: pair slot j -> left row i with offs[i] <= j < offs[i+1].
+    # Each row with pairs marks its first slot; the cummax carries it
+    # over the row's run (rows without pairs share a neighbour's offset
+    # and mark nothing)
+    first = jnp.where((counts > 0) & (offs < capacity), offs,
+                      capacity).astype(jnp.int32)
+    i = lax.cummax(jnp.zeros(capacity, jnp.int32).at[first].max(
+        lax.iota(jnp.int32, nl), mode="drop"))
     j = jnp.arange(capacity, dtype=jnp.int64)
-    i = jnp.searchsorted(offs, j, side="right").astype(jnp.int32) - 1
-    i = jnp.clip(i, 0, nl - 1)
     k = j - offs[i]
     valid = (j < total) & (k < counts[i])
     r_pos = jnp.clip(lo[i] + k, 0, nr - 1)
-    right_idx = r_order[r_pos].astype(jnp.int32)
-    return JoinPairs(jnp.where(valid, i, 0).astype(jnp.int32),
+    right_idx = r_order[r_pos]
+    return JoinPairs(jnp.where(valid, i, 0),
                      jnp.where(valid, right_idx, 0),
                      valid, total)

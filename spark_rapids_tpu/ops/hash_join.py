@@ -32,8 +32,8 @@ Three engines share that skeleton:
     (``direct`` sub-path).
 
 ``device`` (XLA)
-    The same hash ids drive ops/device_join.inner_join_device (sort +
-    searchsorted run expansion) inside ONE compiled program per
+    The same hash ids drive ops/device_join.inner_join_device (sort-
+    merge run expansion) inside ONE compiled program per
     (schema digest, row buckets, capacity): fixed-capacity pair slots
     with a true count, equality verification fused into the program,
     and the pair capacity doubling under the SAME

@@ -385,7 +385,7 @@ def _device_ids(left: Table, right: Table, compare_nulls: str):
     """Per-row equality ids over the joined key columns.  Eager key
     prep (string pad widths are data-dependent) + one jitted sorted-gid
     program.  The join core only needs an injective int64 key (it sorts
-    + searchsorts), so a single all-valid fixed-width key column IS its
+    and merges), so a single all-valid fixed-width key column IS its
     own id — no sort at all; multi-column encodings (strings as packed
     words + length, decimal128 as limb words) and nullable keys pay for
     the sorted-gid pass."""
@@ -422,15 +422,11 @@ def _device_ids(left: Table, right: Table, compare_nulls: str):
 
 @jax.jit
 def _device_join_total(lid, rid, lval, rval):
-    """Count-only half of inner_join_device: sort + two searchsorteds
-    (no reverse map, no pair expansion)."""
-    r_sortkey = jnp.where(rval, rid, jnp.int64(2**63 - 1))
-    rk_sorted = jnp.sort(r_sortkey)
-    n_valid_r = jnp.sum(rval.astype(jnp.int32))
-    lo = jnp.minimum(jnp.searchsorted(rk_sorted, lid, side="left"),
-                     n_valid_r)
-    hi = jnp.minimum(jnp.searchsorted(rk_sorted, lid, side="right"),
-                     n_valid_r)
+    """Count-only half of inner_join_device: the same merged run
+    bounds (no slot map, no pair expansion)."""
+    from spark_rapids_tpu.ops.device_join import merge_run_bounds
+
+    lo, hi, _ = merge_run_bounds(lid, rid, rval)
     counts = jnp.where(lval, hi - lo, 0).astype(jnp.int64)
     return jnp.sum(counts)
 

@@ -216,11 +216,13 @@ class Project(Node):
 @dataclass(frozen=True)
 class JoinProbe(Node):
     """Fixed-capacity device inner-join probe
-    (ops/device_join.inner_join_device — the PR-9 device engine,
-    inlined instead of round-tripped).  Defines ``<p>.li`` ``<p>.ri``
-    (int32 pair indices), ``<p>.valid`` (bool per slot) and
-    ``<p>.total`` (int64 TRUE pair count; ``total > capacity`` is the
-    overflow signal the capacity-retry driver doubles on)."""
+    (ops/device_join.inner_join_device, inlined instead of
+    round-tripped: two sorts of both sides' keys merge each left row
+    with its run of right rows, scans give the bounds).  Defines
+    ``<p>.li`` ``<p>.ri`` (int32 pair indices), ``<p>.valid`` (bool per
+    slot) and ``<p>.total`` (int64 TRUE pair count; ``total >
+    capacity`` is the overflow signal the capacity-retry driver doubles
+    on)."""
     prefix: str
     left: Expr
     right: Expr

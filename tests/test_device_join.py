@@ -2,6 +2,8 @@
 (device_join.py, models/distributed_join.py) against brute-force
 oracles on the 8-device CPU mesh."""
 
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,6 +55,113 @@ def test_inner_join_device_edges():
     out = inner_join_device(big, big, 16,
                             right_valid=jnp.asarray([False, True]))
     assert int(out.total) == 1
+
+
+INT64_MAX = 2**63 - 1
+
+
+def _contract(lk, rk, lval, rval, capacity):
+    """The probe's contract as plain numpy: pairs in left-row order,
+    each run in right-row order, cut at ``capacity``; empty slots 0."""
+    li, ri = [], []
+    for i in range(len(lk)):
+        if lval[i]:
+            js = np.nonzero(rval & (rk == lk[i]))[0]
+            li += [i] * len(js)
+            ri += js.tolist()
+    m = min(len(li), capacity)
+    out_l = np.zeros(capacity, np.int32)
+    out_r = np.zeros(capacity, np.int32)
+    out_l[:m], out_r[:m] = li[:m], ri[:m]
+    return out_l, out_r, np.arange(capacity) < m, len(li)
+
+
+def _case(name):
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    if name == "duplicates_both_sides":
+        lk, rk = rng.integers(0, 8, 60), rng.integers(0, 8, 90)
+        lval, rval, cap = np.ones(60, bool), np.ones(90, bool), 1024
+    elif name == "unique_right_q5_shape":
+        # 48 returns into 384 sales (n_r = 8 n_l) on 37-bit keys, both
+        # padded to a bucket with side-specific sentinels masked off
+        sales = rng.choice(1 << 37, 384, replace=False)
+        lk = np.concatenate([rng.choice(sales[:360], 48, replace=False),
+                             np.full(16, -1)])
+        rk = np.concatenate([sales[:360], np.full(24, -2)])
+        lval = np.arange(64) < 48
+        rval = np.arange(384) < 360
+        cap = 64
+    elif name == "invalid_rows_each_side":
+        lk, rk = rng.integers(0, 30, 100), rng.integers(0, 30, 140)
+        lval, rval = rng.random(100) < 0.7, rng.random(140) < 0.6
+        cap = 512
+    elif name == "int64_max_valid_and_invalid":
+        lk = rng.choice([INT64_MAX, -2**63, 0, 5], 40)
+        rk = rng.choice([INT64_MAX, -2**63, 0, 7], 50)
+        lval, rval = rng.random(40) < 0.8, rng.random(50) < 0.7
+        cap = 1024
+    elif name == "total_over_capacity":
+        lk, rk = rng.integers(0, 3, 70), rng.integers(0, 3, 80)
+        lval, rval = rng.random(70) < 0.9, rng.random(80) < 0.9
+        cap = 100
+    elif name == "empty_left":
+        lk, rk = np.zeros(0, np.int64), rng.integers(0, 4, 9)
+        lval, rval, cap = np.zeros(0, bool), np.ones(9, bool), 16
+    else:                                   # empty_right
+        lk, rk = rng.integers(0, 4, 9), np.zeros(0, np.int64)
+        lval, rval, cap = np.ones(9, bool), np.zeros(0, bool), 16
+    return lk.astype(np.int64), rk.astype(np.int64), lval, rval, cap
+
+
+CASES = ("duplicates_both_sides", "unique_right_q5_shape",
+         "invalid_rows_each_side", "int64_max_valid_and_invalid",
+         "total_over_capacity", "empty_left", "empty_right")
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_inner_join_device_contract_bit_identical(name):
+    lk, rk, lval, rval, cap = _case(name)
+    want = _contract(lk, rk, lval, rval, cap)
+    out = jax.jit(lambda a, b, c, d: inner_join_device(a, b, cap, c, d))(
+        jnp.asarray(lk), jnp.asarray(rk), jnp.asarray(lval),
+        jnp.asarray(rval))
+    assert np.asarray(out.left_indices).dtype == np.int32
+    assert np.asarray(out.right_indices).dtype == np.int32
+    assert np.array_equal(np.asarray(out.left_indices), want[0])
+    assert np.array_equal(np.asarray(out.right_indices), want[1])
+    assert np.array_equal(np.asarray(out.valid), want[2])
+    assert out.total.dtype == jnp.int64 and int(out.total) == want[3]
+    if name == "total_over_capacity":
+        assert want[3] > cap
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_device_join_total_contract(name):
+    from spark_rapids_tpu.ops.joins import _device_join_total
+
+    lk, rk, lval, rval, cap = _case(name)
+    got = _device_join_total(jnp.asarray(lk), jnp.asarray(rk),
+                             jnp.asarray(lval), jnp.asarray(rval))
+    assert got.dtype == jnp.int64
+    assert int(got) == _contract(lk, rk, lval, rval, cap)[3]
+
+
+def test_inner_join_device_has_no_loop():
+    """The probe is sorts and scans: no binary search's loop of
+    dependent gathers is left in the program."""
+    def primitives(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from primitives(sub)
+
+    jaxpr = jax.make_jaxpr(
+        lambda a, b, c, d: inner_join_device(a, b, 1 << 12, c, d))(
+        jnp.zeros(1 << 12, jnp.int64), jnp.zeros(1 << 15, jnp.int64),
+        jnp.ones(1 << 12, jnp.bool_), jnp.ones(1 << 15, jnp.bool_))
+    names = set(primitives(jaxpr.jaxpr))
+    assert "sort" in names
+    assert not names & {"while", "scan"}
 
 
 @pytest.fixture(scope="module")
