@@ -66,6 +66,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "srt_hbm_bytes_in_use": ("gauge", "backend-reported HBM in use"),
     "srt_exchange_capacity_doublings_total": (
         "counter", "exchange capacity-retry doublings"),
+    "srt_exchange_rows_total": (
+        "counter", "rows sent by a stage's hash exchange, by table"),
     "srt_journal_dropped_total": (
         "counter", "journal events lost to ring wrap"),
     "srt_retry_episodes_total": ("counter", "failed retry episodes"),

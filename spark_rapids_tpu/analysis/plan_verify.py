@@ -372,6 +372,8 @@ def verify_stage(plan: ir.StagePlan,
         for e in _node_exprs(node):
             _check_expr_ops(name, label, e)
             _expr_refs(e, refs)
+        if isinstance(node, ir.Exchange):
+            refs += [("col", c) for c in node.columns]
         for kind, ref in refs:
             if kind == "mask":
                 if ref not in input_names:
@@ -388,6 +390,10 @@ def verify_stage(plan: ir.StagePlan,
             raise PlanVerifyError(
                 name, label,
                 f"non-positive join capacity {node.capacity}")
+        if isinstance(node, ir.Exchange) and node.capacity < 1:
+            raise PlanVerifyError(
+                name, label,
+                f"non-positive exchange capacity {node.capacity}")
         if isinstance(node, ir.SegmentSum) and node.num_segments < 1:
             raise PlanVerifyError(
                 name, label,
@@ -435,6 +441,13 @@ def verify_stage(plan: ir.StagePlan,
                 env[f"{p}.li"] = env[f"{p}.ri"] = "int32"
                 env[f"{p}.valid"] = "bool"
                 env[f"{p}.total"] = "int64"
+            elif isinstance(node, ir.Exchange):
+                for k in node.keys:
+                    _require_int(name, label, "partition key",
+                                 _expr_dtype(name, label, k, env))
+                for c in node.columns:
+                    env[f"{node.prefix}.{c}"] = env[c]
+                env[f"{node.prefix}.valid"] = "bool"
             elif isinstance(node, ir.SegmentSum):
                 _require_int(name, label, "segment ids",
                              _expr_dtype(name, label, node.ids, env))

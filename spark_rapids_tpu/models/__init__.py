@@ -361,33 +361,78 @@ def _run_q72_fused(params: dict, ctx: QueryContext):
     return _rows(i, w, c)
 
 
+def _q5_mesh_run(shape: dict, limit: int, mesh):
+    """q5 over a mesh through the exchange's capacity retry: a run
+    whose exchange sent a chip more rows than a slot holds is run again
+    at twice the slots.  ``run(tables, day)`` returns (outputs, send
+    counts by table, overflowed) and web_sales' slot."""
+    from spark_rapids_tpu.parallel.exchange import with_capacity_retry
+    from spark_rapids_tpu.plan import catalog as plan_catalog
+
+    def make_step(capacity):
+        slots = dict(plan_catalog.q5_exchange_slots(shape, capacity)[0])
+
+        def step(tables, day):
+            *out, sent = plan_catalog.run_q5_channels(
+                tables, shape, day, limit, mesh=mesh, capacity=capacity)
+            over = any(int(sent[t].max()) > slots[t] for t in sent)
+            return out, sent, over
+        return step
+
+    return with_capacity_retry(make_step, shape["exchange_slots"][-1][1])
+
+
 def _run_q5_channels(params: dict, ctx: QueryContext):
     """TPC-DS q5 as its template writes it (three channels, the web
     returns-to-sales join, ROLLUP) over a database held on the device:
     ``sizes`` and ``db_seed`` name the database (SF10 by default), which
     the first query loads into ``resident.REGISTRY`` and later queries
     bind to; ``sales_date`` and ``limit`` (the template's _LIMIT, 100
-    by default) are the substitutions.  Rows are (channel, id, sales,
-    returns, profit), NULL as -1."""
+    by default) are the substitutions.  ``chips`` (1 by default) shards
+    the database over the first ``chips`` devices, one executor a chip,
+    and the web join's sides meet through Spark's hash exchange over
+    them.  Rows are (channel, id, sales, returns, profit), NULL as
+    -1."""
+    import jax
     import numpy as np
+    from jax.sharding import Mesh
 
     from spark_rapids_tpu.models import resident, tpcds
     from spark_rapids_tpu.plan import catalog as plan_catalog
     ctx.check_cancel()
     sizes = tpcds.q5_sizes(params.get("sizes"))
     db_seed = int(params.get("db_seed", 5))
+    chips = int(params.get("chips", 1))
     day = tpcds.q5_day(params.get("sales_date", tpcds.Q5_SALES_DATE))
+    mesh = None
+    if chips > 1:
+        if chips > jax.device_count():
+            raise ValueError(f"q5 over {chips} chips: {jax.device_count()} "
+                             f"devices")
+        mesh = Mesh(np.array(jax.devices()[:chips]), ("data",))
     tables = resident.REGISTRY.get(
-        ("tpcds_q5", tuple(sorted(sizes.items())), db_seed),
+        ("tpcds_q5", tuple(sorted(sizes.items())), db_seed, chips),
         lambda: plan_catalog.q5_channels_tables(
-            tpcds.gen_q5_db(sizes, db_seed)))
+            tpcds.gen_q5_db(sizes, db_seed), mesh))
     shape = plan_catalog.q5_channels_shape(
-        sizes, tpcds.q5_dim_ids(sizes), tpcds.Q5_WINDOW_DAYS)
+        sizes, tpcds.q5_dim_ids(sizes), tpcds.Q5_WINDOW_DAYS, chips)
     limit = int(params.get("limit", tpcds.Q5_LIMIT))
     # _execute's span, with the probe's true pair count on it
     with ctx.phase("execute", path="stage") as span:
-        *rows, of, pairs = plan_catalog.run_q5_channels(tables, shape,
-                                                        day, limit)
+        if mesh is None:
+            *rows, of, pairs = plan_catalog.run_q5_channels(tables, shape,
+                                                            day, limit)
+        else:
+            run = _pipeline(
+                ("q5_channels_mesh", tuple(sorted(shape.items())), limit),
+                lambda: _q5_mesh_run(shape, limit, mesh))
+            (out, sent, _over), capacity = run(tables, day)
+            *rows, of, pairs = out
+            for table, counts in sent.items():
+                _obs.record_exchange_rows(table, int(counts.sum()))
+            span.set_attr("exchange_capacity", capacity)
+            span.set_attr("exchange_max_dest_rows",
+                          max(int(c.max()) for c in sent.values()))
         span.set_attr("join_pairs", int(np.asarray(pairs)))
     if bool(np.asarray(of)):
         raise RuntimeError("q5 capacity overflow: a web sale key "

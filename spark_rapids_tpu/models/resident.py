@@ -23,7 +23,7 @@ from typing import Callable, Dict, Hashable
 from spark_rapids_tpu import observability as _obs
 
 # half of one v5e's 16 GB: a database and the working sets of the
-# queries beside it
+# queries beside it, counted on the fullest device (table_bytes)
 DEFAULT_BUDGET_BYTES = 8 << 30
 
 
@@ -32,9 +32,23 @@ def _leaves(tables: Dict[str, object]):
         yield from (value if isinstance(value, tuple) else (value,))
 
 
+def _device_bytes(a) -> int:
+    """Bytes of ``a`` on its most-loaded device: a shard of an array
+    sharded over devices, the whole of one replicated or on one."""
+    shards = getattr(a, "addressable_shards", None)
+    if not shards:
+        return int(getattr(a, "nbytes", 0))
+    per = {}
+    for s in shards:
+        per[s.device] = per.get(s.device, 0) + int(s.data.nbytes)
+    return max(per.values())
+
+
 def table_bytes(tables: Dict[str, object]) -> int:
-    """Device bytes of a database: every array it holds, padding too."""
-    return sum(int(getattr(a, "nbytes", 0)) for a in _leaves(tables))
+    """Device bytes of a database, padding too, as one device holds
+    them: each array's bytes on its most-loaded device, so a database
+    sharded over chips is held against the budget of one."""
+    return sum(_device_bytes(a) for a in _leaves(tables))
 
 
 def table_rows(tables: Dict[str, object]) -> int:

@@ -169,6 +169,10 @@ HBM_BYTES_IN_USE = METRICS.gauge(
 EXCHANGE_DOUBLINGS = METRICS.counter(
     "srt_exchange_capacity_doublings_total",
     "ICI exchange capacity-retry doublings")
+EXCHANGE_ROWS = METRICS.counter(
+    "srt_exchange_rows_total",
+    "Rows a stage's hash Exchange sent over the mesh (every chip, every "
+    "destination, its own included), by table", labels=("table",))
 JOURNAL_DROPPED_TOTAL = METRICS.counter(
     "srt_journal_dropped_total",
     "Journal events overwritten by ring wrap-around (counted at emit)")
@@ -1473,6 +1477,14 @@ def record_exchange_doubling(from_capacity: int, to_capacity: int,
     EXCHANGE_DOUBLINGS.inc()
     JOURNAL.emit("exchange_capacity_doubling", from_capacity=from_capacity,
                  to_capacity=to_capacity, attempt=attempt)
+
+
+def record_exchange_rows(table: str, rows: int) -> None:
+    """Exchange hook (the catalog's mesh runner): ``rows`` of ``table``
+    were sent by one run's hash Exchange, read from the executable's
+    send counts."""
+    if _SWITCH.enabled:
+        EXCHANGE_ROWS.inc(rows, labels=(table,))
 
 
 def record_device_memory(allocated_bytes: int) -> None:

@@ -31,14 +31,11 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from spark_rapids_tpu import observability as _obs
 
 _I32 = jnp.int32
-
-# counting-sort rank working-set cap: the (rows, n_parts) int32 cumsum
-# beyond this falls back to the stable-argsort layout
-_COUNTING_SORT_MAX_BYTES = 64 << 20
 
 
 def build_padded_sends(arrays: Sequence[jnp.ndarray], part: jnp.ndarray,
@@ -46,43 +43,59 @@ def build_padded_sends(arrays: Sequence[jnp.ndarray], part: jnp.ndarray,
     """Pack rows into per-destination padded slots.
 
     arrays: per-column row-major arrays (rows, ...) sharing axis 0.
-    part:   (rows,) int32 destination partition per row.
+    part:   (rows,) int32 destination partition per row, in
+            ``[0, n_parts]``: ``n_parts`` sends the row nowhere (a pad
+            row of a bucketed shard).
     Returns (sends, counts): sends[i] has shape (n_parts, capacity, ...);
     counts is (n_parts,) true row counts (may exceed capacity — caller
-    checks)."""
-    # stable counting sort (ISSUE 9 satellite): partition ids are small
-    # ints, so the within-partition rank is one (rows, n_parts) one-hot
-    # cumsum — O(n * n_parts) elementwise work instead of the
-    # O(n log n) comparator sort jnp.argsort paid on every exchange.
-    # No explicit reorder is even needed: (partition, rank) slots are
-    # unique, so each row scatters straight to its padded slot, and the
-    # receive-side (src, slot) order is byte-identical to the old
-    # argsort layout (rank == stable sorted position within partition).
-    # The (rows, n_parts) int32 cumsum is the working set; past a
-    # budget it would dwarf the row data, so huge shards keep the
-    # argsort layout (identical (partition, rank) slots either way).
+    checks).
+
+    Slot (p, r) holds the r-th row, in row order, of those bound for
+    p, for r under ``min(counts[p], capacity)``, and zeros past it.
+    The layout is ONE stable ``lax.sort`` on the destination with the
+    columns as payloads, then ``n_parts`` slices of ``capacity`` rows
+    at each destination's start: a (partition, rank) slot is the
+    row's stable sorted position.  No scatter: on a TPU a scatter
+    costs some 113 ns an element (PERF.md, PR 28), a sort with its
+    payloads some 7.5 (PR 39)."""
     pi = part.astype(_I32)
-    rows = int(pi.shape[0])
-    if rows * max(n_parts, 1) * 4 <= _COUNTING_SORT_MAX_BYTES:
-        onehot = pi[:, None] == jnp.arange(n_parts, dtype=_I32)[None, :]
-        rank = jnp.take_along_axis(
-            jnp.cumsum(onehot.astype(_I32), axis=0),
-            jnp.clip(pi, 0, n_parts - 1)[:, None], axis=1)[:, 0] - 1
-        counts = jnp.sum(onehot, axis=0, dtype=_I32)
-    else:
-        order = jnp.argsort(pi)          # jnp.argsort is stable
-        p_sorted = pi[order]
-        counts = jnp.bincount(pi, length=n_parts).astype(_I32)
-        starts = jnp.concatenate(
-            [jnp.zeros(1, _I32), jnp.cumsum(counts)[:-1].astype(_I32)])
-        rank_sorted = jnp.arange(rows, dtype=_I32) - starts[p_sorted]
-        rank = jnp.zeros(rows, _I32).at[order].set(rank_sorted)
-    slot = jnp.where(rank < capacity, rank, capacity)  # overflow -> dropped
-    sends = []
-    for a in arrays:
-        buf = jnp.zeros((n_parts, capacity) + a.shape[1:], a.dtype)
-        sends.append(buf.at[pi, slot].set(a, mode="drop"))
+    counts = jnp.sum(pi[:, None] == jnp.arange(n_parts, dtype=_I32),
+                     axis=0, dtype=_I32)
+    starts = jnp.cumsum(counts) - counts
+    flat = [a.reshape(a.shape[0], -1) for a in arrays]
+    widths = [f.shape[1] for f in flat]
+    cols = [f[:, j] for f in flat for j in range(f.shape[1])]
+    cols = lax.sort((pi,) + tuple(cols), num_keys=1, is_stable=True)[1:]
+    live = jnp.arange(capacity, dtype=_I32)[None, :] < jnp.minimum(
+        counts, capacity)[:, None]
+    sends, at = [], 0
+    for a, w in zip(arrays, widths):
+        packed = []
+        for c in cols[at:at + w]:
+            c = jnp.concatenate([c, jnp.zeros(capacity, c.dtype)])
+            block = jnp.stack([lax.dynamic_slice(c, (starts[p],),
+                                                 (capacity,))
+                               for p in range(n_parts)])
+            packed.append(jnp.where(live, block, jnp.zeros((), c.dtype)))
+        at += w
+        sends.append(jnp.stack(packed, axis=-1).reshape(
+            (n_parts, capacity) + a.shape[1:]))
     return sends, counts
+
+
+def hash_partitions(keys: Sequence[jnp.ndarray], valid: jnp.ndarray,
+                    n_parts: int) -> jnp.ndarray:
+    """Spark's ``HashPartitioning(keys, n_parts)``: ``pmod(murmur3(keys,
+    seed 42), n_parts)`` per row (``ops/hash.py``'s Spark-exact kernel,
+    each key hashed as its own type, the seed chained across them), and
+    ``n_parts`` (sent nowhere) where ``valid`` is False."""
+    from spark_rapids_tpu.columns import dtypes
+    from spark_rapids_tpu.columns.column import Column
+    from spark_rapids_tpu.ops.hash import murmur3_32
+    rows = int(keys[0].shape[0])
+    h = murmur3_32([Column(dtypes.from_numpy(k.dtype), rows, data=k)
+                    for k in keys]).data
+    return jnp.where(valid, h % n_parts, n_parts).astype(_I32)
 
 
 def exchange(arrays: Sequence[jnp.ndarray], part: jnp.ndarray,

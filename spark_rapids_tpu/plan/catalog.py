@@ -19,6 +19,9 @@ pipelines compiled through plan/compiler.py:
                  channels' date filter, sums and web join probe, then
                  ROLLUP(channel, id), NULLS FIRST, LIMIT 100; its
                  sides share ``_fact_side`` with the store-channel q5;
+                 over a mesh the database is sharded, the web join's
+                 sides meet through a hash ``Exchange`` and the whole
+                 pipeline is one executable (``MeshPipeline``);
   * q67-shape  — GROUP BY ROLLUP(category, class) + rank() OVER
                  (PARTITION BY category ORDER BY sales DESC): the new
                  Rollup and WindowRank nodes (real q67 uses exactly
@@ -37,11 +40,13 @@ reach an aggregate.
 
 from __future__ import annotations
 
+from typing import Optional
+
 from spark_rapids_tpu.plan.compiler import (compile_pipeline,
                                             compile_stage,
                                             fused_pipeline_fn)
-from spark_rapids_tpu.plan.ir import (Arange, Bin, Col, ColSpec, Idx,
-                                      IsIn, JoinProbe, Lit, Mask,
+from spark_rapids_tpu.plan.ir import (Arange, Bin, Col, ColSpec, Exchange,
+                                      Idx, IsIn, JoinProbe, Lit, Mask,
                                       Pipeline, Project, Reduce, Rollup,
                                       ScanBind, SegmentSum,
                                       ShuffleBoundary, Sl, Sort,
@@ -289,7 +294,8 @@ def q5_slots(ids):
 
 def q5_channels_map_plan(outlets, ids, item_bits: int,
                          join_capacity: int,
-                         window_days: int = 15) -> StagePlan:
+                         window_days: int = 15, *,
+                         exchange_slots) -> StagePlan:
     """q5's map side, all three channels in one stage.
 
     * date_dim is filtered first (``d_date`` between SALES_DATE and
@@ -297,8 +303,11 @@ def q5_channels_map_plan(outlets, ids, item_bits: int,
       over its surviving keys, as Spark's dynamic pruning does; each
       fact keeps the rows whose date key is among them (``IsIn``);
     * web returns find their sale through a JoinProbe on the packed
-      key (order number << ``item_bits``) | item over the whole of
-      web_sales, and take the sale's site;
+      key (order number << ``item_bits``) | item over web_sales, and
+      take the sale's site; both sides reach the probe through an
+      ``Exchange`` on (item, order number), as Spark's sort-merge join
+      does: nothing on one chip, an all-to-all on a mesh, whose slots
+      are ``exchange_slots`` ((table, rows), web_returns first);
     * each fact side sums into its outlets (``_fact_side``), and each
       channel's outlets fold through the outlet dim's business ids into
       the one group table of ``q5_slots`` — the UNION ALL of the
@@ -329,13 +338,24 @@ def q5_channels_map_plan(outlets, ids, item_bits: int,
         nodes.append(Project(f"{side}_key", Bin(
             "add", Bin("mul", Un("i64", Col(f"{side}_order")), shift),
             Un("i64", Col(f"{side}_item")))))
+    # Spark's plan for the web join: both sides behind Exchange
+    # hashpartitioning(item, order number); on one chip it is nothing
+    wr_slot, ws_slot = (s for _t, s in exchange_slots)
+    nodes += [
+        Exchange("web_returns", (Col("wr_item"), Col("wr_order")),
+                 ("wr_key", "wr_date", "wr_amt", "wr_loss"), Mask("wr"),
+                 wr_slot),
+        Exchange("web_sales", (Col("ws_item"), Col("ws_order")),
+                 ("ws_key", "ws_outlet"), Mask("ws"), ws_slot),
+    ]
     li, ri = Col("wj.li"), Col("wj.ri")
     nodes += [
-        JoinProbe("wj", Col("wr_key"), Col("ws_key"), join_capacity,
-                  left_valid=Mask("wr"), right_valid=Mask("ws")),
+        JoinProbe("wj", Col("web_returns.wr_key"), Col("web_sales.ws_key"),
+                  join_capacity, left_valid=Col("web_returns.valid"),
+                  right_valid=Col("web_sales.valid")),
         Project("wr_keep", _and(
             Col("wj.valid"),
-            IsIn(Idx(Col("wr_date"), li), Col("win_keys")),
+            IsIn(Idx(Col("web_returns.wr_date"), li), Col("win_keys")),
             Col("win_any"))),
     ]
     totals = {"sales": [], "returns": [], "profit": [], "cnt": []}
@@ -348,8 +368,9 @@ def q5_channels_map_plan(outlets, ids, item_bits: int,
         if ret == "wr":
             nodes += _fact_side(
                 ret, Col("wr_keep"),
-                Bin("sub", Idx(Col("ws_outlet"), ri), Lit(1)),
-                Idx(Col("wr_amt"), li), Idx(Col("wr_loss"), li), n_out)
+                Bin("sub", Idx(Col("web_sales.ws_outlet"), ri), Lit(1)),
+                Idx(Col("web_returns.wr_amt"), li),
+                Idx(Col("web_returns.wr_loss"), li), n_out)
         else:
             nodes += _fact_side(ret, Col(f"{ret}_keep"),
                                 Bin("sub", Col(f"{ret}_outlet"), Lit(1)),
@@ -451,74 +472,143 @@ def q5_channels_finish_plan(ids, limit: int = 100) -> StagePlan:
 
 def q5_channels_pipeline(outlets, ids, item_bits: int,
                          join_capacity: int, limit: int = 100,
-                         window_days: int = 15) -> Pipeline:
+                         window_days: int = 15, *,
+                         exchange_slots) -> Pipeline:
     return Pipeline(
         name="q5_channels",
         stages=(q5_channels_map_plan(outlets, ids, item_bits,
-                                     join_capacity, window_days),
+                                     join_capacity, window_days,
+                                     exchange_slots=exchange_slots),
                 q5_channels_finish_plan(ids, limit)),
         boundaries=(ShuffleBoundary(
             ("sales", "returns", "profit", "cnt", "of", "pairs")),))
 
 
-def q5_channels_tables(host: dict) -> dict:
+_Q5_FACT_TABLES = dict(zip(("ss", "sr", "cs", "cr", "ws", "wr"),
+                           ("store_sales", "store_returns", "catalog_sales",
+                            "catalog_returns", "web_sales", "web_returns")))
+
+
+def q5_channels_tables(host: dict, mesh=None) -> dict:
     """A q5 database (``models.tpcds.gen_q5_db``) as the map stage
     binds it, on the device: each fact padded once to its row bucket
-    with its columns' pad values (``Padded``), the dims as they are."""
+    with its columns' pad values (``Padded``), the dims as they are.
+    On a ``mesh`` each device holds one contiguous shard of every fact
+    (as a scan of files splits a table among executors), each padded
+    to the bucket of the largest, and the dims whole."""
     import jax
     import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from spark_rapids_tpu.perf.jit_cache import bucket_rows
     from spark_rapids_tpu.plan.compiler import Padded
-    facts = dict(zip(("ss", "sr", "cs", "cr", "ws", "wr"),
-                     ("store_sales", "store_returns", "catalog_sales",
-                      "catalog_returns", "web_sales", "web_returns")))
+
+    def padded(c, b, pad):
+        return np.concatenate([c, np.full(b - len(c), pad, c.dtype)])
+
     out = {}
     for inp in Q5_CHANNEL_INPUTS:
         if not inp.bucket:
             continue
-        cols = host[facts[inp.name]]
+        cols = host[_Q5_FACT_TABLES[inp.name]]
         rows = len(cols[0])
-        b = bucket_rows(rows)
+        if mesh is None:
+            b = bucket_rows(rows)
+            out[inp.name] = Padded(tuple(
+                jax.device_put(padded(c, b, spec.pad))
+                for spec, c in zip(inp.columns, cols)), rows)
+            continue
+        devices = list(mesh.devices.flat)
+        edges = [rows * i // len(devices) for i in range(len(devices) + 1)]
+        shards = list(zip(edges[:-1], edges[1:]))
+        b = bucket_rows(max(hi - lo for lo, hi in shards))
+        sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
         out[inp.name] = Padded(tuple(
-            jax.device_put(np.concatenate(
-                [c, np.full(b - rows, spec.pad, c.dtype)]))
-            for spec, c in zip(inp.columns, cols)), rows)
-    out["dd"] = (jax.device_put(host["d_date_sk"]),
-                 jax.device_put(host["d_date"]))
+            jax.make_array_from_single_device_arrays(
+                (b * len(devices),), sharding,
+                [jax.device_put(padded(c[lo:hi], b, spec.pad), d)
+                 for d, (lo, hi) in zip(devices, shards)])
+            for spec, c in zip(inp.columns, cols)), rows,
+            [hi - lo for lo, hi in shards])
+    whole = None if mesh is None else NamedSharding(mesh, P())
+    out["dd"] = (jax.device_put(host["d_date_sk"], whole),
+                 jax.device_put(host["d_date"], whole))
     for dim in _DIM_ID:
-        out[dim] = (jax.device_put(host[dim]),)
+        out[dim] = (jax.device_put(host[dim], whole),)
     return out
 
 
-def q5_channels_shape(sizes: dict, ids: dict, window_days: int) -> dict:
+def q5_channels_shape(sizes: dict, ids: dict, window_days: int,
+                      chips: int = 1) -> dict:
     """The static parameters of a q5 database's plan: outlets a channel
     (from ``sizes``), business ids a channel (``ids``, by outlet dim),
-    the item key's bits, the probe's capacity (web_returns' row bucket:
-    web_sales' key is unique, so a return finds at most one sale) and
-    the date window's length in days."""
+    the item key's bits, the date window's length in days, the chips
+    the facts are sharded over, each web table's exchange slot (the
+    rows one chip sends one chip: its share under uniform hashing and
+    eight standard deviations, to a power of two and at most its
+    shard's bucket) and the probe's capacity: web_sales' key is
+    unique, so a return finds at most one sale and the probe needs a
+    slot a return it holds (web_returns' row bucket on one chip, its
+    received slots on a mesh)."""
+    import math
+
     from spark_rapids_tpu.perf.jit_cache import bucket_rows
+    slots = []
+    for table in ("web_returns", "web_sales"):
+        shard = -(-int(sizes[table]) // chips)
+        share = shard / chips
+        slots.append((table, min(bucket_rows(shard), bucket_rows(
+            math.ceil(share + 8 * math.sqrt(share))))))
     return {"outlets": tuple(sizes[dim] for _c, _s, _r, dim in Q5_CHANNELS),
             "ids": tuple(ids[dim] for _c, _s, _r, dim in Q5_CHANNELS),
             "item_bits": int(sizes["item"]).bit_length(),
-            "join_capacity": bucket_rows(sizes["web_returns"]),
-            "window_days": int(window_days)}
+            "join_capacity": (bucket_rows(sizes["web_returns"])
+                              if chips == 1 else chips * slots[0][1]),
+            "window_days": int(window_days),
+            "chips": int(chips),
+            "exchange_slots": tuple(slots)}
+
+
+def q5_exchange_slots(shape: dict, capacity: Optional[int] = None):
+    """((table, slot rows), ...) of the web exchange and the probe's
+    capacity when web_sales' slot is ``capacity`` (the shape's by
+    default): web_returns' slot and the probe's capacity scale with
+    it, as the capacity retry doubles it."""
+    slots, join_capacity = shape["exchange_slots"], shape["join_capacity"]
+    if capacity is None:
+        return slots, join_capacity
+    first = slots[-1][1]
+    return (tuple((t, s * capacity // first) for t, s in slots),
+            join_capacity * capacity // first)
 
 
 def run_q5_channels(tables: dict, shape: dict, sales_day: int,
-                    limit: int = 100):
+                    limit: int = 100, mesh=None,
+                    capacity: Optional[int] = None):
     """q5 over a database held on the device (``q5_channels_tables``)
-    for SALES_DATE ``sales_day`` (days since 1970-01-01): the two-stage
-    pipeline, one executable a stage.  Returns (channel, id, sales,
-    returns, profit) of the first ``limit`` rows (a limit past the
-    rollup's rows serves every row, from one executable), the overflow
-    flag and the probe's true pair count."""
+    for SALES_DATE ``sales_day`` (days since 1970-01-01).  Returns
+    (channel, id, sales, returns, profit) of the first ``limit`` rows
+    (a limit past the rollup's rows serves every row, from one
+    executable), the overflow flag and the probe's true pair count.
+
+    On one chip the two-stage pipeline, one executable a stage.  On a
+    ``mesh`` (tables sharded over it) the whole pipeline is one
+    executable (``MeshPipeline``), and a dict of each web table's send
+    counts, (devices, devices), follows the outputs, which
+    ``capacity`` sizes (``q5_exchange_slots``)."""
     import numpy as np
+
+    from spark_rapids_tpu.plan.compiler import MeshPipeline
     limit = min(int(limit), q5_slots(shape["ids"])[1])
-    pipe = compile_pipeline(q5_channels_pipeline(
-        shape["outlets"], shape["ids"], shape["item_bits"],
-        shape["join_capacity"], limit, shape["window_days"]))
-    return pipe.run({**tables, "q": (np.int32(sales_day),)})
+    slots, join_capacity = q5_exchange_slots(shape, capacity)
+    pipeline = q5_channels_pipeline(
+        shape["outlets"], shape["ids"], shape["item_bits"], join_capacity,
+        limit, shape["window_days"], exchange_slots=slots)
+    inputs = {**tables, "q": (np.int32(sales_day),)}
+    if mesh is None:
+        return compile_pipeline(pipeline).run(inputs)
+    out, sent = MeshPipeline(pipeline, mesh).run(inputs)
+    return (*out, sent)
 
 # ------------------------------------------------------------------ q72
 

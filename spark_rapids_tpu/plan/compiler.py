@@ -13,8 +13,10 @@ What runs a plan:
   * :meth:`CompiledStage.run` — single process, one AOT executable;
     the only way a stage executes (no switch selects another);
   * :func:`fused_pipeline_fn` — the WHOLE pipeline (boundaries elided,
-    ``Reduce`` -> ``lax.psum``) as one function for ``shard_map``: a
-    mesh rank runs one program end to end;
+    ``Reduce`` -> ``lax.psum``, ``Exchange`` -> all-to-all) as one
+    function for ``shard_map``: a mesh rank runs one program end to
+    end; :class:`MeshPipeline` runs it over sharded tables through the
+    compile cache;
   * stage-by-stage through the distributed runner, with the kudo
     socket shuffle carrying each boundary (distributed/runner.py).
 
@@ -54,11 +56,16 @@ class Padded(tuple):
     """A bucketed input's columns already padded to their power-of-two
     row bucket (with each ColSpec's pad value), and the true row count:
     a table held on the device binds as it is, with no copy a call
-    (models/resident.py)."""
+    (models/resident.py).  A table sharded over a mesh
+    (:class:`MeshPipeline`) holds each device's shard padded to one
+    bucket, and ``shard_rows`` the true rows of each shard in device
+    order (``rows`` is their sum)."""
 
-    def __new__(cls, columns, rows: int):
+    def __new__(cls, columns, rows: int, shard_rows=None):
         self = super().__new__(cls, columns)
         self.rows = int(rows)
+        self.shard_rows = (None if shard_rows is None
+                           else tuple(int(r) for r in shard_rows))
         return self
 
 
@@ -99,6 +106,9 @@ _ENGINES = "__engines__"
 # Idx}, and Idx key -> (value, engine) of those evaluated so far
 _IDX_PEERS = "__idx_peers__"
 _LOOKED_UP = "__looked_up__"
+# env key under which a mesh trace leaves each Exchange's send counts
+# (n_parts,) by node prefix
+_SENT = "__sent__"
 
 _CAST = {"i32": jnp.int32, "i64": jnp.int64, "f64": jnp.float64,
          "b": jnp.bool_}
@@ -316,6 +326,22 @@ def _eval_kind(node, env, reduce_axis: Optional[str]) -> None:
         env[f"{p}.ri"] = pairs.right_indices
         env[f"{p}.valid"] = pairs.valid
         env[f"{p}.total"] = pairs.total
+    elif isinstance(node, ir.Exchange):
+        p, valid = node.prefix, _eval(node.valid, env)
+        cols = [env[c] for c in node.columns]
+        if reduce_axis is not None:
+            # Spark's hash exchange over the mesh axis: every device
+            # ends with the rows whose partition is its index
+            from spark_rapids_tpu.parallel import exchange as _ex
+            n = lax.axis_size(reduce_axis)
+            part = _ex.hash_partitions(
+                [_eval(k, env) for k in node.keys], valid, n)
+            cols, valid, _total, sent = _ex.exchange(
+                cols, part, reduce_axis, n, node.capacity)
+            env.setdefault(_SENT, {})[p] = sent
+        for c, a in zip(node.columns, cols):
+            env[f"{p}.{c}"] = a
+        env[f"{p}.valid"] = valid
     elif isinstance(node, ir.SegmentSum):
         from spark_rapids_tpu.ops import segment_sum as _ss
         value = node.value
@@ -907,15 +933,10 @@ def compile_pipeline(pipeline: ir.Pipeline) -> CompiledPipeline:
     return CompiledPipeline(pipeline)
 
 
-def fused_pipeline_fn(pipeline: ir.Pipeline,
-                      reduce_axis: Optional[str] = None):
-    """The WHOLE pipeline as one function (boundaries elided, Reduce
-    -> psum over ``reduce_axis``) for shard_map: a mesh rank runs ONE
-    XLA program between collectives.  Args are the external inputs'
-    columns flattened in declaration order; boundary-fed ScanBinds
-    (every column already defined upstream) consume no args.  Returns
-    (fn, n_args)."""
-    _verify_once(pipeline)
+def _external_inputs(pipeline: ir.Pipeline) -> list:
+    """The ScanBinds a pipeline binds from its caller, in declaration
+    order; a boundary-fed ScanBind (every column defined upstream) is
+    not one."""
     defined = set()
     external = []
     for stage in pipeline.stages:
@@ -925,8 +946,34 @@ def fused_pipeline_fn(pipeline: ir.Pipeline,
                 defined.update(c.name for c in inp.columns)
         for node in stage.nodes:
             defined.update(node.outs())
-    n_args = sum(len(i.columns) for i in external)
+    return external
+
+
+def _exchange_prefixes(pipeline: ir.Pipeline) -> list:
+    return [n.prefix for s in pipeline.stages for n in s.nodes
+            if isinstance(n, ir.Exchange)]
+
+
+def fused_pipeline_fn(pipeline: ir.Pipeline,
+                      reduce_axis: Optional[str] = None,
+                      row_counts: bool = False):
+    """The WHOLE pipeline as one function (boundaries elided, Reduce
+    -> psum and Exchange -> all-to-all over ``reduce_axis``) for
+    shard_map: a mesh rank runs ONE XLA program.  Args are the
+    external inputs' columns flattened in declaration order;
+    boundary-fed ScanBinds consume no args.  Every ``Mask`` is all
+    true, unless ``row_counts``: then one int32 vector of true rows a
+    device follows the columns for each bucketed external input, and
+    its mask holds the first ``counts[axis_index]`` rows of the shard.
+    The function returns the last stage's outputs, then each Exchange
+    node's send counts (n_parts,), in node order.  Returns (fn,
+    n_args)."""
+    _verify_once(pipeline)
+    external = _external_inputs(pipeline)
+    counted = [i for i in external if row_counts and i.bucket]
+    n_args = sum(len(i.columns) for i in external) + len(counted)
     last = pipeline.stages[-1]
+    exchanges = _exchange_prefixes(pipeline)
 
     def fn(*args):
         env: Dict[str, object] = {}
@@ -935,13 +982,130 @@ def fused_pipeline_fn(pipeline: ir.Pipeline,
             for spec in inp.columns:
                 env[spec.name] = args[pos]
                 pos += 1
+        for inp in counted:
+            rows = env[inp.columns[0].name].shape[0]
+            n_valid = args[pos][lax.axis_index(reduce_axis)]
+            env[f"__mask__{inp.name}"] = (
+                jnp.arange(rows, dtype=jnp.int32) < n_valid)
+            pos += 1
         for stage in pipeline.stages:
             for inp in stage.inputs:
+                if f"__mask__{inp.name}" in env:
+                    continue
                 first = env[inp.columns[0].name]
                 rows = first.shape[0] if getattr(first, "ndim", 0) \
                     else 0
                 env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
             _eval_nodes(stage, env, reduce_axis)
-        return tuple(env[o] for o in last.outputs)
+        return (tuple(env[o] for o in last.outputs)
+                + tuple(env[_SENT][p] for p in exchanges))
 
     return fn, n_args
+
+
+class MeshPipeline:
+    """A pipeline as ONE executable over the first axis of ``mesh``
+    (``fused_pipeline_fn`` under ``shard_map``): each device binds its
+    own shard of every bucketed input (a :class:`Padded` with
+    ``shard_rows``), whose ``Mask`` reads that shard's true rows; the
+    other inputs are replicated; ``Reduce`` is a psum and ``Exchange``
+    an all-to-all over the axis.  The executable lives in the process
+    compile cache under (pipeline digest, operand shapes, devices,
+    bucket), and a run records what :meth:`CompiledStage.run` records:
+    spans ``stage_run:<pipeline>``, ``stage_bind``, ``stage_compile``
+    (when it builds), ``dispatch`` and ``device_wait`` (attribute
+    ``stage``), and ``srt_stage_fusion_total``."""
+
+    def __init__(self, pipeline: ir.Pipeline, mesh):
+        self.pipeline, self.mesh = pipeline, mesh
+        self.name = pipeline.name
+        self.axis = mesh.axis_names[0]
+        self.fn, _n_args = fused_pipeline_fn(pipeline, self.axis,
+                                             row_counts=True)
+        self.external = _external_inputs(pipeline)
+        self.exchanges = _exchange_prefixes(pipeline)
+        self.nodes = sum(len(s.nodes) for s in pipeline.stages)
+
+    def _bind(self, inputs):
+        """(args, in_specs, shape parts, largest shard bucket)."""
+        import numpy as np
+        from jax.sharding import PartitionSpec as P
+        cols, counts, specs, parts, bucket = [], [], [], [], 0
+        for inp in self.external:
+            arrs = inputs[inp.name]
+            if len(arrs) != len(inp.columns):
+                raise ValueError(
+                    f"input {inp.name!r} expects {len(inp.columns)} "
+                    f"columns, got {len(arrs)}")
+            if inp.bucket:
+                if getattr(arrs, "shard_rows", None) is None:
+                    raise ValueError(f"input {inp.name!r} is not sharded "
+                                     f"over the mesh (Padded.shard_rows)")
+                shard = arrs[0].shape[0] // len(arrs.shard_rows)
+                bucket = max(bucket, shard)
+                cols.extend(arrs)
+                specs += [P(self.axis)] * len(arrs)
+                counts.append(np.asarray(arrs.shard_rows, np.int32))
+                parts.append((",".join(_canon_dtype(a) for a in arrs),
+                              shard))
+            else:
+                cols.extend(arrs)
+                specs += [P()] * len(arrs)
+                parts.append((",".join(
+                    f"{_canon_dtype(a)}{tuple(np.shape(a))}"
+                    for a in arrs), 0))
+        specs += [P()] * len(counts)
+        return cols + counts, tuple(specs), parts, bucket
+
+    def run(self, inputs: Mapping[str, Sequence]):
+        """The last stage's outputs, and each Exchange's send counts
+        by node prefix as a (devices, n_parts) numpy array."""
+        import numpy as np
+
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        from spark_rapids_tpu import observability as _obs
+        from spark_rapids_tpu.perf import jit_cache as _jc
+        from spark_rapids_tpu.perf.calibrate import operands_digest
+
+        n_out = len(self.pipeline.stages[-1].outputs)
+        t0 = time.monotonic_ns()
+        with _obs.TRACER.span(f"stage_run:{self.name}", kind="phase"):
+            with _obs.TRACER.span("stage_bind", kind="phase") as span:
+                args, specs, parts, bucket = self._bind(inputs)
+                span.set_attr("bucket", bucket)
+            devices = ",".join(str(d.id) for d in self.mesh.devices.flat)
+            digest = (f"{self.pipeline.digest}|{operands_digest(parts)}"
+                      f"|{self.axis}:{devices}")
+            compiled_now = []
+
+            def build():
+                t_build = time.monotonic_ns()
+                out_specs = ((P(),) * n_out
+                             + (P(self.axis),) * len(self.exchanges))
+                with _obs.TRACER.span(
+                        "stage_compile", kind="compile",
+                        attrs={"stage": self.name, "digest": digest,
+                               "bucket": bucket, "nodes": self.nodes}):
+                    ex = jax.jit(shard_map(
+                        self.fn, mesh=self.mesh, in_specs=specs,
+                        out_specs=out_specs)).lower(*args).compile()
+                compiled_now.append(time.monotonic_ns() - t_build)
+                return ex
+
+            ex = _jc.CACHE.get_or_build(f"pipeline.{self.name}", digest,
+                                        bucket, build)
+            with _obs.TRACER.span("dispatch", kind="phase"):
+                out = ex(*args)
+            with _obs.TRACER.span("device_wait", kind="phase",
+                                  attrs={"stage": self.name}):
+                jax.block_until_ready(out)
+        _obs.record_stage_fusion(
+            self.name, "fused", digest=digest,
+            wall_ns=time.monotonic_ns() - t0, nodes=self.nodes,
+            compiled=bool(compiled_now))
+        n_dev = self.mesh.devices.size
+        sent = {p: np.asarray(s).reshape(n_dev, -1)
+                for p, s in zip(self.exchanges, out[n_out:])}
+        return out[:n_out], sent

@@ -296,6 +296,34 @@ class Reduce(Node):
 
 
 @dataclass(frozen=True)
+class Exchange(Node):
+    """Spark's ``Exchange hashpartitioning(keys)``: each row that
+    ``valid`` holds goes to the partition Spark's murmur3 of ``keys``
+    (seed 42, chained over the keys) pmod the number of partitions,
+    with the named ``columns``.  Defines ``<p>.<column>`` for each of
+    them, the rows this partition received, and ``<p>.valid``.  One
+    chip is one partition: the node lowers to nothing there (the
+    outputs are the inputs and ``valid``).  Under ``shard_map`` it is
+    ``parallel.exchange.exchange`` over the mesh axis, ``capacity``
+    rows a (source, destination) slot; rows past it are dropped and
+    show in the send counts the mesh run returns."""
+    prefix: str
+    keys: Tuple[Expr, ...]
+    columns: Tuple[str, ...]
+    valid: Expr
+    capacity: int
+
+    def outs(self):
+        return tuple(f"{self.prefix}.{c}" for c in self.columns) + (
+            f"{self.prefix}.valid",)
+
+    def key(self):
+        return (f"X({self.prefix},{','.join(_k(k) for k in self.keys)}"
+                f";{','.join(self.columns)},{_k(self.valid)},"
+                f"{self.capacity})")
+
+
+@dataclass(frozen=True)
 class WindowSum(Node):
     """Window aggregate ``sum(value) OVER (PARTITION BY part)``
     broadcast back to every row: segment-sum + gather."""
