@@ -68,6 +68,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "exchange capacity-retry doublings"),
     "srt_exchange_rows_total": (
         "counter", "rows sent by a stage's hash exchange, by table"),
+    "srt_pruned_rows_total": (
+        "counter", "true fact rows a date-ordered scan skipped, by table"),
     "srt_journal_dropped_total": (
         "counter", "journal events lost to ring wrap"),
     "srt_retry_episodes_total": ("counter", "failed retry episodes"),

@@ -372,8 +372,13 @@ def verify_stage(plan: ir.StagePlan,
         for e in _node_exprs(node):
             _check_expr_ops(name, label, e)
             _expr_refs(e, refs)
-        if isinstance(node, ir.Exchange):
+        if isinstance(node, (ir.Exchange, ir.WindowSlice)):
             refs += [("col", c) for c in node.columns]
+        if isinstance(node, ir.WindowSlice) \
+                and node.input not in input_names:
+            raise PlanVerifyError(
+                name, label,
+                f"slice input {node.input!r} does not name a stage input")
         for kind, ref in refs:
             if kind == "mask":
                 if ref not in input_names:
@@ -394,6 +399,10 @@ def verify_stage(plan: ir.StagePlan,
             raise PlanVerifyError(
                 name, label,
                 f"non-positive exchange capacity {node.capacity}")
+        if isinstance(node, ir.WindowSlice) and node.capacity < 1:
+            raise PlanVerifyError(
+                name, label,
+                f"non-positive slice capacity {node.capacity}")
         if isinstance(node, ir.SegmentSum) and node.num_segments < 1:
             raise PlanVerifyError(
                 name, label,
@@ -448,6 +457,16 @@ def verify_stage(plan: ir.StagePlan,
                 for c in node.columns:
                     env[f"{node.prefix}.{c}"] = env[c]
                 env[f"{node.prefix}.valid"] = "bool"
+            elif isinstance(node, ir.WindowSlice):
+                for what, e in (("slice order", node.order),
+                                ("slice bound", node.lo),
+                                ("slice bound", node.hi)):
+                    _require_int(name, label, what,
+                                 _expr_dtype(name, label, e, env))
+                for c in node.columns:
+                    env[f"{node.prefix}.{c}"] = env[c]
+                env[f"{node.prefix}.valid"] = "bool"
+                env[f"{node.prefix}.over"] = "bool"
             elif isinstance(node, ir.SegmentSum):
                 _require_int(name, label, "segment ids",
                              _expr_dtype(name, label, node.ids, env))

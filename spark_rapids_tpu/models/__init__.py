@@ -413,12 +413,17 @@ def _run_q5_channels(params: dict, ctx: QueryContext):
     tables = resident.REGISTRY.get(
         ("tpcds_q5", tuple(sorted(sizes.items())), db_seed, chips),
         lambda: plan_catalog.q5_channels_tables(
-            tpcds.gen_q5_db(sizes, db_seed), mesh))
+            tpcds.gen_q5_db(sizes, db_seed), mesh, tpcds.Q5_WINDOW_DAYS))
     shape = plan_catalog.q5_channels_shape(
-        sizes, tpcds.q5_dim_ids(sizes), tpcds.Q5_WINDOW_DAYS, chips)
+        sizes, tpcds.q5_dim_ids(sizes), tpcds.Q5_WINDOW_DAYS, chips,
+        plan_catalog.q5_windows(tables))
     limit = int(params.get("limit", tpcds.Q5_LIMIT))
+    scan_rows, skipped = plan_catalog.q5_scan_rows(tables)
+    for table, rows in skipped.items():
+        _obs.record_pruned_rows(table, rows)
     # _execute's span, with the probe's true pair count on it
     with ctx.phase("execute", path="stage") as span:
+        span.set_attr("scan_rows", scan_rows)
         if mesh is None:
             *rows, of, pairs = plan_catalog.run_q5_channels(tables, shape,
                                                             day, limit)
@@ -436,8 +441,9 @@ def _run_q5_channels(params: dict, ctx: QueryContext):
         span.set_attr("join_pairs", int(np.asarray(pairs)))
     if bool(np.asarray(of)):
         raise RuntimeError("q5 capacity overflow: a web sale key "
-                           "repeats or a date window holds more than "
-                           f"{plan_catalog.Q5_WINDOW_KEYS} date keys")
+                           "repeats, a date window holds more than "
+                           f"{plan_catalog.Q5_WINDOW_KEYS} date keys, or "
+                           "a fact's window more rows than its slice")
     return _rows(*rows)
 
 

@@ -9,9 +9,17 @@ budget, dropping the least recently used database first.  A database
 larger than the whole budget is still loaded (a query needs it), with
 everything else dropped.
 
+A loader may put a fact's true rows in the order of a column and give
+the table a ``window`` (``plan.compiler.Padded``): the q5 loader orders
+each fact by its date, a shard by its own chip, and sizes a slice for
+the widest window of the query's days, so that a query reads one slice
+of each fact where it would mask all of it.  The capacity is fixed for
+the life of the database, so its executables are too.
+
 Counted by ``srt_resident_table_total{outcome=load|hit|evict}``; a
-load is the timeline's ``table_load`` span (attributes ``rows`` and
-``bytes``), under the query that paid for it.
+load is the timeline's ``table_load`` span (attributes ``rows``,
+``bytes`` and, for tables held in order, ``window_capacity``), under
+the query that paid for it.
 """
 
 from __future__ import annotations
@@ -56,6 +64,13 @@ def table_rows(tables: Dict[str, object]) -> int:
     return sum(int(getattr(v, "rows", 0)) for v in tables.values())
 
 
+def table_windows(tables: Dict[str, object]) -> str:
+    """The slice capacity of each table held in order, as
+    ``name=rows`` joined by commas ('' where none is)."""
+    return ",".join(f"{name}={v.window}" for name, v in tables.items()
+                    if getattr(v, "window", None) is not None)
+
+
 class ResidentTables:
     """Databases on the device, keyed by what defines them (name,
     sizes, seed), least recently used first."""
@@ -89,6 +104,9 @@ class ResidentTables:
                 nbytes = table_bytes(tables)
                 span.set_attr("rows", table_rows(tables))
                 span.set_attr("bytes", nbytes)
+                windows = table_windows(tables)
+                if windows:
+                    span.set_attr("window_capacity", windows)
             with self._lock:
                 self._held[key] = (tables, nbytes)
                 _obs.record_resident_table("load")

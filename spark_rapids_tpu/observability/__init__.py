@@ -173,6 +173,11 @@ EXCHANGE_ROWS = METRICS.counter(
     "srt_exchange_rows_total",
     "Rows a stage's hash Exchange sent over the mesh (every chip, every "
     "destination, its own included), by table", labels=("table",))
+PRUNED_ROWS = METRICS.counter(
+    "srt_pruned_rows_total",
+    "True rows of a fact held in date order that a query's date-filtered "
+    "scan skipped (each shard's rows less its slice), by table",
+    labels=("table",))
 JOURNAL_DROPPED_TOTAL = METRICS.counter(
     "srt_journal_dropped_total",
     "Journal events overwritten by ring wrap-around (counted at emit)")
@@ -1485,6 +1490,13 @@ def record_exchange_rows(table: str, rows: int) -> None:
     send counts."""
     if _SWITCH.enabled:
         EXCHANGE_ROWS.inc(rows, labels=(table,))
+
+
+def record_pruned_rows(table: str, rows: int) -> None:
+    """Zone-map hook (the catalog's q5 runner): one query's slice of
+    ``table`` skipped ``rows`` of its true rows."""
+    if _SWITCH.enabled:
+        PRUNED_ROWS.inc(rows, labels=(table,))
 
 
 def record_device_memory(allocated_bytes: int) -> None:

@@ -51,7 +51,7 @@ from spark_rapids_tpu.plan.ir import (Arange, Bin, Col, ColSpec, Exchange,
                                       ScanBind, SegmentSum,
                                       ShuffleBoundary, Sl, Sort,
                                       StagePlan, Stack, Un, Where,
-                                      WindowRank, WindowSum)
+                                      WindowRank, WindowSlice, WindowSum)
 
 I64_SENTINEL = Lit(2 ** 62, "int64")
 
@@ -292,28 +292,57 @@ def q5_slots(ids):
     return tuple(subs), at
 
 
+def _q5_scan(side: str, cols, windows: dict):
+    """How the map stage reads fact ``side``'s columns ``cols`` (names
+    without the side's prefix): through a ``WindowSlice`` on its date
+    where ``windows`` gives the side a capacity (a resident fact
+    ordered at load), whole and masked otherwise.  Returns (nodes,
+    column by name, validity)."""
+    names = tuple(f"{side}_{c}" for c in cols)
+    if side not in windows:
+        return [], {c: Col(n) for c, n in zip(cols, names)}, Mask(side)
+    p = f"{side}_w"
+    return ([WindowSlice(p, side, Col(f"{side}_date"), Col("win_lo"),
+                         Col("win_hi"), names, windows[side])],
+            {c: Col(f"{p}.{n}") for c, n in zip(cols, names)},
+            Col(f"{p}.valid"))
+
+
 def q5_channels_map_plan(outlets, ids, item_bits: int,
                          join_capacity: int,
                          window_days: int = 15, *,
-                         exchange_slots) -> StagePlan:
+                         exchange_slots, windows=()) -> StagePlan:
     """q5's map side, all three channels in one stage.
 
     * date_dim is filtered first (``d_date`` between SALES_DATE and
       SALES_DATE + ``window_days`` - 1, read at run time) and hands
       over its surviving keys, as Spark's dynamic pruning does; each
       fact keeps the rows whose date key is among them (``IsIn``);
+    * a fact that ``windows`` ((side, capacity), ...) names was put in
+      date order at load (``q5_channels_tables``) and is read as one
+      ``WindowSlice``: the capacity's rows from its first row dated in
+      the window, found by a binary search, so the filter, the sums
+      and the probe's left side see the fortnight and not the table;
+      a window that held more rows than its slice raises ``of``.  A
+      fact without a capacity is read whole, masked;
     * web returns find their sale through a JoinProbe on the packed
-      key (order number << ``item_bits``) | item over web_sales, and
-      take the sale's site; both sides reach the probe through an
-      ``Exchange`` on (item, order number), as Spark's sort-merge join
-      does: nothing on one chip, an all-to-all on a mesh, whose slots
-      are ``exchange_slots`` ((table, rows), web_returns first);
+      key (order number << ``item_bits``) | item over the whole of
+      web_sales, and take the sale's site; both sides reach the probe
+      through an ``Exchange`` on (item, order number), as Spark's
+      sort-merge join does: nothing on one chip, an all-to-all on a
+      mesh, whose slots are ``exchange_slots`` ((table, rows), ...,
+      web_returns first).  The returns' date filter lies below the
+      join, ahead of the exchange: a predicate on the left side's own
+      column under an inner join, as Spark's optimizer pushes it, so
+      only the window's returns are sent and probed;
     * each fact side sums into its outlets (``_fact_side``), and each
       channel's outlets fold through the outlet dim's business ids into
       the one group table of ``q5_slots`` — the UNION ALL of the
       channels."""
     subs, n_slots = q5_slots(ids)
     k = Q5_WINDOW_KEYS
+    windows = dict(windows)
+    first_key = Idx(Col("d_key_s"), Lit(0))
     nodes = [
         Project("d_in", _and(
             Bin("ge", Col("d_date"), Col("sales_date")),
@@ -327,55 +356,63 @@ def q5_channels_map_plan(outlets, ids, item_bits: int,
         # the empty slots repeat a surviving key: they match nothing new
         Project("win_keys", Where(
             Bin("lt", Arange(k, "int32"), Col("win_n")),
-            Sl(Col("d_key_s"), 0, k), Idx(Col("d_key_s"), Lit(0)))),
+            Sl(Col("d_key_s"), 0, k), first_key)),
+        # the first and the last surviving key: the slices' bounds
+        Project("win_lo", first_key),
+        Project("win_hi", Idx(Col("d_key_s"), Bin(
+            "max", Bin("sub", Col("win_n"), Lit(1)), Lit(0)))),
     ]
-    for side in ("ss", "sr", "cs", "cr", "ws"):
+    read, over = {}, []
+    for inp in Q5_CHANNEL_INPUTS[:6]:
+        side = inp.name
+        cols = tuple(c.name[len(side) + 1:] for c in inp.columns)
+        if side == "ws":            # its sales; the join reads it whole
+            cols = cols[:4]
+        found, read[side], valid = _q5_scan(side, cols, windows)
+        nodes += found
+        over += [Col(f"{n.prefix}.over") for n in found]
         nodes.append(Project(f"{side}_keep", _and(
-            IsIn(Col(f"{side}_date"), Col("win_keys")), Col("win_any"),
-            Mask(side))))
+            IsIn(read[side]["date"], Col("win_keys")), Col("win_any"),
+            valid)))
+    wr = read["wr"]
     shift = Lit(1 << item_bits)
-    for side in ("wr", "ws"):
+    for side, order, item in (("wr", wr["order"], wr["item"]),
+                              ("ws", Col("ws_order"), Col("ws_item"))):
         nodes.append(Project(f"{side}_key", Bin(
-            "add", Bin("mul", Un("i64", Col(f"{side}_order")), shift),
-            Un("i64", Col(f"{side}_item")))))
+            "add", Bin("mul", Un("i64", order), shift), Un("i64", item))))
     # Spark's plan for the web join: both sides behind Exchange
     # hashpartitioning(item, order number); on one chip it is nothing
     wr_slot, ws_slot = (s for _t, s in exchange_slots)
+    amt, loss = wr["amt"].name, wr["loss"].name
     nodes += [
-        Exchange("web_returns", (Col("wr_item"), Col("wr_order")),
-                 ("wr_key", "wr_date", "wr_amt", "wr_loss"), Mask("wr"),
-                 wr_slot),
+        Exchange("web_returns", (wr["item"], wr["order"]),
+                 ("wr_key", amt, loss), Col("wr_keep"), wr_slot),
         Exchange("web_sales", (Col("ws_item"), Col("ws_order")),
                  ("ws_key", "ws_outlet"), Mask("ws"), ws_slot),
     ]
     li, ri = Col("wj.li"), Col("wj.ri")
-    nodes += [
-        JoinProbe("wj", Col("web_returns.wr_key"), Col("web_sales.ws_key"),
-                  join_capacity, left_valid=Col("web_returns.valid"),
-                  right_valid=Col("web_sales.valid")),
-        Project("wr_keep", _and(
-            Col("wj.valid"),
-            IsIn(Idx(Col("web_returns.wr_date"), li), Col("win_keys")),
-            Col("win_any"))),
-    ]
+    nodes.append(JoinProbe(
+        "wj", Col("web_returns.wr_key"), Col("web_sales.ws_key"),
+        join_capacity, left_valid=Col("web_returns.valid"),
+        right_valid=Col("web_sales.valid")))
     totals = {"sales": [], "returns": [], "profit": [], "cnt": []}
     for (channel, sold, ret, dim), n_out, sub in zip(Q5_CHANNELS,
                                                     outlets, subs):
+        s = read[sold]
         nodes += _fact_side(sold, Col(f"{sold}_keep"),
-                            Bin("sub", Col(f"{sold}_outlet"), Lit(1)),
-                            Col(f"{sold}_price"), Col(f"{sold}_profit"),
-                            n_out)
+                            Bin("sub", s["outlet"], Lit(1)),
+                            s["price"], s["profit"], n_out)
         if ret == "wr":
             nodes += _fact_side(
-                ret, Col("wr_keep"),
+                ret, Col("wj.valid"),
                 Bin("sub", Idx(Col("web_sales.ws_outlet"), ri), Lit(1)),
-                Idx(Col("web_returns.wr_amt"), li),
-                Idx(Col("web_returns.wr_loss"), li), n_out)
+                Idx(Col(f"web_returns.{amt}"), li),
+                Idx(Col(f"web_returns.{loss}"), li), n_out)
         else:
+            r = read[ret]
             nodes += _fact_side(ret, Col(f"{ret}_keep"),
-                                Bin("sub", Col(f"{ret}_outlet"), Lit(1)),
-                                Col(f"{ret}_amt"), Col(f"{ret}_loss"),
-                                n_out)
+                                Bin("sub", r["outlet"], Lit(1)),
+                                r["amt"], r["loss"], n_out)
         slot = Col(f"{channel}_slot")
         nodes.append(Project(f"{channel}_slot", Bin(
             "add", Col(_DIM_ID[dim]), Lit(sub + 1))))
@@ -394,9 +431,8 @@ def q5_channels_map_plan(outlets, ids, item_bits: int,
                                        parts[2])))
     nodes += [
         Project("pairs", Col("wj.total")),
-        Project("of", Bin("or", Bin("gt", Col("wj.total"),
-                                    Lit(join_capacity)),
-                          Bin("gt", Col("win_n"), Lit(k)))),
+        Project("of", _or(Bin("gt", Col("wj.total"), Lit(join_capacity)),
+                          Bin("gt", Col("win_n"), Lit(k)), *over)),
     ]
     return StagePlan(name="q5_channels_map", inputs=Q5_CHANNEL_INPUTS,
                      nodes=tuple(nodes),
@@ -473,12 +509,13 @@ def q5_channels_finish_plan(ids, limit: int = 100) -> StagePlan:
 def q5_channels_pipeline(outlets, ids, item_bits: int,
                          join_capacity: int, limit: int = 100,
                          window_days: int = 15, *,
-                         exchange_slots) -> Pipeline:
+                         exchange_slots, windows=()) -> Pipeline:
     return Pipeline(
         name="q5_channels",
         stages=(q5_channels_map_plan(outlets, ids, item_bits,
                                      join_capacity, window_days,
-                                     exchange_slots=exchange_slots),
+                                     exchange_slots=exchange_slots,
+                                     windows=windows),
                 q5_channels_finish_plan(ids, limit)),
         boundaries=(ShuffleBoundary(
             ("sales", "returns", "profit", "cnt", "of", "pairs")),))
@@ -489,15 +526,55 @@ _Q5_FACT_TABLES = dict(zip(("ss", "sr", "cs", "cr", "ws", "wr"),
                             "catalog_returns", "web_sales", "web_returns")))
 
 
-def q5_channels_tables(host: dict, mesh=None) -> dict:
+def _window_capacity(widest: int, bucket: int) -> int:
+    """A slice's rows for a window that holds at most ``widest`` rows of
+    a shard: to a multiple of 1,024, within the shard's bucket."""
+    return min(bucket, max(1024, -(-widest // 1024) * 1024))
+
+
+def _order_rows(n_valid, cols, keys, days: int):
+    """The first ``n_valid`` rows of ``cols`` in the order of the first
+    column (its values carried as the sort's key, the others as its
+    payload; the pad rows after them stay at the tail, as they are),
+    and the most of them that ``days`` consecutive values from any of
+    ``keys`` hold: a binary search of the ordered column for each key
+    and for each key plus ``days``, so no pass over the rows."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from spark_rapids_tpu.plan.compiler import first_rows
+    real = lax.iota(jnp.int32, cols[0].shape[0]) < n_valid
+    key = jnp.where(real, cols[0], jnp.iinfo(cols[0].dtype).max)
+    # rows of one key may come in any order: an unstable sort compiles
+    # in about a third of a stable one's time for a v5e
+    out = lax.sort((key,) + tuple(cols[1:]), num_keys=1, is_stable=False)
+    date = jnp.where(real, out[0], cols[0])
+    first = first_rows(date, n_valid, jnp.concatenate([keys, keys + days]),
+                        False)
+    widest = jnp.max(first[len(keys):] - first[:len(keys)])
+    return (date,) + tuple(out[1:]), widest[None]
+
+
+def q5_channels_tables(host: dict, mesh=None, window_days: int = 15) -> dict:
     """A q5 database (``models.tpcds.gen_q5_db``) as the map stage
     binds it, on the device: each fact padded once to its row bucket
     with its columns' pad values (``Padded``), the dims as they are.
     On a ``mesh`` each device holds one contiguous shard of every fact
     (as a scan of files splits a table among executors), each padded
-    to the bucket of the largest, and the dims whole."""
+    to the bucket of the largest, and the dims whole.
+
+    Each fact's true rows are put in the order of its date (the first
+    column) on the device, a shard by its own chip, and the table keeps
+    its ``window``: the most rows of a shard that ``window_days``
+    consecutive date keys from date_dim's hold, to a multiple of 1,024
+    (``_window_capacity``).  That is the capacity of the
+    ``WindowSlice`` the map stage reads it through, fixed for the life
+    of the database: one executable a stage still."""
+    from concurrent.futures import ThreadPoolExecutor
+
     import jax
     import numpy as np
+    from jax import lax, shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from spark_rapids_tpu.perf.jit_cache import bucket_rows
@@ -506,67 +583,121 @@ def q5_channels_tables(host: dict, mesh=None) -> dict:
     def padded(c, b, pad):
         return np.concatenate([c, np.full(b - len(c), pad, c.dtype)])
 
-    out = {}
-    for inp in Q5_CHANNEL_INPUTS:
-        if not inp.bucket:
-            continue
+    whole = None if mesh is None else NamedSharding(mesh, P())
+    out = {"dd": (jax.device_put(host["d_date_sk"], whole),
+                  jax.device_put(host["d_date"], whole))}
+
+    def order_rows(n_valid, cols, keys):
+        return _order_rows(n_valid, cols, keys, window_days)
+    if mesh is None:
+        order = jax.jit(order_rows)
+    else:
+        axis = mesh.axis_names[0]
+        order = jax.jit(shard_map(
+            lambda counts, cols, keys: order_rows(
+                counts[lax.axis_index(axis)], cols, keys),
+            mesh=mesh, in_specs=(P(), P(axis), P()), out_specs=P(axis)))
+    # each fact on the device as it was drawn: (order's arguments,
+    # true rows, shard rows, bucket)
+    held = {}
+    for inp in Q5_CHANNEL_INPUTS[:6]:
         cols = host[_Q5_FACT_TABLES[inp.name]]
         rows = len(cols[0])
         if mesh is None:
             b = bucket_rows(rows)
-            out[inp.name] = Padded(tuple(
+            held[inp.name] = ((np.int32(rows), tuple(
                 jax.device_put(padded(c, b, spec.pad))
-                for spec, c in zip(inp.columns, cols)), rows)
+                for spec, c in zip(inp.columns, cols)), out["dd"][0]),
+                rows, None, b)
             continue
         devices = list(mesh.devices.flat)
         edges = [rows * i // len(devices) for i in range(len(devices) + 1)]
         shards = list(zip(edges[:-1], edges[1:]))
         b = bucket_rows(max(hi - lo for lo, hi in shards))
         sharding = NamedSharding(mesh, P(mesh.axis_names[0]))
-        out[inp.name] = Padded(tuple(
+        counts = np.asarray([hi - lo for lo, hi in shards], np.int32)
+        held[inp.name] = ((counts, tuple(
             jax.make_array_from_single_device_arrays(
                 (b * len(devices),), sharding,
                 [jax.device_put(padded(c[lo:hi], b, spec.pad), d)
                  for d, (lo, hi) in zip(devices, shards)])
-            for spec, c in zip(inp.columns, cols)), rows,
-            [hi - lo for lo, hi in shards])
-    whole = None if mesh is None else NamedSharding(mesh, P())
-    out["dd"] = (jax.device_put(host["d_date_sk"], whole),
-                 jax.device_put(host["d_date"], whole))
+            for spec, c in zip(inp.columns, cols)), out["dd"][0]),
+            rows, counts, b)
+    # the six sorts compile side by side (a sort of a few payload
+    # columns takes a v5e's compiler a minute), then run one by one
+    lowered = {name: order.lower(*h[0]) for name, h in held.items()}
+    with ThreadPoolExecutor(len(lowered)) as pool:
+        compiled = dict(zip(lowered, pool.map(lambda low: low.compile(),
+                                              lowered.values())))
+    for name in list(held):
+        args, rows, shard_rows, b = held.pop(name)
+        cols, widest = compiled[name](*args)
+        out[name] = Padded(cols, rows, shard_rows, window=_window_capacity(
+            int(np.asarray(widest).max()), b))
     for dim in _DIM_ID:
         out[dim] = (jax.device_put(host[dim], whole),)
     return out
 
 
+def q5_windows(tables: dict) -> dict:
+    """Each fact's slice capacity, by map-stage input, of the facts a
+    q5 database holds in date order (``Padded.window``)."""
+    return {name: t.window for name, t in tables.items()
+            if getattr(t, "window", None) is not None}
+
+
+def q5_scan_rows(tables: dict):
+    """What a query's map stage reads of a q5 database: the true fact
+    rows it reads (a fact read through a slice at most the slice's
+    capacity a shard; web_sales whole, which the join reads whole),
+    and by table the true rows that a slice's scan skips."""
+    read, skipped = 0, {}
+    for side, table in _Q5_FACT_TABLES.items():
+        t = tables[side]
+        if t.window is None:
+            read += t.rows
+            continue
+        took = sum(min(r, t.window) for r in t.shard_rows or (t.rows,))
+        skipped[table] = t.rows - took
+        read += t.rows if side == "ws" else took
+    return read, skipped
+
+
 def q5_channels_shape(sizes: dict, ids: dict, window_days: int,
-                      chips: int = 1) -> dict:
+                      chips: int = 1, windows=None) -> dict:
     """The static parameters of a q5 database's plan: outlets a channel
     (from ``sizes``), business ids a channel (``ids``, by outlet dim),
     the item key's bits, the date window's length in days, the chips
-    the facts are sharded over, each web table's exchange slot (the
-    rows one chip sends one chip: its share under uniform hashing and
-    eight standard deviations, to a power of two and at most its
-    shard's bucket) and the probe's capacity: web_sales' key is
-    unique, so a return finds at most one sale and the probe needs a
-    slot a return it holds (web_returns' row bucket on one chip, its
-    received slots on a mesh)."""
+    the facts are sharded over, the slice capacity of each fact held in
+    date order (``windows``, ``q5_windows``), each web table's
+    exchange slot (the rows one chip sends one chip: its share under
+    uniform hashing and eight standard deviations, to a power of two
+    and at most the bucket of what it sends: web_returns' slice where
+    it has one, its shard otherwise) and the probe's capacity:
+    web_sales' key is unique, so a return finds at most one sale and
+    the probe needs a slot a return it holds (web_returns' slice, or
+    its row bucket, on one chip; its received slots on a mesh)."""
     import math
 
     from spark_rapids_tpu.perf.jit_cache import bucket_rows
+    windows = dict(windows or {})
     slots = []
-    for table in ("web_returns", "web_sales"):
-        shard = -(-int(sizes[table]) // chips)
+    for table, sent in (("web_returns", windows.get("wr")),
+                        ("web_sales", None)):
+        shard = sent or -(-int(sizes[table]) // chips)
         share = shard / chips
         slots.append((table, min(bucket_rows(shard), bucket_rows(
             math.ceil(share + 8 * math.sqrt(share))))))
+    one_chip = windows.get("wr") or bucket_rows(sizes["web_returns"])
     return {"outlets": tuple(sizes[dim] for _c, _s, _r, dim in Q5_CHANNELS),
             "ids": tuple(ids[dim] for _c, _s, _r, dim in Q5_CHANNELS),
             "item_bits": int(sizes["item"]).bit_length(),
-            "join_capacity": (bucket_rows(sizes["web_returns"])
-                              if chips == 1 else chips * slots[0][1]),
+            "join_capacity": (one_chip if chips == 1
+                              else chips * slots[0][1]),
             "window_days": int(window_days),
             "chips": int(chips),
-            "exchange_slots": tuple(slots)}
+            "exchange_slots": tuple(slots),
+            "windows": tuple(sorted(windows.items()))}
 
 
 def q5_exchange_slots(shape: dict, capacity: Optional[int] = None):
@@ -603,7 +734,8 @@ def run_q5_channels(tables: dict, shape: dict, sales_day: int,
     slots, join_capacity = q5_exchange_slots(shape, capacity)
     pipeline = q5_channels_pipeline(
         shape["outlets"], shape["ids"], shape["item_bits"], join_capacity,
-        limit, shape["window_days"], exchange_slots=slots)
+        limit, shape["window_days"], exchange_slots=slots,
+        windows=shape["windows"])
     inputs = {**tables, "q": (np.int32(sales_day),)}
     if mesh is None:
         return compile_pipeline(pipeline).run(inputs)

@@ -59,13 +59,22 @@ class Padded(tuple):
     (models/resident.py).  A table sharded over a mesh
     (:class:`MeshPipeline`) holds each device's shard padded to one
     bucket, and ``shard_rows`` the true rows of each shard in device
-    order (``rows`` is their sum)."""
+    order (``rows`` is their sum).
 
-    def __new__(cls, columns, rows: int, shard_rows=None):
+    ``window``, where it is set, says the true rows of each shard were
+    put in the order of the first column at load (the pad rows stay at
+    the tail), and is the most rows of a shard that any window of the
+    load's length over that column's values holds: the capacity of an
+    ``ir.WindowSlice`` over the table.  A table without it is read
+    whole."""
+
+    def __new__(cls, columns, rows: int, shard_rows=None,
+                window: Optional[int] = None):
         self = super().__new__(cls, columns)
         self.rows = int(rows)
         self.shard_rows = (None if shard_rows is None
                            else tuple(int(r) for r in shard_rows))
+        self.window = None if window is None else int(window)
         return self
 
 
@@ -76,6 +85,21 @@ def _input_rows(arrs) -> int:
         return arrs.rows
     shape = jnp.shape(arrs[0])
     return int(shape[0]) if shape else 0
+
+
+def _bind_rows(env, inp: ir.ScanBind, n_valid=None) -> None:
+    """Bind what an input's rows are: ``Mask(input)`` (the first
+    ``n_valid`` rows, or every row where it is None) and the true-row
+    count a ``WindowSlice`` over the input searches within."""
+    first = env[inp.columns[0].name]
+    rows = first.shape[0] if getattr(first, "ndim", 0) else 0
+    if n_valid is None:
+        env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
+        env[f"__rows__{inp.name}"] = rows
+    else:
+        env[f"__mask__{inp.name}"] = (
+            jnp.arange(rows, dtype=jnp.int32) < n_valid)
+        env[f"__rows__{inp.name}"] = n_valid
 
 
 # -------------------------------------------------------------- evaluation
@@ -278,6 +302,31 @@ def _tap_counts(plan: ir.StagePlan, env) -> list:
     return vals
 
 
+def first_rows(order, n_valid, values, past):
+    """For each of ``values``, the first of the first ``n_valid`` rows
+    of ``order`` (ascending there) whose value is above it where
+    ``past``, at least it otherwise; ``n_valid`` where there is none.
+    A binary search of one gather a step for all of them, not a pass
+    over the rows."""
+    rows = order.shape[0]
+    hi = jnp.full(values.shape, n_valid, jnp.int32)
+    # under shard_map the bounds vary over the mesh axes the column and
+    # the values vary over, as the loop's outputs do
+    vma = (jax.typeof(order).vma | jax.typeof(values).vma
+           | jax.typeof(hi).vma)
+    lo, hi = (lax.pcast(b, tuple(vma - jax.typeof(b).vma), to="varying")
+              for b in (jnp.zeros_like(hi), hi))
+
+    def step(_i, bounds):
+        lo, hi = bounds
+        mid = (lo + hi) // 2
+        at = order[jnp.minimum(mid, rows - 1)]
+        right = (lo < hi) & jnp.where(past, at <= values, at < values)
+        return jnp.where(right, mid + 1, lo), jnp.where(right, hi, mid)
+    # a loop, not unrolled: the stage traces and lowers one step
+    return lax.fori_loop(0, max(rows, 1).bit_length(), step, (lo, hi))[0]
+
+
 def _eval_nodes(plan: ir.StagePlan, env,
                 reduce_axis: Optional[str]) -> None:
     """Evaluate the stage's nodes in order into ``env``, which holds
@@ -342,6 +391,24 @@ def _eval_kind(node, env, reduce_axis: Optional[str]) -> None:
         for c, a in zip(node.columns, cols):
             env[f"{p}.{c}"] = a
         env[f"{p}.valid"] = valid
+    elif isinstance(node, ir.WindowSlice):
+        order = _eval(node.order, env)
+        rows = order.shape[0]
+        n_valid = env[f"__rows__{node.input}"]
+        cap = min(node.capacity, rows)
+        # the true rows whose order lies in [lo, hi] are [start, end)
+        start, end = first_rows(
+            order, n_valid, jnp.stack([_eval(node.lo, env),
+                                       _eval(node.hi, env)]),
+            jnp.array([False, True]))
+        # dynamic_slice's own clamp, made explicit for the validity
+        at = jnp.minimum(start, rows - cap)
+        for c in node.columns:
+            env[f"{node.prefix}.{c}"] = lax.dynamic_slice_in_dim(
+                env[c], at, cap)
+        env[f"{node.prefix}.valid"] = (
+            at + lax.iota(jnp.int32, cap) < n_valid)
+        env[f"{node.prefix}.over"] = end - start > cap
     elif isinstance(node, ir.SegmentSum):
         from spark_rapids_tpu.ops import segment_sum as _ss
         value = node.value
@@ -528,16 +595,10 @@ class CompiledStage:
                 if inp.bucket:
                     bucketed.append(inp)
             for i, inp in enumerate(bucketed):
-                n_valid = args[pos + i]
-                rows = env[inp.columns[0].name].shape[0]
-                env[f"__mask__{inp.name}"] = (
-                    jnp.arange(rows, dtype=jnp.int32) < n_valid)
+                _bind_rows(env, inp, args[pos + i])
             for inp in plan.inputs:
                 if not inp.bucket:
-                    first = env[inp.columns[0].name]
-                    rows = first.shape[0] if first.ndim else 0
-                    env[f"__mask__{inp.name}"] = jnp.ones(
-                        rows, jnp.bool_)
+                    _bind_rows(env, inp)
             _eval_nodes(plan, env, None)
             self._engines.update(env.get(_ENGINES, {}))
             outs = tuple(env[o] for o in plan.outputs)
@@ -563,9 +624,7 @@ class CompiledStage:
                 for spec in inp.columns:
                     env[spec.name] = args[pos]
                     pos += 1
-                first = env[inp.columns[0].name]
-                rows = first.shape[0] if first.ndim else 0
-                env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
+                _bind_rows(env, inp)
             _eval_nodes(plan, env, reduce_axis)
             return tuple(env[o] for o in plan.outputs)
 
@@ -646,9 +705,7 @@ class CompiledStage:
             arrs = [jnp.asarray(a) for a in inputs[inp.name]]
             for spec, a in zip(inp.columns, arrs):
                 env[spec.name] = a
-            first = arrs[0]
-            rows = first.shape[0] if first.ndim else 0
-            env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
+            _bind_rows(env, inp)
         _eval_nodes(self.plan, env, None)
         self._engines.update(env.get(_ENGINES, {}))
         return env
@@ -983,19 +1040,12 @@ def fused_pipeline_fn(pipeline: ir.Pipeline,
                 env[spec.name] = args[pos]
                 pos += 1
         for inp in counted:
-            rows = env[inp.columns[0].name].shape[0]
-            n_valid = args[pos][lax.axis_index(reduce_axis)]
-            env[f"__mask__{inp.name}"] = (
-                jnp.arange(rows, dtype=jnp.int32) < n_valid)
+            _bind_rows(env, inp, args[pos][lax.axis_index(reduce_axis)])
             pos += 1
         for stage in pipeline.stages:
             for inp in stage.inputs:
-                if f"__mask__{inp.name}" in env:
-                    continue
-                first = env[inp.columns[0].name]
-                rows = first.shape[0] if getattr(first, "ndim", 0) \
-                    else 0
-                env[f"__mask__{inp.name}"] = jnp.ones(rows, jnp.bool_)
+                if f"__mask__{inp.name}" not in env:
+                    _bind_rows(env, inp)
             _eval_nodes(stage, env, reduce_axis)
         return (tuple(env[o] for o in last.outputs)
                 + tuple(env[_SENT][p] for p in exchanges))

@@ -324,6 +324,39 @@ class Exchange(Node):
 
 
 @dataclass(frozen=True)
+class WindowSlice(Node):
+    """A zone-map scan of the bucketed input ``input``, whose true rows
+    are in ascending order of its column ``order`` (a resident fact
+    ordered at load: ``plan.compiler.Padded.window``): the
+    ``capacity`` rows from the first true row whose ``order`` is at
+    least ``lo``, found by a binary search over the true rows.
+    Defines ``<prefix>.<column>`` for each of ``columns``,
+    ``<prefix>.valid`` (the slice's true rows) and ``<prefix>.over``:
+    True where the true rows whose ``order`` lies in [``lo``, ``hi``]
+    are more than ``capacity``, so that the slice misses some.  A
+    slice that would run past the bucket starts earlier, and rows
+    outside [``lo``, ``hi``] are in it: the caller's own filter drops
+    them, as it would over the whole input.  On a mesh each device
+    searches and slices its own shard."""
+    prefix: str
+    input: str
+    order: Expr
+    lo: Expr
+    hi: Expr
+    columns: Tuple[str, ...]
+    capacity: int
+
+    def outs(self):
+        return tuple(f"{self.prefix}.{c}" for c in self.columns) + (
+            f"{self.prefix}.valid", f"{self.prefix}.over")
+
+    def key(self):
+        return (f"Z({self.prefix},{self.input},{_k(self.order)},"
+                f"{_k(self.lo)},{_k(self.hi)};{','.join(self.columns)},"
+                f"{self.capacity})")
+
+
+@dataclass(frozen=True)
 class WindowSum(Node):
     """Window aggregate ``sum(value) OVER (PARTITION BY part)``
     broadcast back to every row: segment-sum + gather."""

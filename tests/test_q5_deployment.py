@@ -59,18 +59,24 @@ def _want(db, sales_date, limit=100):
                        "limit": limit}, {})
 
 
-def _shape(sizes):
+def _shape(sizes, tables=None):
     sizes = tpcds.q5_sizes(sizes)
     return C.q5_channels_shape(sizes, tpcds.q5_dim_ids(sizes),
-                               tpcds.Q5_WINDOW_DAYS)
+                               tpcds.Q5_WINDOW_DAYS, 1,
+                               tables and C.q5_windows(tables))
+
+
+def _raw(tables, sales_date, limit=100, sizes=SIZES):
+    """The plan's outputs over ``tables``, as numpy arrays."""
+    return [np.asarray(a) for a in C.run_q5_channels(
+        tables, _shape(sizes, tables), tpcds.q5_day(sales_date), limit)]
 
 
 def _run(host, sales_date, limit=100, sizes=SIZES):
     """The plan over ``host`` put on the device as the registry does."""
-    shape = _shape(sizes)
-    *cols, of, pairs = C.run_q5_channels(
-        C.q5_channels_tables(host), shape, tpcds.q5_day(sales_date), limit)
-    rows = [[int(v) for v in r] for r in zip(*(np.asarray(c) for c in cols))]
+    *cols, of, pairs = _raw(C.q5_channels_tables(host), sales_date, limit,
+                            sizes)
+    rows = [[int(v) for v in r] for r in zip(*cols)]
     return REF.from_served(rows), bool(of), int(pairs)
 
 
@@ -131,16 +137,27 @@ def test_rollup_rows_and_nulls_first_order(host):
         for k in (2, 3, 4)]
 
 
+def _window_rows(dates, sales_date):
+    """Rows of a fact's date keys inside SALES_DATE's fortnight."""
+    lo = tpcds.q5_day(sales_date) - tpcds.D_DATE0 + tpcds.D_DATE_SK0
+    return np.flatnonzero((dates >= lo) & (dates < lo + tpcds.Q5_WINDOW_DAYS))
+
+
 def test_web_return_without_its_sale_is_dropped(host):
+    """Every other return of the fortnight loses its sale.  The returns'
+    date filter lies below the join, so the probe pairs the fortnight's
+    returns alone."""
     ws, wr = host["web_sales"], host["web_returns"]
     sold = {(int(i), int(o)): k for k, (i, o) in enumerate(zip(ws[4], ws[5]))}
-    gone = [sold[(int(wr[1][k]), int(wr[2][k]))] for k in range(0, 600, 3)]
+    inside = _window_rows(wr[0], "2000-08-23")
+    gone = [sold[(int(wr[1][k]), int(wr[2][k]))] for k in inside[::2]]
+    assert len(gone) >= 3
     keep = np.ones(len(ws[0]), bool)
     keep[gone] = False
     cut = dict(host, web_sales=tuple(c[keep] for c in ws))
     sizes = dict(SIZES, web_sales=int(keep.sum()))
     rows, of, pairs = _run(cut, "2000-08-23", 1000, sizes)
-    assert not of and pairs == len(wr[0]) - len(gone)
+    assert not of and pairs == len(inside) - len(gone)
     assert rows == _want(_ref_db(cut), "2000-08-23", 1000)
     assert rows != _run(host, "2000-08-23", 1000)[0]
 
@@ -341,3 +358,171 @@ def test_store_channel_shape_keeps_its_plan_and_its_oracle():
                                  np.asarray(rets), np.asarray(profit))
            if a != 2 ** 31 - 1]
     assert got == tpcds.oracle_q5(d, 8)
+
+
+# --------------------------------------- the zone map over ordered facts
+
+# the first and the last sale day, and a fortnight after every fact's
+# last date (a return 90 days after the last sale): no fact holds a row
+EDGE_DATES = ["1998-01-02", "2002-12-31", "2003-07-01"]
+# each fact one row past a power of two
+ABOVE = dict(SIZES, store_sales=2 ** 14 + 1, store_returns=2 ** 11 + 1,
+             catalog_sales=2 ** 15 + 1, catalog_returns=2 ** 12 + 1,
+             web_sales=2 ** 13 + 1, web_returns=2 ** 10 + 1)
+
+
+def _whole(host):
+    """The database as it was held before it was ordered: each fact in
+    its drawn order, padded, with no window, so the map stage reads
+    every row of it and masks them."""
+    from spark_rapids_tpu.perf.jit_cache import bucket_rows
+    tables = C.q5_channels_tables(host)
+    for inp in C.Q5_CHANNEL_INPUTS[:6]:
+        cols = host[C._Q5_FACT_TABLES[inp.name]]
+        b = bucket_rows(len(cols[0]))
+        tables[inp.name] = Padded(tuple(
+            jax.device_put(np.concatenate(
+                [c, np.full(b - len(c), spec.pad, c.dtype)]))
+            for spec, c in zip(inp.columns, cols)), len(cols[0]))
+    return tables
+
+
+def _widest(dates):
+    """The most of ``dates`` in any window of the query's days,
+    counted here from the sorted keys."""
+    d = np.sort(dates)
+    return int((np.searchsorted(d, d + tpcds.Q5_WINDOW_DAYS) -
+                np.arange(len(d))).max())
+
+
+@pytest.mark.parametrize("sizes", [SIZES, ABOVE], ids=["toy", "above"])
+def test_slices_answer_as_the_reference_and_the_whole_tables(sizes):
+    host = tpcds.gen_q5_db(sizes, 2002)
+    sliced, whole = C.q5_channels_tables(host), _whole(host)
+    assert all(sliced[s].window < len(sliced[s][0]) for s in C._Q5_FACT_TABLES)
+    assert C.q5_windows(whole) == {}
+    db = _ref_db(host)
+    for date in DATES[:5] + EDGE_DATES:
+        got = _raw(sliced, date, 1000, sizes)
+        for a, b in zip(got, _raw(whole, date, 1000, sizes)):
+            np.testing.assert_array_equal(a, b)
+        rows = [[int(v) for v in r] for r in zip(*got[:-2])]
+        assert not got[-2] and REF.from_served(rows) == _want(db, date, 1000)
+
+
+def _skewed(sizes, seed):
+    """A database with half of each fact's rows on one day, 2000-08-25."""
+    host = tpcds.gen_q5_db(sizes, seed)
+    hot = tpcds.q5_day("2000-08-25") - tpcds.D_DATE0 + tpcds.D_DATE_SK0
+    for fact in tpcds.Q5_FACTS:
+        host[fact][0][::2] = hot
+    return host
+
+
+def test_skewed_dates_stay_exact():
+    host = _skewed(SIZES, 5)
+    tables = C.q5_channels_tables(host)
+    assert tables["cs"].window >= SIZES["catalog_sales"] // 2
+    db = _ref_db(host)
+    for date in ("2000-08-11", "2000-08-25"):
+        *cols, of, _pairs = _raw(tables, date, 1000)
+        rows = [[int(v) for v in r] for r in zip(*cols)]
+        assert not of and REF.from_served(rows) == _want(db, date, 1000)
+
+
+def test_window_capacity_is_the_widest_window_rounded_up():
+    host = _skewed(ABOVE, 3)
+    tables = C.q5_channels_tables(host)
+    for side, fact in C._Q5_FACT_TABLES.items():
+        widest = _widest(host[fact][0])
+        bucket = len(tables[side][0])
+        assert tables[side].window == min(bucket, -(-widest // 1024) * 1024)
+        # the true rows in date order, the pad rows at the tail
+        date = np.asarray(tables[side][0])
+        np.testing.assert_array_equal(date[:len(host[fact][0])],
+                                      np.sort(host[fact][0]))
+        assert not date[len(host[fact][0]):].any()
+
+
+def test_a_capacity_one_short_raises_rather_than_answers(monkeypatch):
+    """A slice one row short of the widest window: the query over that
+    window fails with the overflow error, and one over a thinner window
+    still answers."""
+    monkeypatch.setattr(C, "_window_capacity", lambda widest, b: widest - 1)
+    resident.REGISTRY.clear()
+    sizes = dict(SIZES, web_returns=600)
+    dates = tpcds.gen_q5_db(sizes, 4)["store_sales"][0]
+    d = np.sort(dates)
+    first = d[np.argmax(np.searchsorted(d, d + tpcds.Q5_WINDOW_DAYS)
+                        - np.arange(len(d)))]
+    day = int(first) - tpcds.D_DATE_SK0 + tpcds.D_DATE0
+    widest = str(np.datetime64("1970-01-01") + day)
+    from spark_rapids_tpu.models import run_catalog_query
+    params = {"sizes": sizes, "db_seed": 4, "limit": 1000}
+    try:
+        with pytest.raises(RuntimeError, match="overflow"):
+            run_catalog_query("tpcds_q5_channels",
+                              dict(params, sales_date=widest))
+        run_catalog_query("tpcds_q5_channels",
+                          dict(params, sales_date="2003-07-01"))
+    finally:
+        resident.REGISTRY.clear()
+
+
+def test_the_map_stage_reads_slices_not_buckets(host):
+    """The lowered map stage: store_sales' columns feed the binary
+    search's loop and the slice alone, and every ``IsIn`` compare runs
+    over a slice's rows."""
+    from spark_rapids_tpu.plan.compiler import CompiledStage
+    tables = C.q5_channels_tables(host)
+    shape = _shape(SIZES, tables)
+    plan = C.q5_channels_map_plan(
+        shape["outlets"], shape["ids"], shape["item_bits"],
+        shape["join_capacity"], shape["window_days"],
+        exchange_slots=shape["exchange_slots"], windows=shape["windows"])
+    stage = CompiledStage(plan)
+    args, _parts, _b = stage._bind_args(
+        {**tables, "q": (np.int32(tpcds.q5_day("2000-08-23")),)})
+    jaxpr = jax.make_jaxpr(stage._fused_callable())(*args).jaxpr
+    ss = set(jaxpr.invars[:4])
+    assert {e.primitive.name for e in jaxpr.eqns
+            if ss & {v for v in e.invars if hasattr(v, "count")}} == {
+                "scan", "dynamic_slice"}
+    caps = set(dict(shape["windows"]).values())
+    assert caps == {1024}
+    assert {e.invars[0].aval.shape for e in jaxpr.eqns
+            if e.primitive.name == "eq"} == {(1024,)}
+    assert shape["join_capacity"] == 1024
+
+
+def test_q3_and_store_channel_digests_stay():
+    assert C.q3_plan(10_957, 2, 16, 3).digest == "f7f4e7f97573f7b3"
+    assert C.q5_pipeline(8, 4096).digest == "fd7f756823d628a3"
+
+
+def test_a_served_query_counts_what_it_pruned():
+    prior = obs.is_enabled()
+    obs.enable()
+    resident.REGISTRY.clear()
+
+    def pruned():
+        series = obs.PRUNED_ROWS.snapshot()["series"]
+        return {s["labels"][0]: s["value"] for s in series}
+    try:
+        before = pruned()
+        from spark_rapids_tpu.models import run_catalog_query
+        run_catalog_query("tpcds_q5_channels", {
+            "sizes": SIZES, "db_seed": 6, "sales_date": DATES[0]})
+        after = pruned()
+        spans = obs.TRACER.records()
+    finally:
+        resident.REGISTRY.clear()
+        if not prior:
+            obs.disable()
+    for table in tpcds.Q5_FACTS:
+        assert after[table] - before.get(table, 0) == SIZES[table] - 1024
+    execute = [s for s in spans if s["name"] == "execute"][-1]["attrs"]
+    assert execute["scan_rows"] == 5 * 1024 + SIZES["web_sales"]
+    load = [s for s in spans if s["name"] == "table_load"][-1]["attrs"]
+    assert load["window_capacity"] == (
+        "ss=1024,sr=1024,cs=1024,cr=1024,ws=1024,wr=1024")

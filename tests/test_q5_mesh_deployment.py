@@ -48,6 +48,9 @@ SIZES = dict(store_sales=20_003, store_returns=2_001, catalog_sales=40_002,
              item=1_000)
 DATES = ["1998-08-01", "1999-08-15", "2000-08-23", "2000-08-30",
          "2001-08-07", "2002-08-30", "2002-08-01", "1999-08-29"]
+# the first and the last sale day, and a fortnight after every fact's
+# last date: no fact holds a row
+EDGE_DATES = ["1998-01-02", "2002-12-31", "2003-07-01"]
 LIMIT = 1000          # past the toy rollup's 163 rows: every row served
 
 
@@ -65,7 +68,7 @@ def _served(chips, db_seed, date):
 def test_mesh_answers_equal_the_reference_and_one_chip(db_seed):
     resident.REGISTRY.clear()
     db = REF.database(dict(SIZES), db_seed)
-    for date in DATES:
+    for date in DATES + EDGE_DATES:
         mesh, one = _served(4, db_seed, date), _served(1, db_seed, date)
         assert mesh == one, date
         want = REF.answer({"db": db, "sales_date": date, "limit": LIMIT}, {})
@@ -74,9 +77,11 @@ def test_mesh_answers_equal_the_reference_and_one_chip(db_seed):
     resident.REGISTRY.clear()
 
 
-def _uneven_tables(host, mesh, shares):
+def _uneven_tables(host, mesh, shares, ordered=True):
     """``q5_channels_tables(host, mesh)`` with each fact cut at
-    ``shares`` of its rows instead of in equal parts."""
+    ``shares`` of its rows instead of in equal parts, each shard put in
+    date order here and the slice sized for the widest shard's window;
+    not ``ordered``, each shard in its drawn order and read whole."""
     from spark_rapids_tpu.perf.jit_cache import bucket_rows
     tables = C.q5_channels_tables(host, mesh)
     devices = list(mesh.devices.flat)
@@ -89,25 +94,53 @@ def _uneven_tables(host, mesh, shares):
         parts = list(zip(edges[:-1], edges[1:]))
         b = bucket_rows(max(hi - lo for lo, hi in parts))
         sharding = NamedSharding(mesh, P("data"))
+        orders = [lo + (np.argsort(cols[0][lo:hi], kind="stable")
+                        if ordered else np.arange(hi - lo))
+                  for lo, hi in parts]
+        widest = max(_widest(cols[0][lo:hi]) for lo, hi in parts)
         tables[inp.name] = Padded(tuple(
             jax.make_array_from_single_device_arrays(
                 (b * len(devices),), sharding,
                 [jax.device_put(np.concatenate(
-                    [c[lo:hi], np.full(b - (hi - lo), spec.pad, c.dtype)]), d)
-                 for d, (lo, hi) in zip(devices, parts)])
+                    [c[o], np.full(b - len(o), spec.pad, c.dtype)]), d)
+                 for d, o in zip(devices, orders)])
             for spec, c in zip(inp.columns, cols)), rows,
-            [hi - lo for lo, hi in parts])
+            [hi - lo for lo, hi in parts],
+            window=C._window_capacity(widest, b) if ordered else None)
     return tables
 
 
-def _run_mesh(tables, date, mesh, capacity=None):
+def _widest(dates):
+    """The most of ``dates`` in any window of the query's days."""
+    d = np.sort(dates)
+    return int((np.searchsorted(d, d + tpcds.Q5_WINDOW_DAYS) -
+                np.arange(len(d))).max())
+
+
+def _window_rows(dates, date):
+    """Rows of a fact's date keys inside SALES_DATE's fortnight."""
+    lo = tpcds.q5_day(date) - tpcds.D_DATE0 + tpcds.D_DATE_SK0
+    return int(((dates >= lo) & (dates < lo + tpcds.Q5_WINDOW_DAYS)).sum())
+
+
+def _mesh_shape(tables):
     sizes = tpcds.q5_sizes(SIZES)
-    shape = C.q5_channels_shape(sizes, tpcds.q5_dim_ids(sizes),
-                                tpcds.Q5_WINDOW_DAYS, 4)
-    *cols, of, pairs, sent = C.run_q5_channels(
-        tables, shape, tpcds.q5_day(date), LIMIT, mesh=mesh,
+    return C.q5_channels_shape(sizes, tpcds.q5_dim_ids(sizes),
+                               tpcds.Q5_WINDOW_DAYS, 4, C.q5_windows(tables))
+
+
+def _raw_mesh(tables, date, mesh, capacity=None):
+    """The mesh pipeline's outputs over ``tables`` as numpy arrays, and
+    the send counts."""
+    *out, sent = C.run_q5_channels(
+        tables, _mesh_shape(tables), tpcds.q5_day(date), LIMIT, mesh=mesh,
         capacity=capacity)
-    rows = [[int(v) for v in r] for r in zip(*(np.asarray(c) for c in cols))]
+    return [np.asarray(a) for a in out] + [sent]
+
+
+def _run_mesh(tables, date, mesh, capacity=None):
+    *cols, of, pairs, sent = _raw_mesh(tables, date, mesh, capacity)
+    rows = [[int(v) for v in r] for r in zip(*cols)]
     return REF.from_served(rows), bool(of), int(pairs), sent
 
 
@@ -120,25 +153,65 @@ def test_shards_of_differing_rows_give_the_same_answers():
     mesh = _mesh()
     tables = _uneven_tables(host, mesh, [0.1, 0.2, 0.3, 0.4])
     assert tables["ss"].shard_rows == (2000, 4000, 6001, 8002)
-    sizes = tpcds.q5_sizes(SIZES)
-    shape = C.q5_channels_shape(sizes, tpcds.q5_dim_ids(sizes),
-                                tpcds.Q5_WINDOW_DAYS, 4)
-    run = _q5_mesh_run(shape, LIMIT, mesh)
+    run = _q5_mesh_run(_mesh_shape(tables), LIMIT, mesh)
     db = REF.database(dict(SIZES), 2002)
     for date in DATES[:3]:
         (out, sent, over), capacity = run(tables, tpcds.q5_day(date))
         *cols, of, pairs = out
         assert not over and not bool(of) and capacity == 2048
-        assert int(pairs) == SIZES["web_returns"]
+        # every return of the fortnight finds its sale
+        returns = _window_rows(host["web_returns"][0], date)
+        assert int(pairs) == returns > 0
         rows = [[int(v) for v in r]
                 for r in zip(*(np.asarray(c) for c in cols))]
         assert REF.from_served(rows) == REF.answer(
             {"db": db, "sales_date": date, "limit": LIMIT}, {})
-        # every row of both web tables was sent once, pad rows none
+        # every row of web_sales and each return of the fortnight was
+        # sent once, pad rows none
         assert int(sent["web_sales"].sum()) == SIZES["web_sales"]
-        assert int(sent["web_returns"].sum()) == SIZES["web_returns"]
+        assert int(sent["web_returns"].sum()) == returns
         assert sent["web_sales"].shape == (4, 4)
         assert int(sent["web_sales"].max()) > 1024
+
+
+def test_mesh_slices_answer_as_the_whole_shards():
+    """Each chip's slice of its date-ordered shard against the same
+    shards in their drawn order, read whole: the same arrays."""
+    host = tpcds.gen_q5_db(SIZES, 2002)
+    mesh = _mesh()
+    sliced = C.q5_channels_tables(host, mesh)
+    whole = _uneven_tables(host, mesh, [0.25] * 4, ordered=False)
+    assert C.q5_windows(whole) == {}
+    # a quarter of the toy's small facts fits in one slice
+    assert [sliced[s].window * 4 < len(sliced[s][0])
+            for s in C._Q5_FACT_TABLES] == [1, 0, 1, 0, 1, 0]
+    db = REF.database(dict(SIZES), 2002)
+    for date in DATES[:2] + EDGE_DATES:
+        got, want = (_raw_mesh(t, date, mesh) for t in (sliced, whole))
+        for a, b in zip(got[:-1], want[:-1]):
+            np.testing.assert_array_equal(a, b)
+        rows = [[int(v) for v in r] for r in zip(*got[:-3])]
+        assert REF.from_served(rows) == REF.answer(
+            {"db": db, "sales_date": date, "limit": LIMIT}, {})
+
+
+def test_each_chip_orders_its_shard_and_the_widest_sizes_the_slice():
+    host = tpcds.gen_q5_db(SIZES, 2002)
+    hot = tpcds.q5_day("2000-08-25") - tpcds.D_DATE0 + tpcds.D_DATE_SK0
+    host["store_sales"][0][:len(host["store_sales"][0]) // 8] = hot
+    tables = C.q5_channels_tables(host, _mesh())
+    for side, fact in C._Q5_FACT_TABLES.items():
+        t, dates = tables[side], host[fact][0]
+        held = np.asarray(t[0]).reshape(4, -1)
+        widest, lo = 0, 0
+        for shard, n in zip(held, t.shard_rows):
+            np.testing.assert_array_equal(shard[:n], np.sort(dates[lo:lo + n]))
+            assert not shard[n:].any()
+            widest = max(widest, _widest(dates[lo:lo + n]))
+            lo += n
+        assert t.window == min(held.shape[1], -(-widest // 1024) * 1024)
+    # the first shard holds all of the hot day's rows, and sizes the slice
+    assert tables["ss"].window == 3072
 
 
 def test_a_side_partitioned_with_another_seed_reads_as_differing(
@@ -228,8 +301,9 @@ def _map_plan(sizes):
 def test_one_chip_map_stage_is_the_plan_before_the_exchange():
     plan = _map_plan(None)
     assert sum(isinstance(n, ir.Exchange) for n in plan.nodes) == 2
-    # the SF10 map stage of PR 39, whose executable the cell measured
-    assert _without_exchanges(plan).digest == "5f64c3d8cdacb015"
+    # the SF10 map stage over facts read whole, the returns' date
+    # filter below the join
+    assert _without_exchanges(plan).digest == "e72f6a52c56f27a4"
 
 
 def test_one_chip_lowering_holds_no_collective_and_no_extra_sort():
@@ -280,8 +354,11 @@ def test_served_mesh_query_counts_the_rows_its_exchange_sent():
         resident.REGISTRY.clear()
         if not prior:
             obs.disable()
-    for table in ("web_sales", "web_returns"):
-        assert after[table] - before.get(table, 0) == SIZES[table]
+    returns = tpcds.gen_q5_db(SIZES, 3)["web_returns"][0]
+    assert after["web_sales"] - before.get("web_sales", 0) == SIZES[
+        "web_sales"]
+    assert after["web_returns"] - before.get("web_returns", 0) == (
+        _window_rows(returns, DATES[0]))
     attrs = spans[-1]["attrs"]
     assert attrs["exchange_capacity"] == 1024
     assert 0 < attrs["exchange_max_dest_rows"] <= 1024

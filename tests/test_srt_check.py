@@ -384,6 +384,39 @@ class TestPlanVerify:
             else:
                 self.pv.verify_stage(plan)
 
+    def test_dtypes_flow_through_the_q5_slices(self):
+        from spark_rapids_tpu.plan import catalog as pc
+        slots = (("web_returns", 1024), ("web_sales", 16384))
+        windows = tuple((s, 1024) for s in ("ss", "sr", "cs", "cr", "ws",
+                                            "wr"))
+        plan = pc.q5_channels_map_plan((150, 12, 6), (150, 6, 3), 10,
+                                       1024, exchange_slots=slots,
+                                       windows=windows)
+        assert sum(isinstance(n, self.ir.WindowSlice)
+                   for n in plan.nodes) == 6
+        dtypes = {i.name: tuple(
+            "int64" if c.name.split("_", 1)[1] in (
+                "price", "profit", "amt", "loss") else "int32"
+            for c in i.columns) for i in plan.inputs}
+        self.pv.verify_stage(plan, dtypes)
+
+    def slice_plan(self, input_name="f", capacity=8):
+        ir = self.ir
+        return ir.StagePlan(
+            "t_slice",
+            inputs=(ir.ScanBind("f", (ir.ColSpec("d"), ir.ColSpec("v"))),),
+            nodes=(ir.WindowSlice("z", input_name, ir.Col("d"),
+                                  ir.Lit(3), ir.Lit(5), ("d", "v"),
+                                  capacity),),
+            outputs=("z.v", "z.valid", "z.over"))
+
+    def test_a_slice_binds_its_input_and_a_capacity(self):
+        self.pv.verify_stage(self.slice_plan(), {"f": ("int32", "int64")})
+        self._expect_reject(self.slice_plan(input_name="g"),
+                            "slice input 'g'")
+        self._expect_reject(self.slice_plan(capacity=0),
+                            "non-positive slice capacity 0")
+
     def _expect_reject(self, plan_or_pipe, *needles):
         with pytest.raises(self.pv.PlanVerifyError) as ei:
             if isinstance(plan_or_pipe, self.ir.Pipeline):

@@ -445,3 +445,53 @@ class TestStageObservability:
         assert "q3" in table
         report = build_report(events)
         assert any(r["stage"] == "q3" for r in report["stages"])
+
+
+# ------------------------------------------------------- window slice
+
+
+def _slice_plan(capacity):
+    """Sum ``v`` over the rows of ``d`` in [lo, hi], read through a
+    WindowSlice of ``capacity`` rows of the date-ordered input ``f``."""
+    return ir.StagePlan(
+        "t_window_slice",
+        inputs=(ir.ScanBind("f", (ir.ColSpec("d"), ir.ColSpec("v"))),
+                ir.ScanBind("q", (ir.ColSpec("lo"), ir.ColSpec("hi")),
+                            bucket=False)),
+        nodes=(
+            ir.WindowSlice("z", "f", ir.Col("d"), ir.Col("lo"),
+                           ir.Col("hi"), ("d", "v"), capacity),
+            ir.Project("keep", ir.Bin("and", ir.Col("z.valid"), ir.Bin(
+                "and", ir.Bin("ge", ir.Col("z.d"), ir.Col("lo")),
+                ir.Bin("le", ir.Col("z.d"), ir.Col("hi"))))),
+            ir.Project("total", ir.Un("sum", ir.Where(
+                ir.Col("keep"), ir.Col("z.v"), ir.Lit(0, "int64")))),
+        ),
+        outputs=("total", "z.over"))
+
+
+@pytest.mark.parametrize("lo,hi", [(0, 2), (3, 5), (6, 9), (9, 9),
+                                   (-5, -1), (10, 20), (4, 3)])
+def test_window_slice_reads_the_rows_of_its_range(lo, hi):
+    """Sorted true rows with a pad tail: every range, the first rows,
+    the last (a slice clamped back from the bucket's end), before and
+    past every key and an empty one, sums as numpy does over the whole
+    column, and ``over`` is False while the range fits the slice."""
+    rng = np.random.default_rng((lo + 10) * 31 + hi + 10)
+    d = np.sort(rng.integers(0, 10, 1000)).astype(np.int32)
+    v = rng.integers(-1000, 1000, 1000).astype(np.int64)
+    b = bucket_rows(len(d))
+    cols = PC.Padded((np.concatenate([d, np.zeros(b - len(d), np.int32)]),
+                      np.concatenate([v, np.full(b - len(v), 7)])), len(d))
+    inside = (d >= lo) & (d <= hi)
+    assert inside.sum() <= 512
+    stage = PC.CompiledStage(_slice_plan(512))
+    q = (np.int32(lo), np.int32(hi))
+    total, over = stage.run({"f": cols, "q": q})
+    assert int(total) == int(v[inside].sum()) and not bool(over)
+    total, over = stage.run_unfused({"f": (d, v), "q": q})
+    assert int(total) == int(v[inside].sum()) and not bool(over)
+    if (lo, hi) == (3, 5):      # a slice one row short: flagged
+        _total, over = PC.CompiledStage(_slice_plan(
+            int(inside.sum()) - 1)).run({"f": cols, "q": q})
+        assert bool(over)
