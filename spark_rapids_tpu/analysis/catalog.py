@@ -95,6 +95,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "table lookups traced by engine"),
     "srt_resident_table_total": (
         "counter", "resident catalog tables by load / hit / evict"),
+    "srt_compile_total": (
+        "counter", "JAX compile steps by step and persistent-cache verdict"),
     "srt_row_conversion_total": (
         "counter", "eager row conversions by direction and engine"),
     "srt_from_rows_validity_total": (

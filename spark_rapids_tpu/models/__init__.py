@@ -410,10 +410,16 @@ def _run_q5_channels(params: dict, ctx: QueryContext):
             raise ValueError(f"q5 over {chips} chips: {jax.device_count()} "
                              f"devices")
         mesh = Mesh(np.array(jax.devices()[:chips]), ("data",))
+
+    def load():
+        with _obs.TRACER.span("table_gen", kind="phase") as span:
+            host = tpcds.gen_q5_db(sizes, db_seed)
+            span.set_attr("rows", sum(len(host[t][0])
+                                      for t in tpcds.Q5_FACTS))
+        return plan_catalog.q5_channels_tables(host, mesh,
+                                               tpcds.Q5_WINDOW_DAYS)
     tables = resident.REGISTRY.get(
-        ("tpcds_q5", tuple(sorted(sizes.items())), db_seed, chips),
-        lambda: plan_catalog.q5_channels_tables(
-            tpcds.gen_q5_db(sizes, db_seed), mesh, tpcds.Q5_WINDOW_DAYS))
+        ("tpcds_q5", tuple(sorted(sizes.items())), db_seed, chips), load)
     shape = plan_catalog.q5_channels_shape(
         sizes, tpcds.q5_dim_ids(sizes), tpcds.Q5_WINDOW_DAYS, chips,
         plan_catalog.q5_windows(tables))

@@ -29,6 +29,8 @@ import threading
 import time
 from typing import Callable, Optional
 
+import jax.monitoring
+
 from spark_rapids_tpu.observability import flight_recorder as _fr
 from spark_rapids_tpu.observability import slo as _slo
 from spark_rapids_tpu.observability import stats as _stats
@@ -290,6 +292,13 @@ RESIDENT_TABLE = METRICS.counter(
     "(models/resident.py), by outcome: load = generated and uploaded, "
     "hit = a query bound to what was held, evict = dropped for the "
     "byte budget, least recently used first", labels=("outcome",))
+COMPILE_TOTAL = METRICS.counter(
+    "srt_compile_total",
+    "JAX compile steps of this process (trace = jaxpr trace, lower = "
+    "MLIR lowering, backend = XLA compile or persistent-cache read), "
+    "by step and, on backend steps, the persistent cache's verdict "
+    "(hit, miss = XLA compiled, off = no cache; '-' on the others)",
+    labels=("step", "persistent"))
 ROW_CONVERSION = METRICS.counter(
     "srt_row_conversion_total",
     "Eager JCUDF row conversions by direction (to_rows / from_rows) "
@@ -555,6 +564,74 @@ TRACER = Tracer(capacity=65536,
                 task_lookup=lambda: TASKS.tasks_for(),
                 on_finish=_on_span_finish,
                 timeline_ref=_SWITCH, annotate=_annotate)
+
+
+# ---------------------------------------------------- compile spans
+# Every compile in the process, whatever built it (the stage compiler's
+# AOT executables, cached_call, a plain jax.jit), from JAX's own compile
+# events: a ``compile`` span a step, opened where JAX starts the step
+# (its start scalar) under the span open on the compiling thread, and
+# ended by the step's time-span event, so a trace nested in another
+# nests.  The persistent cache's two events fire on that thread inside
+# the backend step.
+
+_COMPILE_STEPS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_VERDICTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+# per thread, the compile steps open on it: [step, span, verdict]
+_COMPILING = threading.local()
+
+
+def _open_compiles() -> list:
+    steps = getattr(_COMPILING, "steps", None)
+    if steps is None:
+        steps = _COMPILING.steps = []
+    return steps
+
+
+def _on_compile_start(event: str, _value, **kw) -> None:
+    step = _COMPILE_STEPS.get(event)
+    if step is not None:
+        span = TRACER.start_span("compile", kind="compile", attrs={
+            "step": step, "fun": kw.get("fun_name", "")})
+        _open_compiles().append([step, span, "off"])
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    verdict = _CACHE_VERDICTS.get(event)
+    if verdict is None:
+        return
+    steps = _open_compiles()
+    if steps and steps[-1][0] == "backend":
+        steps[-1][2] = verdict
+
+
+def _on_compile_end(event: str, _start, _end, **_kw) -> None:
+    step = _COMPILE_STEPS.get(event)
+    if step is None:
+        return
+    steps = _open_compiles()
+    if not steps or steps[-1][0] != step:
+        return
+    _step, span, verdict = steps.pop()
+    persistent = "-"
+    if step == "backend":
+        persistent = verdict
+        span.set_attr("persistent", verdict)
+    span.end()
+    if _SWITCH.enabled:
+        COMPILE_TOTAL.inc(labels=(step, persistent))
+
+
+jax.monitoring.register_scalar_listener(_on_compile_start)
+jax.monitoring.register_event_listener(_on_cache_event)
+jax.monitoring.register_event_time_span_listener(_on_compile_end)
 
 
 # ------------------------------------------------------- query profiler

@@ -11,8 +11,9 @@ exhaustive, non-overlapping bucket set:
 
   queue_wait        server admission -> dispatch (``queue_wait_ns``
                     stamped into the profile by the server)
-  compile           ``stage_compile`` build time inside stage walls
-                    (``compile_ns`` on stage records; a cache hit is 0)
+  compile           a stage build's lower + compile wall inside stage
+                    walls (``compile_ns`` on stage records, where its
+                    ``compile`` spans are; a cache hit is 0)
   compute_fused     fused-engine stage wall minus its compile share
   compute_unfused   every other engine's stage wall minus compile
   shuffle_wire      serialize+send segments (``shuffle_wire`` journal

@@ -328,10 +328,13 @@ class Tracer:
     def activate(self, ctx: Optional[SpanContext]):
         """Adopt a remote context as this thread's current parent for
         the duration of the ``with`` block — the shuffle-read side uses
-        this to re-parent its spans under the writing task's span.  A
-        None ctx (or disabled tracer) is a no-op placeholder so callers
-        never branch."""
-        if not self.enabled or ctx is None:
+        this to re-parent its spans under the writing task's span, and a
+        pool thread its timeline spans under the span that handed it
+        work.  A None ctx (or both switches off) is a no-op placeholder
+        so callers never branch."""
+        if ctx is None or not (self.enabled or (
+                self.timeline_ref is not None
+                and self.timeline_ref.enabled)):
             return NOOP_SPAN
         span = Span(self, ctx[0], ctx[1], ROOT_PARENT, "<remote>",
                     "remote", None, None, attached=True, remote=True)

@@ -569,16 +569,50 @@ def q5_channels_tables(host: dict, mesh=None, window_days: int = 15) -> dict:
     consecutive date keys from date_dim's hold, to a multiple of 1,024
     (``_window_capacity``).  That is the capacity of the
     ``WindowSlice`` the map stage reads it through, fixed for the life
-    of the database: one executable a stage still."""
-    from concurrent.futures import ThreadPoolExecutor
+    of the database: one executable a stage still.
+
+    Two timeline spans under the caller's (``table_load``):
+    ``table_upload`` (the padding and every ``device_put``; attribute
+    ``bytes``, what crossed to the devices) and ``table_order`` (the
+    sorts and window searches, their compiles too); each ends when its
+    work on the device is done."""
+    import jax
+
+    from spark_rapids_tpu import observability as _obs
+
+    with _obs.TRACER.span("table_upload", kind="phase") as span:
+        out, held = _q5_upload(host, mesh)
+        # no reference outlives the wait: each fact's drawn columns are
+        # freed as its sort returns (the device holds one fact twice)
+        span.set_attr("bytes", _ready_bytes(
+            [a for t in out.values() for a in t]
+            + [a for h in held.values() for a in h[0][1]]))
+    with _obs.TRACER.span("table_order", kind="phase"):
+        _q5_order(out, held, mesh, window_days)
+        jax.block_until_ready([a for t in out.values() for a in t])
+    return out
+
+
+def _ready_bytes(arrays) -> int:
+    """The bytes of ``arrays`` on their devices (a replicated array
+    once a device), once they are there."""
+    import math
 
     import jax
+    jax.block_until_ready(arrays)
+    return sum(math.prod(a.sharding.shard_shape(a.shape)) * a.itemsize
+               * len(a.sharding.addressable_devices) for a in arrays)
+
+
+def _q5_upload(host: dict, mesh):
+    """The q5 database on the device, the facts as they were drawn:
+    (the dims by name, each fact's (order's arguments, true rows, shard
+    rows, bucket) by map-stage input)."""
+    import jax
     import numpy as np
-    from jax import lax, shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from spark_rapids_tpu.perf.jit_cache import bucket_rows
-    from spark_rapids_tpu.plan.compiler import Padded
 
     def padded(c, b, pad):
         return np.concatenate([c, np.full(b - len(c), pad, c.dtype)])
@@ -586,19 +620,8 @@ def q5_channels_tables(host: dict, mesh=None, window_days: int = 15) -> dict:
     whole = None if mesh is None else NamedSharding(mesh, P())
     out = {"dd": (jax.device_put(host["d_date_sk"], whole),
                   jax.device_put(host["d_date"], whole))}
-
-    def order_rows(n_valid, cols, keys):
-        return _order_rows(n_valid, cols, keys, window_days)
-    if mesh is None:
-        order = jax.jit(order_rows)
-    else:
-        axis = mesh.axis_names[0]
-        order = jax.jit(shard_map(
-            lambda counts, cols, keys: order_rows(
-                counts[lax.axis_index(axis)], cols, keys),
-            mesh=mesh, in_specs=(P(), P(axis), P()), out_specs=P(axis)))
-    # each fact on the device as it was drawn: (order's arguments,
-    # true rows, shard rows, bucket)
+    for dim in _DIM_ID:
+        out[dim] = (jax.device_put(host[dim], whole),)
     held = {}
     for inp in Q5_CHANNEL_INPUTS[:6]:
         cols = host[_Q5_FACT_TABLES[inp.name]]
@@ -623,20 +646,48 @@ def q5_channels_tables(host: dict, mesh=None, window_days: int = 15) -> dict:
                  for d, (lo, hi) in zip(devices, shards)])
             for spec, c in zip(inp.columns, cols)), out["dd"][0]),
             rows, counts, b)
+    return out, held
+
+
+def _q5_order(out: dict, held: dict, mesh, window_days: int) -> None:
+    """Each fact of ``held`` into ``out`` in date order, with its
+    window (``q5_channels_tables``)."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    import jax
+    import numpy as np
+    from jax import lax, shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from spark_rapids_tpu import observability as _obs
+    from spark_rapids_tpu.plan.compiler import Padded
+
+    def order_rows(n_valid, cols, keys):
+        return _order_rows(n_valid, cols, keys, window_days)
+    if mesh is None:
+        order = jax.jit(order_rows)
+    else:
+        axis = mesh.axis_names[0]
+        order = jax.jit(shard_map(
+            lambda counts, cols, keys: order_rows(
+                counts[lax.axis_index(axis)], cols, keys),
+            mesh=mesh, in_specs=(P(), P(axis), P()), out_specs=P(axis)))
     # the six sorts compile side by side (a sort of a few payload
-    # columns takes a v5e's compiler a minute), then run one by one
+    # columns takes a v5e's compiler a minute), then run one by one;
+    # the pool's compile spans go under this thread's open span
     lowered = {name: order.lower(*h[0]) for name, h in held.items()}
+    here = _obs.TRACER.current_context()
+
+    def compile_(low):
+        with _obs.TRACER.activate(here):
+            return low.compile()
     with ThreadPoolExecutor(len(lowered)) as pool:
-        compiled = dict(zip(lowered, pool.map(lambda low: low.compile(),
-                                              lowered.values())))
+        compiled = dict(zip(lowered, pool.map(compile_, lowered.values())))
     for name in list(held):
         args, rows, shard_rows, b = held.pop(name)
         cols, widest = compiled[name](*args)
         out[name] = Padded(cols, rows, shard_rows, window=_window_capacity(
             int(np.asarray(widest).max()), b))
-    for dim in _DIM_ID:
-        out[dim] = (jax.device_put(host[dim], whole),)
-    return out
 
 
 def q5_windows(tables: dict) -> dict:

@@ -665,12 +665,7 @@ class CompiledStage:
 
         def build():
             t0 = time.monotonic_ns()
-            with _obs.TRACER.span(
-                    "stage_compile", kind="compile",
-                    attrs={"stage": self.plan.name, "digest": digest,
-                           "bucket": bucket,
-                           "nodes": self.dispatch_count}):
-                ex = jax.jit(fn).lower(*args).compile()
+            ex = jax.jit(fn).lower(*args).compile()
             compiled_now.append(time.monotonic_ns() - t0)
             return ex
 
@@ -727,9 +722,9 @@ class CompiledStage:
         journal event.  The wall is measured past
         ``block_until_ready`` (an async backend's dispatch-only time
         would lie).  The whole execution is the timeline's
-        ``stage_run:<plan>`` span, with ``stage_bind``,
-        ``stage_compile``, ``dispatch`` and ``device_wait`` under
-        it."""
+        ``stage_run:<plan>`` span, with ``stage_bind``, ``dispatch``
+        and ``device_wait`` under it, and a build's ``compile`` spans
+        (JAX's compile events, ``observability``)."""
         from spark_rapids_tpu import observability as _obs
 
         with _obs.TRACER.span(f"stage_run:{self.plan.name}",
@@ -1062,9 +1057,9 @@ class MeshPipeline:
     an all-to-all over the axis.  The executable lives in the process
     compile cache under (pipeline digest, operand shapes, devices,
     bucket), and a run records what :meth:`CompiledStage.run` records:
-    spans ``stage_run:<pipeline>``, ``stage_bind``, ``stage_compile``
-    (when it builds), ``dispatch`` and ``device_wait`` (attribute
-    ``stage``), and ``srt_stage_fusion_total``."""
+    spans ``stage_run:<pipeline>``, ``stage_bind``, ``compile`` (when
+    it builds), ``dispatch`` and ``device_wait`` (attribute ``stage``),
+    and ``srt_stage_fusion_total``."""
 
     def __init__(self, pipeline: ir.Pipeline, mesh):
         self.pipeline, self.mesh = pipeline, mesh
@@ -1134,13 +1129,9 @@ class MeshPipeline:
                 t_build = time.monotonic_ns()
                 out_specs = ((P(),) * n_out
                              + (P(self.axis),) * len(self.exchanges))
-                with _obs.TRACER.span(
-                        "stage_compile", kind="compile",
-                        attrs={"stage": self.name, "digest": digest,
-                               "bucket": bucket, "nodes": self.nodes}):
-                    ex = jax.jit(shard_map(
-                        self.fn, mesh=self.mesh, in_specs=specs,
-                        out_specs=out_specs)).lower(*args).compile()
+                ex = jax.jit(shard_map(
+                    self.fn, mesh=self.mesh, in_specs=specs,
+                    out_specs=out_specs)).lower(*args).compile()
                 compiled_now.append(time.monotonic_ns() - t_build)
                 return ex
 

@@ -691,7 +691,7 @@ class TestTheCellRefusesAProgramOffItsEngines:
             "to_rows_ms", "from_rows_ms",
             "to_rows_roofline", "from_rows_roofline",
             "rowconv_dispatch_ms", "hbm_roofline", "device_idle_pct",
-            "window_compiles"}
+            "window_compiles", "setup_compile_s", "setup_compile_misses"}
 
     def test_the_configuration_sets_no_engine_variable(self):
         config = harness.load_json(
